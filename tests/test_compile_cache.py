@@ -19,11 +19,6 @@ import jax.numpy as jnp  # noqa: E402
 
 from textblaster_tpu.utils import compile_cache as cc
 
-if not cc.aot_cache_supported():  # pragma: no cover - older jax
-    pytest.skip(
-        "jax lacks experimental.serialize_executable", allow_module_level=True
-    )
-
 
 def _tiny_compiled(scale=3):
     fn = jax.jit(lambda x: x * scale + 1)
@@ -201,3 +196,63 @@ pipeline:
         )
     }
     assert warm_out == cold_out
+
+
+def test_one_device_executable_runs_on_many_device_host(tmp_path):
+    """The suite's host has 8 CPU devices: a stored one-device executable
+    must load onto its own device, not onto all 8 (JAX 0.9 loads onto every
+    local device unless told), and a fresh store instance must call it."""
+    assert len(jax.devices()) > 1
+    key = "h" * 32
+    assert cc.AOTExecutableCache(cache_dir=str(tmp_path)).store(key, _tiny_compiled(5))
+    loaded = cc.AOTExecutableCache(cache_dir=str(tmp_path)).load(key)
+    assert loaded is not None
+    x = jnp.arange(8, dtype=jnp.int32)
+    np.testing.assert_array_equal(np.asarray(loaded(x)), np.arange(8) * 5 + 1)
+
+
+def test_mesh_executable_round_trip(tmp_path):
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    from textblaster_tpu.parallel.mesh import data_mesh
+
+    mesh = data_mesh(jax.devices()[:4])
+    sharding = NamedSharding(mesh, PartitionSpec("data"))
+    compiled = (
+        jax.jit(lambda x: x * 2, in_shardings=sharding, out_shardings=sharding)
+        .lower(jax.ShapeDtypeStruct((8,), jnp.int32))
+        .compile()
+    )
+    cache = cc.AOTExecutableCache(cache_dir=str(tmp_path))
+    assert cache.store("i" * 32, compiled)
+    loaded = cache.load("i" * 32)
+    x = jax.device_put(jnp.arange(8, dtype=jnp.int32), sharding)
+    out = loaded(x)
+    np.testing.assert_array_equal(np.asarray(out), np.arange(8) * 2)
+    assert {s.device for s in out.addressable_shards} == set(jax.devices()[:4])
+
+
+def test_cache_dir_placed_from_outside(tmp_path, monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR, when set, holds every compiled artifact:
+    JAX's cache setting is left as JAX read it, and the executable store
+    moves beside it."""
+    monkeypatch.delenv("TEXTBLAST_NO_COMPILE_CACHE", raising=False)
+    monkeypatch.delenv("TEXTBLAST_AOT_CACHE_DIR", raising=False)
+    placed = tmp_path / "placed"
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(placed))
+    before = jax.config.jax_compilation_cache_dir
+    assert cc.enable_compilation_cache() == str(placed)
+    assert jax.config.jax_compilation_cache_dir == before  # left alone
+    assert placed.is_dir()
+    store = cc.AOTExecutableCache()
+    assert store.cache_dir == os.path.join(str(placed), "textblast-aot")
+    assert store.store("j" * 32, _tiny_compiled())
+    assert os.listdir(store.cache_dir)
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    assert cc.default_aot_dir() == cc.DEFAULT_AOT_DIR
+    try:
+        assert cc.enable_compilation_cache() == cc.DEFAULT_CACHE_DIR
+        assert jax.config.jax_compilation_cache_dir == cc.DEFAULT_CACHE_DIR
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

@@ -253,7 +253,7 @@ def test_gopher_rep_depfuse_vs_staged(interp, monkeypatch):
     st = structure(cps, lens, with_hashes=True)
     with psc.count_scan_dispatches() as counts:
         on = gopher_rep_stats(st, (2, 3), (5, 6), 128, 256)
-    assert set(counts) == {"fused"}, dict(counts)
+    assert set(counts) == {"fused", "pallas_sort"}, dict(counts)
     with monkeypatch.context() as m:
         m.setenv("TEXTBLAST_DEPFUSE", "off")
         st2 = structure(cps, lens, with_hashes=True)
@@ -423,7 +423,7 @@ pipeline:
 # (or silently drops a path out of chain_scan_ok) moves these numbers —
 # update them only with a parity-verified kernel change.
 _GATE_EXPECT_ON = {
-    0: {"fused": 5},
+    0: {"fused": 5, "pallas_sort": 3},
     1: {"fused": 4, "lax_scan": 2, "pallas_scan": 1},
 }
 

@@ -184,6 +184,47 @@ def test_probe_cache_keys_on_env_hatches(monkeypatch):
         assert mod._probe_cached.cache_info().misses == 2
 
 
+@pytest.mark.parametrize(
+    "probe",
+    ["sort", "scan", "fused", "depfuse"],
+)
+def test_failed_probe_on_tpu_raises(probe):
+    """A kernel that fails its probe on a TPU is an error, never a quiet
+    switch to the lax schedule.  Asked to probe "tpu" from this CPU-only
+    process, the Mosaic lowering fails, which must raise; on the CPU the
+    probes answer False (the lax schedules are the CPU's path)."""
+    fn = {
+        "sort": pso._probe_cached,
+        "scan": psc._probe_cached,
+        "fused": psc._probe_fused_cached,
+        "depfuse": psc._probe_depfuse_cached,
+    }[probe]
+    assert fn(("test",), "cpu") is False
+    with pytest.raises(RuntimeError, match="probe"):
+        fn(("test",), "tpu")
+
+
+@pytest.mark.parametrize("probe", ["sort", "scan", "fused", "depfuse"])
+def test_pipeline_probes_before_tracing(probe, monkeypatch):
+    """A probe compiles and runs a kernel, which it cannot do inside the
+    trace of a pipeline program: CompiledPipeline resolves every gate when
+    it is built, and tracing reads the cached verdicts."""
+    from textblaster_tpu.config.pipeline import load_pipeline_config
+    from textblaster_tpu.ops.pipeline import CompiledPipeline
+
+    for var in ("TEXTBLAST_PALLAS", "TEXTBLAST_NO_PALLAS", "TEXTBLAST_FUSED",
+                "TEXTBLAST_DEPFUSE", "TEXTBLAST_PALLAS_INTERPRET"):
+        monkeypatch.delenv(var, raising=False)
+    asked = []
+    for name, mod, attr in (("sort", pso, "_probe_backend"),
+                            ("scan", psc, "_probe_backend"),
+                            ("fused", psc, "_probe_fused"),
+                            ("depfuse", psc, "_probe_depfuse")):
+        monkeypatch.setattr(mod, attr, lambda name=name: asked.append(name) or True)
+    CompiledPipeline(load_pipeline_config("configs/pipeline_config_offline.yaml"))
+    assert probe in asked
+
+
 def test_mesh_tracing_with_mesh_keeps_kernels(interp):
     """mesh_tracing(mesh) means shard_map, not decline; the legacy marker
     forms keep their PR 7 semantics (covered in test_pallas_scan too)."""

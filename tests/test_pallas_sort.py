@@ -99,3 +99,18 @@ def test_sort2_packed_vs_two_operand_fallback():
         jax.config.update("jax_enable_x64", True)
     for g, w in zip(got_packed, got_two_op):
         np.testing.assert_array_equal(g, w)
+
+
+@pytest.mark.parametrize("max_lanes, kind", [(256, "pallas_sort"), (128, "lax_sort")])
+def test_sort_dispatch_is_counted(monkeypatch, max_lanes, kind):
+    """chip_smoke.py fails on any row sort that left the kernel: a width
+    past the gate must book as "lax_sort", never pass silently."""
+    from textblaster_tpu.ops import pallas_sort as pso
+
+    monkeypatch.setenv("TEXTBLAST_PALLAS_INTERPRET", "1")
+    monkeypatch.setattr(pso, "_MAX_SORT_LANES", max_lanes)
+    k = jnp.asarray(np.random.default_rng(5).integers(0, 9, (_ROWS, 256)), jnp.int32)
+    with pso.count_scan_dispatches() as counts:
+        got = pso.sort2(k, k)
+    assert counts == {kind: 1}
+    np.testing.assert_array_equal(np.asarray(got[0]), np.sort(np.asarray(k), axis=1))

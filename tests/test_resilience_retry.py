@@ -155,7 +155,7 @@ def test_classifier_transient_families():
     assert is_retryable_error(ConnectionResetError())
     assert is_retryable_error(MemoryError())
     assert is_retryable_error(XlaRuntimeError("RESOURCE_EXHAUSTED: hbm"))
-    assert is_retryable_error(XlaRuntimeError("UNAVAILABLE: tunnel lost"))
+    assert is_retryable_error(XlaRuntimeError("UNAVAILABLE: connection lost"))
     assert is_retryable_error(
         ParquetError("connection reset while reading footer")
     )

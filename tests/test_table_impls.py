@@ -3,7 +3,7 @@
 The device kernels build their per-line/per-segment/per-word tables two ways
 (:func:`textblaster_tpu.ops.device.use_sort_tables`): XLA scatters (the CPU
 default) and a sorted compaction + gathers (the TPU default — XLA:TPU
-serializes scatters into per-element loops; see TPU_EVIDENCE_r03).  The TPU
+serializes scatters into per-element loops).  The TPU
 path cannot run on TPU in CI, but its *semantics* are backend-independent:
 this suite pins both implementations to identical outputs on the nasty-case
 corpus (blank lines, trailing newlines, all-whitespace lines, citations,

@@ -1,0 +1,166 @@
+"""The main path's kernels compile for a TPU v5e (no chip needed).
+
+Interpret mode runs every kernel's program on the CPU but never asks Mosaic
+to lower it, so a kernel can pass every parity suite and still be refused by
+the chip's compiler (lane reversal, unaligned dynamic slices, bool selects,
+scoped-VMEM overflows — each one happened).  These tests compile for a
+*described* ``v5e:2x2`` chip: the TPU compiler is installed here and
+compiles for a device that is not attached.  Nothing runs.
+
+The topology is described inside a module fixture, never at import: only one
+process may load the TPU library at a time, and every xdist worker imports
+every test file.  The persistent compilation cache is off around these
+tests (an entry compiled for a described chip cannot be read back here).
+"""
+
+import os
+
+import pytest
+
+jax = pytest.importorskip("jax")
+
+import jax.numpy as jnp  # noqa: E402
+from jax.sharding import SingleDeviceSharding  # noqa: E402
+
+from textblaster_tpu.ops import pallas_scan as psc  # noqa: E402
+from textblaster_tpu.ops import pallas_sort as pso  # noqa: E402
+
+ROWS = 64  # the TPU default geometry's rows at the widest bucket
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    cache_was = jax.config.jax_enable_compilation_cache
+    x64_was = jax.config.jax_enable_x64
+    # The chip runs with x64 off; the suite's CPU configuration turns it on.
+    jax.config.update("jax_enable_x64", False)
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", cache_was)
+    jax.config.update("jax_enable_x64", x64_was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture
+def compiled_kernels(monkeypatch):
+    """Real (not interpret-mode) kernels, every hatch open."""
+    for var in ("TEXTBLAST_PALLAS", "TEXTBLAST_NO_PALLAS", "TEXTBLAST_FUSED",
+                "TEXTBLAST_DEPFUSE", "TEXTBLAST_PALLAS_INTERPRET"):
+        monkeypatch.delenv(var, raising=False)
+
+
+def _compile(fn, shapes, sharding):
+    args = [jax.ShapeDtypeStruct(s, d, sharding=sharding) for s, d in shapes]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+def test_scan_kernel_compiles(one_chip, compiled_kernels):
+    def fn(m, a, _):
+        return psc._pallas_scan_tuple(psc._affine_op, (1, 0), (m, a, a), False)
+
+    _compile(fn, [((ROWS, psc._MAX_LANES), jnp.int32)] * 3, one_chip)
+
+
+def test_fused_kernel_compiles(one_chip, compiled_kernels):
+    def fn(m, a, ones, fns):
+        return psc._fused_call(
+            [
+                psc.affine_group(m, (a,)),
+                psc.add_group((ones,), emit="last"),
+                psc.dfa_group(fns, 4),
+            ],
+            False,
+        )
+
+    _compile(fn, [((ROWS, psc._FUSED_MAX_LANES), jnp.int32)] * 4, one_chip)
+
+
+def test_chain_kernel_compiles(one_chip, compiled_kernels):
+    """Reverse-walk passes, shift taps across block edges, VMEM scratch and
+    every group kind — the surface the probe checks on the chip."""
+
+    def fn(m, vals, reset):
+        passes = [
+            psc.chain_pass([{"kind": "affine", "xs": (m, vals), "emit": "none"}]),
+            psc.chain_pass(
+                [
+                    psc.chain_group(
+                        "segmax",
+                        (psc.Tap(0, 0), reset),
+                        prep=lambda seg, r: (jnp.where(r != 0, seg, 0), r),
+                        n_ops=2,
+                    )
+                ],
+                reverse=True,
+            ),
+            psc.chain_pass(
+                [
+                    psc.chain_group(
+                        "copy",
+                        (psc.Tap(1, 0), psc.Tap(0, 0, shift=1, fill=0)),
+                        prep=lambda rt, prev: (rt + prev,),
+                        n_ops=1,
+                        emit="scan",
+                    ),
+                    psc.chain_group(
+                        "affine",
+                        (psc.Tap(1, 0, shift=1, fill=3), vals),
+                        n_ops=2,
+                        emit="last",
+                    ),
+                ],
+                reverse=True,
+            ),
+        ]
+        return psc.chain_scan(passes)
+
+    _compile(fn, [((ROWS, 8192), jnp.int32)] * 3, one_chip)
+
+
+@pytest.mark.parametrize("n_keys", [2, 3])
+def test_sort_kernel_compiles(one_chip, compiled_kernels, n_keys):
+    # The widest row the gate admits: its looped network compiles in ~7 s
+    # (unrolled, 8192 lanes took 41 s and 32768 never finished).
+    def fn(*ks):
+        return pso._pallas_sort_n(ks)
+
+    _compile(fn, [((ROWS, pso._MAX_SORT_LANES), jnp.int32)] * n_keys, one_chip)
+
+
+def test_shipped_pipeline_programs_compile(one_chip, compiled_kernels, monkeypatch):
+    """Every phase program of the shipped config at the 2048 bucket, with
+    the TPU's own defaults (this process's backend is the CPU, so they are
+    steered here): the chain preps in ops/stats.py lower inside Mosaic."""
+    from textblaster_tpu.config.pipeline import load_pipeline_config
+    from textblaster_tpu.ops.pipeline import CompiledPipeline
+
+    monkeypatch.setenv("TEXTBLAST_SCAN_IMPL", "shift")
+    monkeypatch.setenv("TEXTBLAST_TABLE_IMPL", "sort")
+    monkeypatch.setenv("TEXTBLAST_WIRE", "u16")
+    for mod, name in ((pso, "_probe_backend"), (psc, "_probe_backend"),
+                      (psc, "_probe_fused"), (psc, "_probe_depfuse")):
+        monkeypatch.setattr(mod, name, lambda: True)
+    pipeline = CompiledPipeline(
+        load_pipeline_config("configs/pipeline_config_offline.yaml"),
+        batch_size=ROWS,
+    )
+    for phase in range(len(pipeline.phases)):
+        fn = pipeline._build_fn(2048, phase, jit=False)
+        _compile(
+            fn,
+            [((ROWS, 2048), jnp.uint16), ((ROWS,), jnp.int32)],
+            one_chip,
+        )

@@ -17,8 +17,7 @@ def test_device_parity_smoke_x64_off():
     code = r"""
 import os
 os.environ["TEXTBLAST_HOST_TAILS"] = "off"
-from textblaster_tpu.utils.backend_guard import force_cpu_backend
-force_cpu_backend()  # deliberately NOT enable_cpu_x64
+os.environ["JAX_PLATFORMS"] = "cpu"  # x64 deliberately left off
 from textblaster_tpu.utils.compile_cache import enable_compilation_cache
 enable_compilation_cache()
 import jax

@@ -11,7 +11,7 @@ kept/excluded Parquet pair — no broker hop.
 Layer map (TPU-native re-design of SURVEY.md §1):
 
 * :mod:`~textblaster_tpu.data_model` / :mod:`~textblaster_tpu.errors` — L1
-  foundations (document record, outcome, error taxonomy).
+  foundations (document record, outcome, error hierarchy).
 * :mod:`~textblaster_tpu.utils.text` — L1 text primitives (UAX#29-lite
   segmentation shared by host oracle and device kernels).
 * :mod:`~textblaster_tpu.config` — YAML pipeline spec + validation + CLI.

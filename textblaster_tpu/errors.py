@@ -1,4 +1,4 @@
-"""Error taxonomy for the pipeline.
+"""Error hierarchy for the pipeline.
 
 Mirrors the reference's ``PipelineError`` enum (``/root/reference/src/error.rs:9-61``)
 including the load-bearing control-flow trick: a filter signaling "drop this

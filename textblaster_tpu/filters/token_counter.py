@@ -12,7 +12,9 @@ often egress-less):
 1. a local path to a ``tokenizer.json`` file or a directory containing one;
 2. a local ``merges.txt`` (GPT-2 byte-level BPE) counted by the native C++
    core (``textblaster_tpu/native``) — no vocab ids are needed for a count;
-3. the HuggingFace hub cache / network via ``tokenizers.Tokenizer.from_pretrained``;
+3. the HuggingFace hub cache / network via ``tokenizers.Tokenizer.from_pretrained``
+   — or, under ``HF_HUB_OFFLINE=1``, the local hub cache alone (that call
+   would still reach for the network);
 4. a vendored stand-in under ``textblaster_tpu/data/tokenizers/<name>/`` —
    an in-repo-trained byte-level BPE shipped so the default config's
    ``TokenCounter(gpt2)`` executes on egress-less machines (see the README
@@ -32,6 +34,23 @@ from ..errors import UnexpectedError
 from ..executor import ProcessingStep
 
 __all__ = ["TokenCounter"]
+
+
+def _hub_tokenizer(name: str):
+    """The hub tokenizer ``name``: from the hub cache or the network, or
+    from the local hub cache only when ``HF_HUB_OFFLINE`` asks for that."""
+    from tokenizers import Tokenizer
+
+    if os.environ.get("HF_HUB_OFFLINE", "").strip().lower() in ("1", "true", "yes", "on"):
+        from huggingface_hub import try_to_load_from_cache
+
+        path = try_to_load_from_cache(name, "tokenizer.json")
+        if not isinstance(path, str):
+            raise FileNotFoundError(
+                f"tokenizer {name!r} is not in the local hub cache (HF_HUB_OFFLINE)"
+            )
+        return Tokenizer.from_file(path)
+    return Tokenizer.from_pretrained(name)
 
 
 class TokenCounter(ProcessingStep):
@@ -68,7 +87,7 @@ class TokenCounter(ProcessingStep):
                 from tokenizers import Tokenizer
 
                 try:
-                    self._tokenizer = Tokenizer.from_pretrained(tokenizer_name)
+                    self._tokenizer = _hub_tokenizer(tokenizer_name)
                 except Exception:
                     vendored = os.path.join(
                         os.path.dirname(os.path.dirname(os.path.abspath(__file__))),

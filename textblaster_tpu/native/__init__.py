@@ -3,7 +3,11 @@
 The reference's whole runtime is native Rust; here the host-side hot paths
 (UTF-8 batch packing, UAX#29-lite word segmentation, n-gram duplicate scans,
 byte-level BPE counting — see ``src/textblaster_native.cpp``) are C++,
-compiled on first use with the toolchain baked into the image.  Everything
+compiled on first use with the toolchain baked into the image.  A stamp
+next to the built library holds a hash of the source, the compiler flags and
+this machine's CPU, so a checkout copied to another machine never loads an
+object built with ``-march=native`` for a different CPU: it rebuilds the
+library in place (about 2 s).  Everything
 has a pure-Python/numpy fallback (``textblaster_tpu/utils/text.py``), which
 stays the semantic source of truth: parity tests assert the two produce
 identical results.
@@ -14,9 +18,12 @@ Set ``TEXTBLASTER_NATIVE=0`` to force the Python paths.
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import logging
 import os
+import platform
 import subprocess
+import tempfile
 import threading
 from typing import List, Optional, Tuple
 
@@ -36,8 +43,10 @@ __all__ = [
 ]
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
-_SO_PATH = os.path.join(_DIR, "libtextblaster_native.so")
 _SRC = os.path.join(_DIR, "src", "textblaster_native.cpp")
+_SO = os.path.join(_DIR, "libtextblaster_native.so")
+_STAMP = _SO + ".key"
+_FLAGS = ("-O3", "-march=native", "-std=c++17", "-fPIC", "-shared")
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
@@ -49,27 +58,61 @@ _p_i32 = np.ctypeslib.ndpointer(dtype=np.int32, flags="C_CONTIGUOUS")
 _p_i64 = np.ctypeslib.ndpointer(dtype=np.int64, flags="C_CONTIGUOUS")
 
 
-def _build() -> bool:
-    cmd = [
-        os.environ.get("CXX", "g++"),
-        "-O3",
-        "-march=native",
-        "-std=c++17",
-        "-fPIC",
-        "-shared",
-        "-o",
-        _SO_PATH,
-        _SRC,
-    ]
+def _cpu_signature() -> bytes:
+    """What ``-march=native`` compiles for: the machine and its CPU flags."""
+    sig = platform.machine()
     try:
-        proc = subprocess.run(cmd, capture_output=True, text=True, timeout=120)
-    except (OSError, subprocess.TimeoutExpired) as e:
-        logger.warning("native build failed to run: %s", e)
+        with open("/proc/cpuinfo", encoding="utf-8", errors="replace") as f:
+            for line in f:
+                if line.startswith(("flags", "Features", "model name")):
+                    sig += "\n" + line.strip()
+    except OSError:  # pragma: no cover - non-Linux host
+        pass
+    return sig.encode("utf-8")
+
+
+def _build_key(cxx: str) -> str:
+    """Names the library this source, these flags and this CPU build."""
+    h = hashlib.sha256()
+    with open(_SRC, "rb") as f:
+        h.update(f.read())
+    h.update(" ".join((cxx,) + _FLAGS).encode("utf-8"))
+    h.update(_cpu_signature())
+    return h.hexdigest()
+
+
+def _built(key: str) -> bool:
+    try:
+        with open(_STAMP, encoding="utf-8") as f:
+            return f.read() == key and os.path.exists(_SO)
+    except OSError:
         return False
-    if proc.returncode != 0:
-        logger.warning("native build failed:\n%s", proc.stderr[-2000:])
-        return False
-    return True
+
+
+def _build(cxx: str, key: str) -> bool:
+    # Build, then stamp, each under a temporary name renamed into place:
+    # concurrent first users (test workers) never load a half-written
+    # object, and a rebuild replaces the library instead of adding one.
+    fd, tmp = tempfile.mkstemp(dir=_DIR, suffix=".tmp")
+    os.close(fd)
+    try:
+        cmd = [cxx, *_FLAGS, "-o", tmp, _SRC]
+        try:
+            proc = subprocess.run(cmd, capture_output=True, text=True, timeout=120)
+        except (OSError, subprocess.TimeoutExpired) as e:
+            logger.warning("native build failed to run: %s", e)
+            return False
+        if proc.returncode != 0:
+            logger.warning("native build failed:\n%s", proc.stderr[-2000:])
+            return False
+        os.replace(tmp, _SO)
+        with open(tmp, "w", encoding="utf-8") as f:
+            f.write(key)
+        os.replace(tmp, _STAMP)
+        return True
+    finally:
+        if os.path.exists(tmp):
+            os.unlink(tmp)
 
 
 def _load() -> Optional[ctypes.CDLL]:
@@ -82,14 +125,12 @@ def _load() -> Optional[ctypes.CDLL]:
         _tried = True
         if os.environ.get("TEXTBLASTER_NATIVE", "1") == "0":
             return None
-        if not os.path.exists(_SO_PATH) or (
-            os.path.exists(_SRC)
-            and os.path.getmtime(_SRC) > os.path.getmtime(_SO_PATH)
-        ):
-            if not _build():
-                return None
+        cxx = os.environ.get("CXX", "g++")
+        key = _build_key(cxx)
+        if not _built(key) and not _build(cxx, key):
+            return None
         try:
-            lib = ctypes.CDLL(_SO_PATH)
+            lib = ctypes.CDLL(_SO)
         except OSError as e:
             logger.warning("native library failed to load: %s", e)
             return None

@@ -39,7 +39,9 @@ Escape hatches / fallback:
   multi-pass megakernel (:func:`chain_scan`) — callers fall back to the
   staged schedule (which may still use :func:`fused_scan` for its
   independent groups).
-* Non-TPU backends fall back automatically.  ``TEXTBLAST_PALLAS_INTERPRET=1``
+* Non-TPU backends take the lax scans.  On a TPU a kernel that fails its
+  probe raises instead: only the hatches above choose the lax schedule
+  there.  ``TEXTBLAST_PALLAS_INTERPRET=1``
   forces the interpret-mode kernel anywhere — how the fuzz suite runs the
   exact kernel program under tier-1 on CPU.
 * Mosaic ``pallas_call`` custom calls carry no GSPMD partitioning rule, so
@@ -56,7 +58,6 @@ from __future__ import annotations
 
 import contextlib
 import functools
-import logging
 import os
 import threading
 from typing import Callable, Dict, List, NamedTuple, Optional, Sequence, Tuple
@@ -67,15 +68,17 @@ from jax.experimental import pallas as pl
 from jax.sharding import Mesh, PartitionSpec as P
 
 from .pallas_sort import (
+    COMPILER_PARAMS,
     ROWS,
+    count_scan_dispatches,
     interpret_forced,
     pallas_enabled,
+    pallas_sort_supported,
     pltpu,
+    record_scan_dispatch,
     roll_lanes,
     shard_map,
 )
-
-logger = logging.getLogger(__name__)
 
 __all__ = [
     "Tap",
@@ -86,6 +89,7 @@ __all__ = [
     "chain_pass",
     "chain_scan",
     "chain_scan_ok",
+    "chain_scan_supported",
     "copy_group",
     "count_scan_dispatches",
     "depfuse_enabled",
@@ -94,9 +98,11 @@ __all__ = [
     "fused_enabled",
     "fused_scan",
     "fused_scan_ok",
+    "fused_scan_supported",
     "mesh_tracing",
     "pallas_scan_ok",
     "pallas_scan_supported",
+    "probe_kernels",
     "record_scan_dispatch",
     "segmax_group",
 ]
@@ -107,7 +113,10 @@ __all__ = [
 #: while shaving the upper doubling levels of long buckets.
 _BLK = 512
 
-_MAX_LANES = 65536  # beyond this the [8, L] tile no longer fits VMEM comfortably
+#: Widest row the per-scan kernels take (the widest bucket).  Each stream's
+#: [8, L] int32 block is double-buffered in VMEM: a 3-stream scan at 65536
+#: lanes asks for 24 MiB, within :data:`COMPILER_PARAMS`' limit.
+_MAX_LANES = 65536
 
 #: The fused kernel holds every group's input *and* output tiles resident at
 #: once, so its lane ceiling is tighter than the 2–4-stream per-scan kernels.
@@ -167,36 +176,6 @@ def _current_mesh() -> Optional[Mesh]:
         if shards is not None and shards > 1:
             return state
     return None
-
-
-# --- dispatch accounting ----------------------------------------------------
-#
-# bench.py's BENCH_FUSED A/B counts how many scan dispatches one traced
-# (bucket, phase) program issues — the figure the fused kernel exists to
-# shrink.  Recording is thread-local and a no-op unless a
-# count_scan_dispatches() scope is active, so the hot path pays one getattr.
-
-
-def record_scan_dispatch(kind: str) -> None:
-    """Count one scan dispatch of ``kind`` ("fused", "pallas_scan",
-    "lax_scan") if a :func:`count_scan_dispatches` scope is active."""
-    counts = getattr(_tls, "dispatch_counts", None)
-    if counts is not None:
-        counts[kind] = counts.get(kind, 0) + 1
-
-
-@contextlib.contextmanager
-def count_scan_dispatches():
-    """Collect per-kind scan dispatch counts issued while tracing under this
-    scope (trace-time accounting: each recorded dispatch is one device
-    kernel/scan in the lowered program)."""
-    prev = getattr(_tls, "dispatch_counts", None)
-    counts: Dict[str, int] = {}
-    _tls.dispatch_counts = counts
-    try:
-        yield counts
-    finally:
-        _tls.dispatch_counts = prev
 
 
 def _blk_for(length: int) -> int:
@@ -265,6 +244,7 @@ def _pallas_scan_tuple(
             in_specs=[spec] * n,
             out_specs=[spec] * n,
             out_shape=[shape] * n,
+            compiler_params=COMPILER_PARAMS,
             interpret=interpret,
         )(*(x.astype(jnp.int32) for x in xs))
     )
@@ -459,6 +439,7 @@ def _fused_call(groups: Sequence[dict], interpret: bool) -> Tuple[jax.Array, ...
             in_specs=[row_spec] * len(xs),
             out_specs=out_specs,
             out_shape=out_shapes,
+            compiler_params=COMPILER_PARAMS,
             interpret=interpret,
         )(*(x.astype(jnp.int32) for x in xs))
     )
@@ -483,13 +464,9 @@ def _shard_mapped(fn: Callable, mesh: Mesh, xs: Tuple[jax.Array, ...], n_out: in
     pattern.  Rows are independent, so no collective is inserted."""
     spec = P(_DATA_AXIS, None)
     kwargs = dict(mesh=mesh, in_specs=(spec,) * len(xs), out_specs=(spec,) * n_out)
-    try:
-        # Replication checking needs vma annotations pallas outputs don't
-        # carry; rows are fully sharded, nothing is replicated — disable it.
-        mapped = shard_map(fn, check_vma=False, **kwargs)
-    except TypeError:  # pre-vma JAX spells it check_rep
-        mapped = shard_map(fn, check_rep=False, **kwargs)
-    return mapped(*xs)
+    # Replication checking needs vma annotations pallas outputs don't carry;
+    # rows are fully sharded, nothing is replicated — disable it.
+    return shard_map(fn, check_vma=False, **kwargs)(*xs)
 
 
 def _dispatch_scan_tuple(
@@ -525,32 +502,61 @@ def _env_hatches() -> Tuple[str, ...]:
     )
 
 
+def _probe_error(what: str, detail: str) -> RuntimeError:
+    """A probe that fails on a TPU is a fault, not a reason to switch to the
+    lax schedule behind the user's back: callers raise this.  The env
+    hatches (``TEXTBLAST_PALLAS``/``_FUSED``/``_DEPFUSE=off``) are the
+    explicit way to run without a kernel."""
+    return RuntimeError(
+        f"{what} failed its probe on TPU: {detail}.  Set the matching "
+        "TEXTBLAST_PALLAS/_FUSED/_DEPFUSE=off hatch to run without it"
+    )
+
+
+def _scan_probe(interpret: bool = False) -> bool:
+    """One tiny per-scan kernel against ``lax.associative_scan``."""
+    m = jnp.full((ROWS, 128), 31, jnp.int32)
+    a = (jax.lax.broadcasted_iota(jnp.int32, (ROWS, 128), 1) * 7) % 97
+    got = _pallas_scan_tuple(_affine_op, (1, 0), (m, a), interpret=interpret)
+    want = jax.lax.associative_scan(_affine_op, (m, a), axis=1)
+    return all(bool(jnp.array_equal(g, w)) for g, w in zip(got, want))
+
+
 @functools.lru_cache(maxsize=32)
 def _probe_cached(env: Tuple[str, ...], backend: str) -> bool:
-    """Compile and run one tiny kernel on the live backend, checking it
-    against the lax result — Mosaic availability differs per
-    backend/runtime version and a failed probe must mean fallback, not a
-    crashed pipeline."""
+    """Compile and run one tiny kernel on the live backend and check it
+    against the lax result.  Only a TPU lowers Mosaic kernels: other
+    backends answer False (their callers take the lax scans), and a TPU on
+    which the probe fails raises."""
     del env  # participates only in the cache key
-    if pltpu is None or backend == "cpu":
+    if backend != "tpu":
         return False
     try:
-        with jax.ensure_compile_time_eval():
-            m = jnp.full((ROWS, 128), 31, jnp.int32)
-            a = (jax.lax.broadcasted_iota(jnp.int32, (ROWS, 128), 1) * 7) % 97
-            got = _pallas_scan_tuple(_affine_op, (1, 0), (m, a), interpret=False)
-            want = jax.lax.associative_scan(_affine_op, (m, a), axis=1)
-            ok = all(bool(jnp.array_equal(g, w)) for g, w in zip(got, want))
-        if not ok:  # pragma: no cover - would be a Mosaic miscompile
-            logger.warning("Pallas scan probe mismatch; using lax scans")
-        return ok
-    except Exception as e:  # pragma: no cover - backend-specific
-        logger.warning("Pallas scan unavailable on %s: %s", backend, e)
-        return False
+        ok = _scan_probe()
+    except Exception as e:
+        raise _probe_error("Pallas scan kernel", f"{type(e).__name__}: {e}") from e
+    if not ok:
+        raise _probe_error("Pallas scan kernel", "result differs from lax")
+    return True
 
 
 def _probe_backend() -> bool:
     return _probe_cached(_env_hatches(), jax.default_backend())
+
+
+def _fused_probe(interpret: bool = False) -> bool:
+    """One tiny fused kernel, an emit="last" group included."""
+    m = jnp.full((ROWS, 128), 31, jnp.int32)
+    a = (jax.lax.broadcasted_iota(jnp.int32, (ROWS, 128), 1) * 7) % 97
+    ones = jnp.ones((ROWS, 128), jnp.int32)
+    got = _fused_call(
+        [affine_group(m, (a,)), add_group((ones,), emit="last")],
+        interpret=interpret,
+    )
+    want_h = jax.lax.associative_scan(_affine_op, (m, a), axis=1)[1]
+    return bool(jnp.array_equal(got[0], want_h)) and bool(
+        jnp.array_equal(got[1], jnp.full((ROWS, 1), 128, jnp.int32))
+    )
 
 
 @functools.lru_cache(maxsize=32)
@@ -558,27 +564,15 @@ def _probe_fused_cached(env: Tuple[str, ...], backend: str) -> bool:
     """Probe the fused megakernel specifically: its emit="last" outputs use
     a narrower BlockSpec the per-scan probe never exercises."""
     del env
-    if pltpu is None or backend == "cpu":
+    if backend != "tpu":
         return False
     try:
-        with jax.ensure_compile_time_eval():
-            m = jnp.full((ROWS, 128), 31, jnp.int32)
-            a = (jax.lax.broadcasted_iota(jnp.int32, (ROWS, 128), 1) * 7) % 97
-            ones = jnp.ones((ROWS, 128), jnp.int32)
-            got = _fused_call(
-                [affine_group(m, (a,)), add_group((ones,), emit="last")],
-                interpret=False,
-            )
-            want_h = jax.lax.associative_scan(_affine_op, (m, a), axis=1)[1]
-            ok = bool(jnp.array_equal(got[0], want_h)) and bool(
-                jnp.array_equal(got[1], jnp.full((ROWS, 1), 128, jnp.int32))
-            )
-        if not ok:  # pragma: no cover - would be a Mosaic miscompile
-            logger.warning("fused scan probe mismatch; using staged scans")
-        return ok
-    except Exception as e:  # pragma: no cover - backend-specific
-        logger.warning("fused scan unavailable on %s: %s", backend, e)
-        return False
+        ok = _fused_probe()
+    except Exception as e:
+        raise _probe_error("fused scan kernel", f"{type(e).__name__}: {e}") from e
+    if not ok:
+        raise _probe_error("fused scan kernel", "result differs from lax")
+    return True
 
 
 def _probe_fused() -> bool:
@@ -622,16 +616,20 @@ def pallas_scan_ok(b: int, length: int) -> bool:
     )
 
 
+def fused_scan_supported() -> bool:
+    """Whether the fused megakernel can run here: the scan kernels, its own
+    hatch, and its own probe."""
+    if not (fused_enabled() and pallas_scan_supported()):
+        return False
+    return interpret_forced() or _probe_fused()
+
+
 def fused_scan_ok(b: int, length: int) -> bool:
     """Gate for :func:`fused_scan` — the per-scan gate plus the fused
     kernel's own hatch, probe, and tighter VMEM lane ceiling."""
-    if not fused_enabled():
+    if not pallas_scan_ok(b, length) or length > _FUSED_MAX_LANES:
         return False
-    if not pallas_scan_ok(b, length):
-        return False
-    if length > _FUSED_MAX_LANES:
-        return False
-    return interpret_forced() or _probe_fused()
+    return fused_scan_supported()
 
 
 # --- public kernels ---------------------------------------------------------
@@ -700,12 +698,13 @@ def fused_scan(groups: Sequence[dict]) -> List[Tuple[jax.Array, ...]]:
 # handoff each walk the packed tile once instead of 2-4 staged dispatches.
 #
 # Orientation: every stream (external or emitted) is stored in natural lane
-# order.  A pass with reverse=True *walks* the row tile back-to-front (its
-# lane blocks are loaded mirrored + flipped into "walk order", scanned, and
-# written back flipped), which computes the staged ``rev(scan(rev(x)))``
-# idiom bit-exactly while emitting the result already in natural
-# orientation.  Prep callables always see walk-ordered blocks; since they
-# are elementwise, flip commutes and parity is preserved.
+# order.  A pass with reverse=True *walks* the row tile back-to-front: its
+# lane blocks are visited last to first and scanned right-to-left in place
+# (the doubling reads lane j+d instead of j-d, the carry is lane 0), which
+# computes the staged ``rev(scan(rev(x)))`` idiom bit-exactly while emitting
+# the result already in natural orientation.  Nothing is flipped inside the
+# kernel — Mosaic has no lane reversal.  Prep callables are elementwise, so
+# block orientation does not matter to them.
 #
 # Tap(pass_idx, out_idx, shift, fill) addresses the ``out_idx``-th emitted
 # stream (flattened over that pass's groups, all emit modes counted) of an
@@ -714,8 +713,7 @@ def fused_scan(groups: Sequence[dict]) -> List[Tuple[jax.Array, ...]]:
 # pass), with ``fill`` injected at walk position 0.  Shifted *external*
 # operands never need kernel support — callers pre-shift them on the host
 # (elementwise, exact).  emit="none" streams are tap-only: they live in VMEM
-# scratch (``pltpu.VMEM``) and never touch HBM; when pltpu is unavailable
-# (interpret-only platforms) they degrade to discarded outputs.
+# scratch (``pltpu.VMEM``) and never touch HBM.
 
 
 class Tap(NamedTuple):
@@ -774,11 +772,10 @@ def _chain_plan(passes: Sequence[dict]):
     ext_arrays: List[jax.Array] = []
     ext_index: Dict[int, int] = {}
     stream_table: List[List[Tuple[Tuple[str, int], str]]] = []
-    out_modes: List[str] = []  # per out slot: "scan" | "last" | "drop"
+    out_modes: List[str] = []  # per out slot: "scan" | "last"
     n_scratch = 0
     plan = []
     layout: List[List[List[int]]] = []
-    use_scratch = pltpu is not None
     for p_idx, pss in enumerate(passes):
         groups_plan = []
         pass_streams: List[Tuple[Tuple[str, int], str]] = []
@@ -809,14 +806,13 @@ def _chain_plan(passes: Sequence[dict]):
             streams: List[Tuple[str, int]] = []
             g_layout: List[int] = []
             for _ in spec[3]:
-                if emit == "none" and use_scratch:
+                if emit == "none":
                     storage = ("scratch", n_scratch)
                     n_scratch += 1
                 else:
                     storage = ("out", len(out_modes))
-                    out_modes.append("drop" if emit == "none" else emit)
-                    if emit != "none":
-                        g_layout.append(storage[1])
+                    out_modes.append(emit)
+                    g_layout.append(storage[1])
                 streams.append(storage)
                 pass_streams.append((storage, emit))
             groups_plan.append(
@@ -849,33 +845,44 @@ def _chain_body(plan, refs, n_ext: int, n_out: int) -> None:
     for pss in plan:
         reverse = pss["reverse"]
         groups = pss["groups"]
+        # Walk-order neighbour: the lane one step earlier in the walk sits
+        # at natural offset -1 (forward) or +1 (reverse).  A circular right
+        # roll by ``step`` brings it into place; ``edge`` is the lane whose
+        # neighbour lies outside the block.
+        step, edge = (blk - 1, blk - 1) if reverse else (1, 0)
+
+        def block_start(b_i):
+            # A reverse pass walks the blocks back to front, each block kept
+            # in natural lane order (Mosaic has no lane reversal).
+            start = length - (b_i + 1) * blk if reverse else b_i * blk
+            return pl.multiple_of(start, blk)
 
         def load(ref, b_i, shift, fill):
-            start = b_i * blk
-            if reverse:
-                # Mirrored block, flipped into walk order: walk lane w of
-                # block b_i is natural lane length-1-(b_i*blk+w).
-                x = jnp.flip(ref[:, pl.ds(length - start - blk, blk)], axis=1)
-            else:
-                x = ref[:, pl.ds(start, blk)]
+            start = block_start(b_i)
+            x = ref[:, pl.ds(start, blk)]
             if shift:
-                # Previous-walk-position value: the natural lane just past
-                # this block's walk start (clamped; unused when b_i == 0,
-                # where ``fill`` is injected instead).
+                # Previous-walk-position value across the block edge: the
+                # natural lane just before (forward) or after (reverse) this
+                # block, read from the 128-lane tile holding it (Mosaic loads
+                # only whole tiles at dynamic offsets).  Clamped; unused when
+                # b_i == 0, where ``fill`` is injected instead.
                 if reverse:
-                    prev_idx = jnp.minimum(length - start, length - 1)
+                    tile = jnp.minimum(start + blk, length - 128)
+                    pick = slice(0, 1)
                 else:
-                    prev_idx = jnp.maximum(start - 1, 0)
+                    tile = jnp.maximum(start - 128, 0)
+                    pick = slice(127, 128)
+                tile = pl.multiple_of(tile, 128)
                 prev = jnp.where(
                     b_i == 0,
                     jnp.full((rows, 1), fill, jnp.int32),
-                    ref[:, pl.ds(prev_idx, 1)],
+                    ref[:, pl.ds(tile, 128)][:, pick],
                 )
-                x = jnp.where(lane < 1, prev, roll_lanes(x, 1))
+                x = jnp.where(lane == edge, prev, roll_lanes(x, step))
             return x
 
         def body(b_i, carry):
-            start = b_i * blk
+            start = block_start(b_i)
             new_carry = []
             for gi, g in enumerate(groups):
                 op, identities, _, emit_idx, emit_last = g["spec"]
@@ -892,8 +899,14 @@ def _chain_body(plan, refs, n_ext: int, n_out: int) -> None:
                     idents = tuple(jnp.int32(v) for v in identities)
                     d2 = 1
                     while d2 < blk:
+                        # Walk-earlier lane d2 steps back: j-d2 forward,
+                        # j+d2 (a right roll by blk-d2) in reverse.
+                        if reverse:
+                            valid, sh = lane < blk - d2, blk - d2
+                        else:
+                            valid, sh = lane >= d2, d2
                         shifted = tuple(
-                            jnp.where(lane >= d2, roll_lanes(x, d2), ident)
+                            jnp.where(valid, roll_lanes(x, sh), ident)
                             for x, ident in zip(xs, idents)
                         )
                         xs = op(shifted, xs)
@@ -901,15 +914,10 @@ def _chain_body(plan, refs, n_ext: int, n_out: int) -> None:
                     xs = op(carry[gi], xs)
                 if not emit_last:
                     for storage, x_idx in zip(g["streams"], emit_idx):
-                        r = ref_for(storage)
-                        if reverse:
-                            r[:, pl.ds(length - start - blk, blk)] = jnp.flip(
-                                xs[x_idx], axis=1
-                            )
-                        else:
-                            r[:, pl.ds(start, blk)] = xs[x_idx]
+                        ref_for(storage)[:, pl.ds(start, blk)] = xs[x_idx]
+                last = 0 if reverse else blk - 1  # the block's last walk lane
                 new_carry.append(
-                    tuple(x[:, blk - 1 : blk] for x in xs) if op is not None else ()
+                    tuple(x[:, last : last + 1] for x in xs) if op is not None else ()
                 )
             return tuple(new_carry)
 
@@ -952,6 +960,7 @@ def _chain_call(plan, ext_arrays, out_modes, n_scratch: int, interpret: bool):
             in_specs=[row_spec] * n_ext,
             out_specs=out_specs,
             out_shape=out_shapes,
+            compiler_params=COMPILER_PARAMS,
             interpret=interpret,
             **kwargs,
         )(*(x.astype(jnp.int32) for x in ext_arrays))
@@ -989,101 +998,125 @@ def depfuse_enabled() -> bool:
     return os.environ.get("TEXTBLAST_DEPFUSE", "").lower() not in ("off", "0", "false")
 
 
+def _chain_probe(interpret: bool = False) -> bool:
+    """A two-block chain: reverse walks, cross-pass and shift taps, VMEM
+    scratch and the segmented-max op, against the staged lax schedule."""
+    L = 1024
+    iota = jax.lax.broadcasted_iota(jnp.int32, (ROWS, L), 1)
+    vals = (iota * 7 + 3) % 97
+    reset = ((iota % 64) == 0).astype(jnp.int32)
+    m = jnp.where(reset != 0, 0, 1)
+    probe_passes = [
+            chain_pass([{"kind": "affine", "xs": (m, vals), "emit": "none"}]),
+            chain_pass(
+                [
+                    chain_group(
+                        "segmax",
+                        (Tap(0, 0), reset),
+                        prep=lambda seg, r: (jnp.where(r != 0, seg, 0), r),
+                        n_ops=2,
+                    )
+                ],
+                reverse=True,
+            ),
+            chain_pass(
+                [
+                    chain_group(
+                        "copy",
+                        (Tap(1, 0), Tap(0, 0, shift=1, fill=0)),
+                        prep=lambda rt, prev: (rt + prev,),
+                        n_ops=1,
+                        emit="scan",
+                    ),
+                    chain_group(
+                        "add",
+                        (Tap(1, 0),),
+                        prep=lambda rt: (jnp.where(rt > 50, 1, 0),),
+                        n_ops=1,
+                        emit="last",
+                    ),
+                ]
+            ),
+        ]
+    plan, ext, modes, n_scr, layout = _chain_plan(probe_passes)
+    flat = _chain_call(plan, tuple(ext), modes, n_scr, interpret=interpret)
+    got = [
+        [tuple(flat[s] for s in g_slots) for g_slots in p_layout]
+        for p_layout in layout
+    ]
+    seg = jax.lax.associative_scan(_affine_op, (m, vals), axis=1)[1]
+    rt = jnp.flip(
+        jax.lax.associative_scan(
+            _segmax_op,
+            (
+                jnp.flip(jnp.where(reset != 0, seg, 0), 1),
+                jnp.flip(reset, 1),
+            ),
+            axis=1,
+        )[0],
+        1,
+    )
+    prev = jnp.concatenate([jnp.zeros((ROWS, 1), jnp.int32), seg[:, :-1]], 1)
+    ok = (
+        bool(jnp.array_equal(got[2][0][0], rt + prev))
+        and bool(
+            jnp.array_equal(
+                got[2][1][0],
+                jnp.sum(jnp.where(rt > 50, 1, 0), axis=1, keepdims=True),
+            )
+        )
+        and bool(jnp.array_equal(got[1][0][0], rt))
+    )
+    return ok
+
+
 @functools.lru_cache(maxsize=32)
 def _probe_depfuse_cached(env: Tuple[str, ...], backend: str) -> bool:
-    """Probe the chain kernel specifically: reverse-walk passes (lane
-    flips), cross-pass taps, shift taps, VMEM scratch staging, and the
+    """Probe the chain kernel specifically: reverse-walk passes, cross-pass
+    taps, shift taps, VMEM scratch staging, and the
     segmented-max op exercise Mosaic surface the fused probe never
     touches."""
     del env
-    if pltpu is None or backend == "cpu":
+    if backend != "tpu":
         return False
     try:
-        with jax.ensure_compile_time_eval():
-            L = 256
-            iota = jax.lax.broadcasted_iota(jnp.int32, (ROWS, L), 1)
-            vals = (iota * 7 + 3) % 97
-            reset = ((iota % 64) == 0).astype(jnp.int32)
-            m = jnp.where(reset != 0, 0, 1)
-            probe_passes = [
-                    chain_pass([{"kind": "affine", "xs": (m, vals), "emit": "none"}]),
-                    chain_pass(
-                        [
-                            chain_group(
-                                "segmax",
-                                (Tap(0, 0), reset),
-                                prep=lambda seg, r: (jnp.where(r != 0, seg, 0), r),
-                                n_ops=2,
-                            )
-                        ],
-                        reverse=True,
-                    ),
-                    chain_pass(
-                        [
-                            chain_group(
-                                "copy",
-                                (Tap(1, 0), Tap(0, 0, shift=1, fill=0)),
-                                prep=lambda rt, prev: (rt + prev,),
-                                n_ops=1,
-                                emit="scan",
-                            ),
-                            chain_group(
-                                "add",
-                                (Tap(1, 0),),
-                                prep=lambda rt: (jnp.where(rt > 50, 1, 0),),
-                                n_ops=1,
-                                emit="last",
-                            ),
-                        ]
-                    ),
-                ]
-            plan, ext, modes, n_scr, layout = _chain_plan(probe_passes)
-            flat = _chain_call(plan, tuple(ext), modes, n_scr, interpret=False)
-            got = [
-                [tuple(flat[s] for s in g_slots) for g_slots in p_layout]
-                for p_layout in layout
-            ]
-            seg = jax.lax.associative_scan(_affine_op, (m, vals), axis=1)[1]
-            rt = jnp.flip(
-                jax.lax.associative_scan(
-                    _segmax_op,
-                    (
-                        jnp.flip(jnp.where(reset != 0, seg, 0), 1),
-                        jnp.flip(reset, 1),
-                    ),
-                    axis=1,
-                )[0],
-                1,
-            )
-            prev = jnp.concatenate([jnp.zeros((ROWS, 1), jnp.int32), seg[:, :-1]], 1)
-            ok = (
-                bool(jnp.array_equal(got[2][0][0], rt + prev))
-                and bool(
-                    jnp.array_equal(
-                        got[2][1][0],
-                        jnp.sum(jnp.where(rt > 50, 1, 0), axis=1, keepdims=True),
-                    )
-                )
-                and bool(jnp.array_equal(got[1][0][0], rt))
-            )
-        if not ok:  # pragma: no cover - would be a Mosaic miscompile
-            logger.warning("chain scan probe mismatch; using staged scans")
-        return ok
-    except Exception as e:  # pragma: no cover - backend-specific
-        logger.warning("chain scan unavailable on %s: %s", backend, e)
-        return False
+        ok = _chain_probe()
+    except Exception as e:
+        raise _probe_error("chain scan kernel", f"{type(e).__name__}: {e}") from e
+    if not ok:
+        raise _probe_error("chain scan kernel", "result differs from lax")
+    return True
 
 
 def _probe_depfuse() -> bool:
     return _probe_depfuse_cached(_env_hatches(), jax.default_backend())
 
 
+def chain_scan_supported() -> bool:
+    """Whether the dependency-chain kernel can run here: the fused kernel,
+    the dependency-fusion hatch, and its own probe."""
+    if not (depfuse_enabled() and fused_scan_supported()):
+        return False
+    return interpret_forced() or _probe_depfuse()
+
+
 def chain_scan_ok(b: int, length: int) -> bool:
     """Gate for :func:`chain_scan` — the fused gate (so ``TEXTBLAST_FUSED``
     and the mesh/shape rules compose) plus the dependency-fusion hatch and
     its own backend probe."""
-    if not depfuse_enabled():
-        return False
-    if not fused_scan_ok(b, length):
-        return False
-    return interpret_forced() or _probe_depfuse()
+    return fused_scan_ok(b, length) and chain_scan_supported()
+
+
+def probe_kernels() -> Dict[str, bool]:
+    """Resolve every kernel gate now and return what each says.
+
+    A probe compiles and runs a tiny kernel, which it cannot do from inside
+    another program's trace, so callers that trace kernels ask this first
+    (``CompiledPipeline`` does on construction); the gates asked later while
+    tracing read the cached verdicts."""
+    return {
+        "pallas_sort": pallas_sort_supported(),
+        "pallas_scan": pallas_scan_supported(),
+        "fused_scan": fused_scan_supported(),
+        "chain_scan": chain_scan_supported(),
+    }

@@ -9,11 +9,13 @@ rows resident in VMEM for the entire bitonic network, so the ~log²(m)/2
 stages cost lane-shuffles (``pltpu.roll``) and VPU selects instead of HBM
 bandwidth.
 
-The network is a standard bitonic sorter: static Python loops over
-``(size, stride)`` stages — everything unrolls at trace time, all shapes
-static, no gathers (partner access is a pair of circular lane shifts selected
-by a constant parity mask), which keeps the kernel inside Mosaic's supported
-op set.
+The network is a standard bitonic sorter: two nested ``fori_loop`` calls over
+the ``(size, stride)`` stages, the keys held in the output refs between
+stages, all shapes static, no gathers (partner access is a pair of circular
+lane shifts selected by a per-stage parity mask), which keeps the kernel
+inside Mosaic's supported op set.  The loops keep Mosaic's compile time flat
+in the row length: unrolled, the ~log2(m)^2/2 stages took 41 s to compile at
+8192 lanes and grew ~4x per doubling; looped, 65536 lanes compile in ~7 s.
 
 Rows are independent; the grid tiles the batch dimension.  Row length must be
 a power of two (all duplicate tables in :mod:`.stats` are sized to powers of
@@ -34,27 +36,18 @@ pallas_call program the TPU runs, minus the Mosaic lowering.
 
 from __future__ import annotations
 
+import contextlib
 import functools
-import logging
 import os
-from typing import Optional, Tuple
+import threading
+from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu  # lowering is TPU-only
 from jax.sharding import Mesh, PartitionSpec as P
-
-try:  # moved out of experimental in newer JAX
-    from jax import shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map
-
-try:  # pltpu is importable on all platforms; lowering is TPU-only.
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
-
-logger = logging.getLogger(__name__)
 
 __all__ = [
     "sort2",
@@ -67,11 +60,52 @@ __all__ = [
     "interpret_forced",
     "pallas_enabled",
     "roll_lanes",
+    "COMPILER_PARAMS",
+    "count_scan_dispatches",
+    "record_scan_dispatch",
     "shard_map",
 ]
 
 _ROWS = 8  # sublane tile for int32
 ROWS = _ROWS
+
+#: Scoped VMEM every kernel may use.  The compiler's default is 16 MiB,
+#: which the widest buckets' row tiles overflow (a 3-stream scan at 65536
+#: lanes asks for 24 MiB, a 3-key sort for ~54 MiB); v5e has 128 MiB.
+COMPILER_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=64 * 2**20)
+
+_tls = threading.local()
+
+
+# --- dispatch accounting ----------------------------------------------------
+#
+# bench.py's BENCH_FUSED A/B and chip_smoke.py count the kernels one traced
+# (bucket, phase) program issues.  Recording is thread-local and a no-op
+# unless a count_scan_dispatches() scope is active, so the hot path pays one
+# getattr.
+
+
+def record_scan_dispatch(kind: str) -> None:
+    """Count one dispatch of ``kind`` ("fused", "pallas_scan", "lax_scan",
+    "pallas_sort", "lax_sort") if a :func:`count_scan_dispatches` scope is
+    active."""
+    counts = getattr(_tls, "dispatch_counts", None)
+    if counts is not None:
+        counts[kind] = counts.get(kind, 0) + 1
+
+
+@contextlib.contextmanager
+def count_scan_dispatches():
+    """Collect per-kind dispatch counts issued while tracing under this
+    scope (trace-time accounting: each recorded dispatch is one device
+    kernel/scan/sort in the lowered program)."""
+    prev = getattr(_tls, "dispatch_counts", None)
+    counts: Dict[str, int] = {}
+    _tls.dispatch_counts = counts
+    try:
+        yield counts
+    finally:
+        _tls.dispatch_counts = prev
 
 #: Mesh axis the batch dimension is sharded over (parallel.mesh.DATA_AXIS;
 #: duplicated here to keep this module importable standalone).
@@ -111,9 +145,7 @@ def roll_lanes(k: jax.Array, shift: int) -> jax.Array:
     non-negative shifts; callers spell a left-roll by ``s`` as a right-roll
     by ``lanes - s``.  Works under interpret mode too (generic lowering ==
     ``jnp.roll``), so CPU tests run the exact kernel program the TPU lowers."""
-    if pltpu is not None:
-        return pltpu.roll(k, shift=shift, axis=1)
-    return jnp.roll(k, shift, axis=1)  # pragma: no cover - pltpu unavailable
+    return pltpu.roll(k, shift=shift, axis=1)
 
 
 _roll = roll_lanes
@@ -123,38 +155,43 @@ def _bitonic_kernel(*refs):
     n = len(refs) // 2
     in_refs, out_refs = refs[:n], refs[n:]
     m = in_refs[0].shape[-1]
-    ks = tuple(r[:] for r in in_refs)
+    for i, o in zip(in_refs, out_refs):
+        o[:] = i[:]
 
     # In-kernel lane index (Pallas kernels cannot capture host constants).
     lane = jax.lax.broadcasted_iota(jnp.int32, (1, m), 1)
-    size = 2
-    while size <= m:
-        stride = size // 2
-        while stride >= 1:
-            # Per-lane masks for this stage (stage parameters are static).
-            is_lower = (lane & stride) == 0  # partner is at i+stride
-            asc = (lane & size) == 0
 
-            # pltpu.roll requires non-negative shifts; left-roll by `stride`
-            # is a right-roll by `m - stride`.
-            partners = tuple(
-                jnp.where(is_lower, _roll(k, m - stride), _roll(k, stride))
-                for k in ks
-            )
-            lower = tuple(jnp.where(is_lower, k, p) for k, p in zip(ks, partners))
-            upper = tuple(jnp.where(is_lower, p, k) for k, p in zip(ks, partners))
-            # Select between the two bool comparisons with i1 bitwise logic:
-            # Mosaic cannot lower `select_n` with bool *operands* at >1 lane
-            # tile (arith.trunci vector<i8> -> vector<i1> is unsupported).
-            swap = (asc & _lex_gt(lower, upper)) | (
-                jnp.logical_not(asc) & _lex_gt(upper, lower)
-            )
-            ks = tuple(jnp.where(swap, p, k) for k, p in zip(ks, partners))
-            stride //= 2
-        size *= 2
+    def stage(j, size):
+        # One compare-exchange stage of the size-``size`` merge; the keys
+        # live in the output refs between stages.
+        stride = jnp.right_shift(size, j + 1)
+        ks = tuple(o[:] for o in out_refs)
+        is_lower = (lane & stride) == 0  # partner is at i+stride
+        asc = (lane & size) == 0
+        # pltpu.roll requires non-negative shifts; left-roll by `stride`
+        # is a right-roll by `m - stride`.
+        partners = tuple(
+            jnp.where(is_lower, _roll(k, m - stride), _roll(k, stride))
+            for k in ks
+        )
+        lower = tuple(jnp.where(is_lower, k, p) for k, p in zip(ks, partners))
+        upper = tuple(jnp.where(is_lower, p, k) for k, p in zip(ks, partners))
+        # Select between the two bool comparisons with i1 bitwise logic:
+        # Mosaic cannot lower `select_n` with bool *operands* at >1 lane
+        # tile (arith.trunci vector<i8> -> vector<i1> is unsupported).
+        swap = (asc & _lex_gt(lower, upper)) | (
+            jnp.logical_not(asc) & _lex_gt(upper, lower)
+        )
+        for o, k, p in zip(out_refs, ks, partners):
+            o[:] = jnp.where(swap, p, k)
+        return size
 
-    for o, k in zip(out_refs, ks):
-        o[:] = k
+    def merge(log_size, carry):
+        size = jnp.left_shift(jnp.int32(1), log_size)
+        jax.lax.fori_loop(0, log_size, stage, size)
+        return carry
+
+    jax.lax.fori_loop(1, m.bit_length(), merge, 0)
 
 
 def _pallas_sort_n(ks: Tuple[jax.Array, ...], interpret: bool = False):
@@ -173,6 +210,7 @@ def _pallas_sort_n(ks: Tuple[jax.Array, ...], interpret: bool = False):
         in_specs=[spec] * len(ks),
         out_specs=[spec] * len(ks),
         out_shape=[shape] * len(ks),
+        compiler_params=COMPILER_PARAMS,
         interpret=interpret,
     )(*(k.astype(jnp.int32) for k in ks))
 
@@ -202,19 +240,32 @@ def _env_hatches() -> Tuple[str, ...]:
     )
 
 
+def _sort_probe(interpret: bool = False) -> bool:
+    """One tiny sort against ``jnp.sort``."""
+    k = (jax.lax.broadcasted_iota(jnp.int32, (_ROWS, 128), 1) * 37) % 101
+    got = pallas_sort2(k, k, interpret=interpret)
+    return bool(jnp.array_equal(got[0], jnp.sort(k, axis=1)))
+
+
 @functools.lru_cache(maxsize=32)
 def _probe_cached(env: Tuple[str, ...], backend: str) -> bool:
+    """Compile and run one tiny sort on the live backend.  Only a TPU
+    lowers Mosaic kernels: other backends answer False (callers take
+    ``lax.sort``), and a TPU on which the kernel fails raises — the
+    ``TEXTBLAST_PALLAS=off`` hatch is the explicit way to run without it."""
     del env  # participates only in the cache key
-    if pltpu is None or backend == "cpu":
+    if backend != "tpu":
         return False
     try:
-        with jax.ensure_compile_time_eval():
-            x = jnp.zeros((_ROWS, 128), jnp.int32)
-            jax.block_until_ready(pallas_sort3(x, x, x))
-        return True
-    except Exception as e:  # pragma: no cover - backend-specific
-        logger.warning("Pallas sort unavailable on %s: %s", backend, e)
-        return False
+        ok = _sort_probe()
+    except Exception as e:
+        raise RuntimeError(
+            f"Pallas sort kernel failed its probe on TPU: {type(e).__name__}: "
+            f"{e}.  Set TEXTBLAST_PALLAS=off to run without it"
+        ) from e
+    if not ok:
+        raise RuntimeError("Pallas sort kernel probe differs from lax.sort on TPU")
+    return True
 
 
 def _probe_backend() -> bool:
@@ -233,13 +284,16 @@ def pallas_sort_supported() -> bool:
     return _probe_backend()
 
 
+#: Widest row the bitonic kernel sorts (the widest bucket); wider rows take
+#: ``lax.sort``.  Bounded by VMEM: a 3-key row tile at 65536 lanes asks for
+#: ~54 MiB of :data:`COMPILER_PARAMS`' 64 MiB.
+_MAX_SORT_LANES = 65536
+
+
 def _pallas_ok(b: int, m: int) -> bool:
-    # Upper bound: m=16384 is silicon-proven (round 3); 32768 still fits the
-    # ~8 VMEM row-copies the network needs, 65536 would not — those rows fall
-    # back to lax.sort.
     return (
         pallas_sort_supported()
-        and 128 <= m <= 32768
+        and 128 <= m <= _MAX_SORT_LANES
         and not (m & (m - 1))
         and b % _ROWS == 0
         and b > 0
@@ -266,18 +320,31 @@ def _sharded_sort(fn, mesh: Mesh, ks):
     spec = P(_DATA_AXIS, None)
     n = len(ks)
     kwargs = dict(mesh=mesh, in_specs=(spec,) * n, out_specs=(spec,) * n)
-    try:
-        # Replication checking needs vma annotations pallas outputs don't
-        # carry; rows are fully sharded, nothing is replicated — disable it.
-        mapped = shard_map(fn, check_vma=False, **kwargs)
-    except TypeError:  # pre-vma JAX spells it check_rep
-        mapped = shard_map(fn, check_rep=False, **kwargs)
-    return mapped(*ks)
+    # Replication checking needs vma annotations pallas outputs don't carry;
+    # rows are fully sharded, nothing is replicated — disable it.
+    return shard_map(fn, check_vma=False, **kwargs)(*ks)
 
 
 def _dispatch(*ks) -> Tuple[jax.Array, ...]:
     interpret = _interpret_forced()
     return tuple(_pallas_sort_n(ks, interpret=interpret))
+
+
+def _kernel_sort(ks, mesh: Optional[Mesh]) -> Optional[Tuple[jax.Array, ...]]:
+    """The Pallas sort of ``ks`` (shard_mapped over ``mesh``'s data axis
+    when it has more than one device), or None when the gates decline it;
+    books the dispatch either way."""
+    b, m = ks[0].shape
+    n_dev = _data_axis_size(mesh)
+    if n_dev is not None and n_dev > 1:
+        if b % n_dev == 0 and _pallas_ok(b // n_dev, m):
+            record_scan_dispatch("pallas_sort")
+            return _sharded_sort(_dispatch, mesh, ks)
+    elif n_dev == 1 and _pallas_ok(b, m):
+        record_scan_dispatch("pallas_sort")
+        return _dispatch(*ks)
+    record_scan_dispatch("lax_sort")
+    return None
 
 
 def sort3(
@@ -288,13 +355,9 @@ def sort3(
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Lexicographic row sort: Pallas bitonic network on TPU (shard_mapped
     over ``mesh`` when given), ``lax.sort`` elsewhere."""
-    b, m = k1.shape
-    n_dev = _data_axis_size(mesh)
-    if n_dev is not None and n_dev > 1:
-        if b % n_dev == 0 and _pallas_ok(b // n_dev, m):
-            return _sharded_sort(_dispatch, mesh, (k1, k2, k3))
-    elif n_dev == 1 and _pallas_ok(b, m):
-        return pallas_sort3(k1, k2, k3, interpret=_interpret_forced())
+    out = _kernel_sort((k1, k2, k3), mesh)
+    if out is not None:
+        return out
     return jax.lax.sort(
         (k1.astype(jnp.int32), k2.astype(jnp.int32), k3.astype(jnp.int32)),
         dimension=1,
@@ -317,13 +380,9 @@ def sort2(
     is (k1, then k2) — identical to the stable form for non-negative
     payloads, which every caller passes (iotas or byte lengths).  With x64
     off, the 1-key *stable* two-operand ``lax.sort`` is used."""
-    b, m = k1.shape
-    n_dev = _data_axis_size(mesh)
-    if n_dev is not None and n_dev > 1:
-        if b % n_dev == 0 and _pallas_ok(b // n_dev, m):
-            return _sharded_sort(_dispatch, mesh, (k1, k2))
-    elif n_dev == 1 and _pallas_ok(b, m):
-        return pallas_sort2(k1, k2, interpret=_interpret_forced())
+    out = _kernel_sort((k1, k2), mesh)
+    if out is not None:
+        return out
     if jax.config.jax_enable_x64:
         z = (k1.astype(jnp.int64) << 32) | k2.astype(jnp.int64)
         s = jax.lax.sort(z, dimension=1)

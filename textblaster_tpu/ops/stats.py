@@ -87,6 +87,12 @@ def _shift_l(x: jax.Array, fill=0) -> jax.Array:
     return jnp.concatenate([x[:, 1:], jnp.full_like(x[:, :1], fill)], axis=1)
 
 
+def _bool_select(c: jax.Array, a: jax.Array, b: jax.Array) -> jax.Array:
+    """``jnp.where(c, a, b)`` over bools, spelled as i1 logic so it lowers
+    inside Mosaic kernels (chain preps)."""
+    return (c & a) | (~c & b)
+
+
 def _first_col(mask: jax.Array) -> jax.Array:
     out = jnp.zeros_like(mask, dtype=bool)
     return out.at[:, 0].set(True)
@@ -231,7 +237,7 @@ def _scatter(values, idx, active, m, fill=0, op="set"):
 
 # --- Scatter-free table construction (the TPU path) --------------------------
 # XLA:TPU lowers the per-segment scatters above to serialized per-element
-# loops (round-3 on-chip profile: ~13s/batch, TPU_EVIDENCE_r03).  When
+# loops (an early on-chip profile measured ~13 s per batch).  When
 # ``use_sort_tables()`` is on, tables are built instead by ONE sorted
 # compaction of the active positions (the already-tuned VMEM bitonic network)
 # plus a small ``take_along_axis`` gather per value stream.  This requires
@@ -343,11 +349,13 @@ def structure(
         def _derive(held, hs, shh, e, w, br, she, shw):
             # in_word / in_unit / unit_start from the held scans (staged
             # twin formulas; XLA CSE dedups across the preps sharing them).
-            iw = jnp.where(e != 0, held > 0, w != 0)
+            # Bool selects as i1 logic: Mosaic cannot lower select_n with
+            # bool operands (no i8 -> i1 truncation), so no jnp.where here.
+            iw = _bool_select(e != 0, held > 0, w != 0)
             bs = ~iw & (br != 0)
             sym = bs | ((e != 0) & ~iw & (hs > 0))
             iu = iw | sym
-            piw = jnp.where(she != 0, shh > 0, shw != 0)
+            piw = _bool_select(she != 0, shh > 0, shw != 0)
             us = (iw & ~piw) | bs
             return iu, us
 
@@ -363,7 +371,7 @@ def structure(
         )
 
         def prep_sym(held, e, w, br):
-            iw = jnp.where(e != 0, held > 0, w != 0)
+            iw = _bool_select(e != 0, held > 0, w != 0)
             return e, (~iw & (br != 0)).astype(jnp.int32)
 
         def prep_agg(held, hs, shh, e, w, br, she, shw, wd, np_, al):

@@ -251,17 +251,8 @@ def initialize(
 
 
 def _distributed_initialized() -> bool:
-    """True once this process joined a ``jax.distributed`` job.
-
-    ``jax.distributed.is_initialized`` only exists on newer jax; on older
-    versions (this container's 0.4.x included) probe the distributed state's
-    client directly instead of raising AttributeError mid-run."""
-    fn = getattr(jax.distributed, "is_initialized", None)
-    if fn is not None:
-        return bool(fn())
-    from jax._src import distributed
-
-    return getattr(distributed.global_state, "client", None) is not None
+    """True once this process joined a ``jax.distributed`` job."""
+    return bool(jax.distributed.is_initialized())
 
 
 def global_data_mesh() -> "jax.sharding.Mesh":
@@ -1110,8 +1101,8 @@ def host_allgather_obj(obj) -> list:
 
 def _local_stats(out: dict) -> dict:
     """This process's rows of every ``data``-sharded output, in row order,
-    moved in ONE bundled transfer (per-key np.asarray is a synchronous round
-    trip each on remote-tunnel backends — see assemble_batch)."""
+    moved in ONE bundled transfer (per-key np.asarray is a synchronous
+    device round trip each — see assemble_batch)."""
     shard_tree = {
         k: [
             s.data
@@ -2475,6 +2466,7 @@ def run_multihost(
     import pyarrow.parquet as pq
 
     from ..errors import PipelineError
+    from ..ops.device import tpu_refusal
     from ..orchestration import (
         AggregationResult,
         aggregate_results_from_stream,
@@ -2541,6 +2533,9 @@ def run_multihost(
                 "membership deliberately has no lockstep exchanges to "
                 "carry it"
             )
+        refusal = tpu_refusal()  # elastic ranks never join jax.distributed
+        if refusal:
+            raise PipelineError(refusal)
         return _run_elastic(
             config,
             input_file,
@@ -2663,6 +2658,11 @@ def run_multihost(
                 continue
 
     try:
+        # The gang has formed (jax.distributed, when used, is up): only now
+        # may the backend be touched to refuse a missing TPU.
+        refusal = tpu_refusal()
+        if refusal:
+            raise PipelineError(refusal)
         mesh = global_data_mesh()
         _ride_reformations(_align_trace_clocks)
 

@@ -3,7 +3,7 @@
 A device batch that exhausts its whole degradation ladder (retry -> split ->
 host-oracle rerun) still *completes* — the host rung is bit-exact — but each
 such batch costs the full host pipeline.  When the device keeps failing
-batch after batch (dead TPU slice, wedged remote tunnel), paying ladder
+batch after batch (dead TPU slice, wedged device runtime), paying ladder
 latency per batch is strictly worse than admitting the device is gone:
 after ``threshold`` consecutive failures the breaker trips and the run
 degrades wholesale to the host backend.  The transition is recorded in
@@ -11,7 +11,7 @@ METRICS (``resilience_breaker_trips_total`` counter +
 ``resilience_breaker_open`` gauge) and logged once.
 
 Half-open recovery: a long shard should not stay host-bound after a
-transient outage (tunnel blip, preempted slice that came back).  After
+transient outage (runtime blip, preempted slice that came back).  After
 ``cooldown_s`` of open time, the next ``allow_request()`` grants exactly one
 probe batch (half-open).  If that batch succeeds the breaker closes and the
 run returns to the device; if it fails the breaker reopens with a fresh

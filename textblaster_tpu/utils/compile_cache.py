@@ -1,6 +1,8 @@
 """Persistent compilation caches.
 
-Two layers, both rooted in the repo-local (gitignored) ``.cache/``:
+Two layers, both rooted in ``$JAX_COMPILATION_CACHE_DIR`` when that is set
+(the store in its ``textblast-aot/`` subdirectory) and otherwise in the
+repo-local (gitignored) ``.cache/``:
 
 1. **XLA's built-in compilation cache** (:func:`enable_compilation_cache`)
    — skips the XLA *compile*, but every process still pays trace + lower
@@ -37,6 +39,8 @@ import pickle
 import tempfile
 from typing import Any, Dict, Optional
 
+from jax.experimental.serialize_executable import deserialize_and_load, serialize
+
 logger = logging.getLogger(__name__)
 
 __all__ = [
@@ -45,7 +49,6 @@ __all__ = [
     "DEFAULT_AOT_DIR",
     "AOTExecutableCache",
     "aot_cache_enabled",
-    "aot_cache_supported",
     "config_fingerprint",
     "program_cache_key",
 ]
@@ -73,9 +76,21 @@ _SUFFIX = ".aotx"
 _COST_SUFFIX = ".cost.json"
 
 
+def _placed_cache_dir() -> str:
+    """``JAX_COMPILATION_CACHE_DIR`` when the caller placed the cache from
+    outside, else ""."""
+    return os.environ.get("JAX_COMPILATION_CACHE_DIR", "").strip()
+
+
 def enable_compilation_cache(cache_dir: str | None = None) -> str:
-    """Point JAX's persistent compilation cache at ``cache_dir`` (created if
-    missing).  Returns the directory used.
+    """Turn on JAX's persistent compilation cache and return its directory
+    (created if missing).
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already reads the cache
+    from there and ``jax_compilation_cache_dir`` is left alone; otherwise
+    the cache goes to ``cache_dir`` or the fixed in-checkout
+    :data:`DEFAULT_CACHE_DIR` (the path is part of the cache's key, so it
+    must not move between runs).
 
     ``TEXTBLAST_NO_COMPILE_CACHE=1`` turns this into a no-op (measurement
     escape hatch: cache-loaded XLA:CPU executables can differ in performance
@@ -84,9 +99,13 @@ def enable_compilation_cache(cache_dir: str | None = None) -> str:
 
     if os.environ.get("TEXTBLAST_NO_COMPILE_CACHE") == "1":
         return ""
-    cache_dir = cache_dir or DEFAULT_CACHE_DIR
+    placed = _placed_cache_dir()
+    if cache_dir is None and placed:
+        cache_dir = placed
+    else:
+        cache_dir = cache_dir or DEFAULT_CACHE_DIR
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
     os.makedirs(cache_dir, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
     return cache_dir
 
@@ -96,22 +115,16 @@ def aot_cache_enabled() -> bool:
     return os.environ.get("TEXTBLAST_NO_COMPILE_CACHE") != "1"
 
 
-@functools.lru_cache(maxsize=1)
-def aot_cache_supported() -> bool:
-    """Whether the installed jax has the AOT serialization API.
-
-    ``jax.export`` only round-trips StableHLO — the importer still pays a
-    full XLA compile, which is the cost this cache exists to skip —
-    so the *executable*-level ``serialize_executable`` API is required."""
-    try:
-        from jax.experimental.serialize_executable import (  # noqa: F401
-            deserialize_and_load,
-            serialize,
-        )
-
-        return True
-    except Exception:  # pragma: no cover - older/partial jax builds
-        return False
+def default_aot_dir() -> str:
+    """Where the executable store lives unless a caller names a directory:
+    ``TEXTBLAST_AOT_CACHE_DIR``, else beside JAX's cache when that was placed
+    from outside (so every compiled artifact lands in one directory), else
+    :data:`DEFAULT_AOT_DIR`."""
+    explicit = os.environ.get("TEXTBLAST_AOT_CACHE_DIR")
+    if explicit:
+        return explicit
+    placed = _placed_cache_dir()
+    return os.path.join(placed, "textblast-aot") if placed else DEFAULT_AOT_DIR
 
 
 # --- cache keys -------------------------------------------------------------
@@ -236,11 +249,7 @@ class AOTExecutableCache:
     def __init__(
         self, cache_dir: Optional[str] = None, max_bytes: Optional[int] = None
     ) -> None:
-        self.cache_dir = (
-            cache_dir
-            or os.environ.get("TEXTBLAST_AOT_CACHE_DIR")
-            or DEFAULT_AOT_DIR
-        )
+        self.cache_dir = cache_dir or default_aot_dir()
         if max_bytes is None:
             max_bytes = int(
                 float(os.environ.get("TEXTBLAST_AOT_CACHE_MB", "512")) * 1_000_000
@@ -296,12 +305,12 @@ class AOTExecutableCache:
     def load(self, key: str):
         """Return the deserialized executable for ``key``, or None on any
         miss (absent, bypassed, unsupported, corrupt — the latter evicted)."""
-        if not (aot_cache_enabled() and aot_cache_supported()):
+        if not aot_cache_enabled():
             return None
         path = self._path(key)
         try:
             with open(path, "rb") as f:
-                payload, in_tree, out_tree = pickle.load(f)
+                payload, in_tree, out_tree, device_ids = pickle.load(f)
         except FileNotFoundError:
             return None
         except Exception as e:  # corrupt / truncated / wrong pickle
@@ -310,9 +319,15 @@ class AOTExecutableCache:
             _unlink_quiet(self._cost_path(key))
             return None
         try:
-            from jax.experimental.serialize_executable import deserialize_and_load
+            import jax
 
-            compiled = deserialize_and_load(payload, in_tree, out_tree)
+            by_id = {d.id: d for d in jax.devices()}
+            compiled = deserialize_and_load(
+                payload,
+                in_tree,
+                out_tree,
+                execution_devices=[by_id[i] for i in device_ids],
+            )
         except Exception as e:  # runtime/topology mismatch that beat the key
             logger.warning("evicting unloadable AOT cache entry %s: %s", key, e)
             _unlink_quiet(path)
@@ -327,21 +342,24 @@ class AOTExecutableCache:
     def store(self, key: str, compiled) -> bool:
         """Serialize ``compiled`` under ``key``; returns True on success.
         Backends whose executables do not serialize simply decline."""
-        if not (aot_cache_enabled() and aot_cache_supported()):
+        if not aot_cache_enabled():
             return False
         try:
-            from jax.experimental.serialize_executable import (
-                deserialize_and_load,
-                serialize,
-            )
-
             payload, in_tree, out_tree = serialize(compiled)
+            # The executable's own devices: without them a one-device
+            # program would be loaded onto every local device and fail at
+            # its first call.
+            devices = compiled.runtime_executable().local_devices()
             # Validate before writing: executables XLA served from its own
             # persistent compilation cache serialize without their kernel
             # object code ("Symbols not found" on load, XLA:CPU) — a store
             # that every future process would evict is worse than no store.
-            deserialize_and_load(payload, in_tree, out_tree)
-            blob = pickle.dumps((payload, in_tree, out_tree))
+            deserialize_and_load(
+                payload, in_tree, out_tree, execution_devices=devices
+            )
+            blob = pickle.dumps(
+                (payload, in_tree, out_tree, [d.id for d in devices])
+            )
         except Exception as e:
             logger.debug("AOT serialize declined for %s: %s", key, e)
             return False
