@@ -14,6 +14,9 @@ Four properties, matching the observability acceptance bar:
   JSON containing all six stage spans plus at least one device-dispatch
   span, and the ``--run-report`` funnel sums exactly to the
   excluded-Parquet row count.
+* Inside a ``jax.profiler`` session the same spans land in the profile's
+  ``.xplane.pb`` as ``tb.<name>`` events on the emitting thread's line,
+  with their args; with neither sink on, a span is the shared no-op.
 """
 
 import json
@@ -30,7 +33,7 @@ from textblaster_tpu.data_model import TextDocument
 from textblaster_tpu.ops.pipeline import process_documents_device
 from textblaster_tpu.resilience import FAULTS
 from textblaster_tpu.utils.metrics import RUN_REPORT_SCHEMA
-from textblaster_tpu.utils.trace import TRACER
+from textblaster_tpu.utils.trace import _NULL_SPAN, TRACER
 
 CONFIG_YAML = """
 pipeline:
@@ -47,8 +50,12 @@ GOOD = (
 )
 BAD = "too short"
 
-#: The six host-pipeline stage span names (ISSUE acceptance set).
+#: The six host-pipeline stage span names.
 STAGE_SPANS = ("read", "pack", "dispatch", "device_wait", "post", "write")
+
+#: Spans of a consumer waiting on an overlap thread's queue or future: they
+#: exist only where there is such a thread, and only when it is behind.
+WAIT_SPANS = ("feed_wait", "pack_wait")
 
 
 @pytest.fixture(autouse=True)
@@ -138,13 +145,15 @@ def test_serial_and_overlapped_runs_emit_same_span_multiset(
         )
         TRACER.close()
         return Counter(
-            e["name"] for e in TRACER.drain() if e.get("ph") == "X"
+            e["name"]
+            for e in TRACER.drain()
+            if e.get("ph") == "X" and e["name"] not in WAIT_SPANS
         )
 
     serial = _run("serial", no_overlap=True)
     overlapped = _run("overlap", no_overlap=False)
     assert serial == overlapped
-    for name in STAGE_SPANS:
+    for name in STAGE_SPANS + ("chunk_fill", "phase", "assemble", "write_enqueue"):
         assert serial[name] > 0, f"stage span {name} missing"
 
 
@@ -298,3 +307,164 @@ def test_single_process_alignment_handshake_offsets_zero():
     assert meta[0]["args"]["offset_us"] == 0
     assert "origin_wall_us" in meta[0]["args"]
     assert meta[0]["args"]["host_walls_us"] == [meta[0]["args"]["origin_wall_us"]]
+
+
+# --- program spans in the profiler's trace -----------------------------------
+
+
+def _profiled_run(tmp_path, config, docs, **kw):
+    """A ``process_documents_device`` run inside a ``jax.profiler`` session;
+    returns the host plane's lines as ``[[(name, start_ns, end_ns, args)]]``."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    trace_dir = str(tmp_path / "profile")
+    jax.profiler.start_trace(trace_dir)
+    try:
+        outcomes = list(process_documents_device(config, iter(docs), **kw))
+    finally:
+        jax.profiler.stop_trace()
+    paths = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"), recursive=True)
+    assert paths, "the profiler session wrote no .xplane.pb"
+    lines = []
+    for plane in ProfileData.from_file(sorted(paths)[-1]).planes:
+        if plane.name != "/host:CPU":
+            continue
+        for line in plane.lines:
+            lines.append([
+                (e.name, int(e.start_ns), int(e.start_ns + e.duration_ns), dict(e.stats))
+                for e in line.events
+                if e.name.startswith("tb.")
+            ])
+    return outcomes, [ln for ln in lines if ln]
+
+
+def _driving_line(lines):
+    (line,) = [ln for ln in lines if any(n == "tb.dispatch" for n, *_ in ln)]
+    return line
+
+
+def _inside(inner, outer):
+    return outer[1] <= inner[1] and inner[2] <= outer[2]
+
+
+def test_profiler_session_records_program_spans_on_the_driving_line(
+    tmp_path, monkeypatch
+):
+    config = parse_pipeline_config(CONFIG_YAML)
+    # 17 docs in the 512 bucket with 16-row batches: one batch, then a
+    # leftover group of one, which the host oracle takes (a tail).  The
+    # suite pins tails to the device; this test needs the host tail.
+    monkeypatch.delenv("TEXTBLAST_HOST_TAILS", raising=False)
+    docs = [TextDocument(id=f"d{i}", content=GOOD, source="t") for i in range(17)]
+    outcomes, lines = _profiled_run(tmp_path, config, docs, device_batch=16,
+                                    buckets=(512, 2048))
+    assert len(outcomes) == 17
+    line = _driving_line(lines)
+    by_name = {}
+    for ev in line:
+        by_name.setdefault(ev[0], []).append(ev)
+    for name in ("tb.chunk_fill", "tb.phase", "tb.dispatch", "tb.device_wait",
+                 "tb.assemble", "tb.host_tail", "tb.post"):
+        assert by_name.get(name), f"{name} missing from the driving line"
+    # Nesting: every dispatch and post inside a phase, every wait and
+    # assembly inside a post; chunk fills are not inside a phase.
+    phases = by_name["tb.phase"]
+    for name in ("tb.dispatch", "tb.post"):
+        for ev in by_name[name]:
+            assert any(_inside(ev, ph) for ph in phases), name
+    posts = by_name["tb.post"]
+    for name in ("tb.device_wait", "tb.assemble", "tb.host_tail"):
+        for ev in by_name[name]:
+            assert any(_inside(ev, po) for po in posts), name
+    for ev in by_name["tb.chunk_fill"]:
+        assert not any(_inside(ev, ph) for ph in phases)
+    # One batch: pack, dispatch, wait and assembly share its number.
+    (dispatch,) = by_name["tb.dispatch"]
+    batch = dispatch[3]["batch"]
+    assert batch >= 0
+    assert [ev[3]["batch"] for ev in by_name["tb.device_wait"]] == [batch]
+    assert [ev[3]["batch"] for ev in by_name["tb.assemble"]] == [batch]
+    packs = [ev for ln in lines for ev in ln if ev[0] == "tb.pack"]
+    assert [ev[3]["batch"] for ev in packs] == [batch]
+    (wait,) = by_name["tb.device_wait"]
+    assert wait[3]["leaves"] > 0 and wait[3]["bytes"] > 0
+    (tail,) = by_name["tb.host_tail"]
+    assert tail[3] == {"kind": "tail", "docs": 1}
+    (phase,) = phases
+    assert phase[3] == {"chunk": by_name["tb.chunk_fill"][0][3]["chunk"],
+                        "phase": 0, "docs_in": 17, "batches": 1, "survivors": 0}
+
+
+def test_profiler_session_records_host_suffix_block(tmp_path):
+    from tokenizers import Tokenizer
+    from tokenizers.models import WordLevel
+    from tokenizers.pre_tokenizers import Whitespace
+
+    tok = Tokenizer(WordLevel({"[UNK]": 0}, unk_token="[UNK]"))
+    tok.pre_tokenizer = Whitespace()
+    tok_path = str(tmp_path / "tokenizer.json")
+    tok.save(tok_path)
+    config = parse_pipeline_config(f"""
+pipeline:
+  - type: GopherQualityFilter
+    min_doc_words: 5
+  - type: TokenCounter
+    tokenizer_name: "{tok_path}"
+""")
+    docs = _docs(32)
+    outcomes, lines = _profiled_run(tmp_path, config, docs, device_batch=16)
+    kept = [o for o in outcomes if o.kind == o.SUCCESS]
+    assert kept and all("token_count" in o.document.metadata for o in kept)
+    line = _driving_line(lines)
+    suffix = [ev for ev in line if ev[0] == "tb.host_suffix"]
+    assemble = {ev[3]["batch"]: ev for ev in line if ev[0] == "tb.assemble"}
+    # One block per batch with passing rows, after that batch's assembly,
+    # and its documents are exactly the kept ones.
+    assert suffix
+    for ev in suffix:
+        a = assemble[ev[3]["batch"]]
+        assert a[2] <= ev[1]
+    assert sum(ev[3]["docs"] for ev in suffix) == len(kept)
+    assert not [ev for ln in lines if ln is not line for ev in ln
+                if ev[0] == "tb.host_suffix"]
+
+
+def test_span_is_the_shared_null_span_with_both_sinks_off():
+    from jax.profiler import TraceAnnotation
+
+    assert not TRACER.enabled and not TraceAnnotation.is_enabled()
+    assert TRACER.span("dispatch", {"batch": 1}) is _NULL_SPAN
+    assert not TRACER.span("phase").live
+
+
+def test_profiler_session_alone_yields_live_spans_and_no_json_events(tmp_path):
+    import jax
+
+    jax.profiler.start_trace(str(tmp_path / "p"))
+    try:
+        with TRACER.span("phase", {"chunk": 0}) as sp:
+            assert sp.live and sp is not _NULL_SPAN
+            sp.add_args({"survivors": 3})
+    finally:
+        jax.profiler.stop_trace()
+    assert TRACER.drain() == []
+
+
+def test_json_tracing_and_profiler_session_record_the_same_span(tmp_path):
+    import jax
+
+    TRACER.configure(None)
+    jax.profiler.start_trace(str(tmp_path / "p"))
+    try:
+        with TRACER.span("host_tail", {"kind": "tail"}) as sp:
+            assert sp.live
+            sp.add_args({"docs": 2})
+    finally:
+        jax.profiler.stop_trace()
+    TRACER.close()
+    (ev,) = [e for e in TRACER.drain() if e.get("ph") == "X"]
+    assert ev["name"] == "host_tail"
+    assert ev["args"] == {"kind": "tail", "docs": 2}

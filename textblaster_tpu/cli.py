@@ -154,12 +154,18 @@ def build_parser() -> argparse.ArgumentParser:
                           "transitions).  Load it at https://ui.perfetto.dev "
                           "or chrome://tracing.  Near-zero cost when off; "
                           "with --coordinator, process i>0 writes "
-                          "OUT.JSON.host<i>")
+                          "OUT.JSON.host<i>.  The same spans land in the "
+                          "--trace-device profile whether or not this is "
+                          "given")
     run.add_argument("--trace-device", default=None, metavar="LOGDIR",
-                     help="Also capture the XLA device-side profile via "
-                          "jax.profiler.trace into LOGDIR (TensorBoard/"
-                          "Perfetto-loadable).  Opt-in and independent of "
-                          "--trace")
+                     help="Capture a jax.profiler trace into LOGDIR "
+                          "(TensorBoard/Perfetto-loadable): the XLA device "
+                          "operations and the program's own per-batch, "
+                          "per-group and per-chunk spans (named tb.<stage>, "
+                          "on each emitting thread's line) in one .xplane.pb "
+                          "on one clock, so every idle stretch of the device "
+                          "lines up with the stage the host was in.  "
+                          "Independent of --trace")
     run.add_argument("--events-file", default=None, metavar="OUT.JSONL",
                      help="Write the structured operational event journal: "
                           "every retry/breaker/ladder transition, negotiated "

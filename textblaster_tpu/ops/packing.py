@@ -51,12 +51,15 @@ class PackedBatch:
     ``lengths`` — ``[B] int32`` document char counts.
     ``valid``  — ``[B] bool``; False rows are padding documents.
     ``docs``   — the host-side documents, index-aligned with rows.
+    ``seq``    — the batch's sequence number in its pipeline's spans
+    (-1 where none was given).
     """
 
     cps: np.ndarray
     lengths: np.ndarray
     valid: np.ndarray
     docs: List[TextDocument]
+    seq: int = -1
 
     @property
     def batch_size(self) -> int:

@@ -23,6 +23,7 @@ the host oracle — the outlier path SURVEY.md §5 calls for.
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import logging
 import os
 import time as _time_mod
@@ -527,6 +528,15 @@ class CompiledPipeline:
         # serial (lockstep dispatch must not reorder across hosts).
         self._overlap = getattr(config, "overlap", None) or OverlapConfig()
         self._pack_pool_obj = None
+        # Sequence numbers shared by every span of one batch and of one
+        # chunk (utils/trace.py), so a trace joins pack, dispatch, wait and
+        # assembly of the same batch.
+        self._batch_ids = itertools.count()
+        self._chunks = 0  # chunks started: the next chunk's number
+        # The post stage's parts exist from the start, so a run with no
+        # host tail reads 0 rather than nothing.
+        METRICS.inc("stage_host_suffix_seconds", 0.0)
+        METRICS.inc("stage_host_tail_seconds", 0.0)
 
     def _badwords_host_step(self, idx: int):
         """The real host C4BadWordsFilter for device step ``idx`` — runs only
@@ -697,6 +707,9 @@ class CompiledPipeline:
                         out[f"{i}:hazard:{lang}"] = per_hazard[lang]
             return out
 
+        # The program's name carries its (bucket, phase): the profiler's
+        # dispatch and device-module events then say which program ran.
+        fn.__name__ = fn.__qualname__ = f"tb_b{length}_p{phase}"
         if not jit:
             # Raw traceable fn (scan_dispatch_counts traces it under
             # jax.eval_shape to count dispatches without compiling).
@@ -1538,8 +1551,8 @@ class CompiledPipeline:
             TELEMETRY.mark("dispatch", (d.id for d in batch.docs))
         with TRACER.span(
             "device_dispatch",
-            {"bucket": batch.max_len, "rows": batch.batch_size,
-             "phase": phase},
+            {"batch": batch.seq, "bucket": batch.max_len,
+             "rows": batch.batch_size, "phase": phase},
         ):
             fn = self._fn_for(batch.max_len, phase, rows=batch.batch_size)
             if self.mesh is not None:
@@ -1640,8 +1653,17 @@ class CompiledPipeline:
             try:
                 with TRACER.span(
                     "device_wait",
-                    {"bucket": batch.max_len, "phase": phase},
+                    {"batch": batch.seq, "bucket": batch.max_len,
+                     "phase": phase},
                 ) as sp:
+                    if sp.live:
+                        # What the fetch moves: a slow wait on few bytes
+                        # is the device (or the GIL), not the transfer.
+                        leaves = jax.tree_util.tree_leaves(stats)
+                        sp.add_args({
+                            "leaves": len(leaves),
+                            "bytes": int(sum(x.nbytes for x in leaves)),
+                        })
                     out = jax.device_get(stats)
                     if PROFILER.enabled:
                         # Duration must be taken inside the span: the event
@@ -1670,13 +1692,26 @@ class CompiledPipeline:
         """Bottom rung: the full host-oracle pipeline, bit-identical to the
         device path by the same contract the overflow fallback relies on
         (docs are re-stamped identically even mid-phase)."""
-        outcomes: List[ProcessingOutcome] = []
-        for doc in docs:
-            METRICS.inc("resilience_ladder_host_total")
-            outcome = execute_processing_pipeline(self.host_executor, doc)
-            if outcome is not None:
-                outcomes.append(outcome)
-        return outcomes
+        METRICS.inc("resilience_ladder_host_total", len(docs))
+        outcomes = self._host_block(
+            "host_tail", {"kind": "ladder", "docs": len(docs)},
+            "stage_host_tail_seconds", self.host_executor, docs,
+        )
+        return [o for o in outcomes if o is not None]
+
+    @staticmethod
+    def _host_block(
+        span: str, args: Dict, counter: str, executor, docs: List[TextDocument]
+    ) -> List[Optional[ProcessingOutcome]]:
+        """``execute_processing_pipeline`` over ``docs`` in order, as one
+        span whose clock reads also feed ``counter``.  A hard error leaves
+        ``None`` in its document's place."""
+        t0 = _time_mod.perf_counter()
+        try:
+            with TRACER.span(span, args):
+                return [execute_processing_pipeline(executor, d) for d in docs]
+        finally:
+            METRICS.inc(counter, _time_mod.perf_counter() - t0)
 
     def _execute_packed(
         self, batch: PackedBatch, phase: int, inflight=None
@@ -1728,6 +1763,7 @@ class CompiledPipeline:
                 if not part:
                     continue
                 sub = pack_documents(part, sub_rows, batch.max_len)
+                sub.seq = batch.seq
                 try:
                     stats = self._device_fetch(sub, phase)
                 except RetryExhaustedError:
@@ -1764,87 +1800,115 @@ class CompiledPipeline:
         Returns ``(outcomes, survivors)``: outcomes are final (filtered docs,
         host-fallback reruns, and — on the last phase — passes); survivors
         are documents that passed a non-final phase and continue to the next.
+
+        The host calls of a batch run as two blocks after the row walk — the
+        overflow reruns on the host oracle, then the host steps that follow
+        the last phase (TokenCounter) — each outcome kept in its row's place,
+        so the ``assemble``, ``host_tail`` and ``host_suffix`` spans and
+        counters split the post stage without moving an outcome.
         """
-        if TELEMETRY.enabled:
-            TELEMETRY.mark("assemble", (d.id for d in batch.docs))
-        # ONE bundled transfer: each per-key np.asarray is its own
-        # synchronous device round trip; jax.device_get moves the whole tree
-        # in one call.
-        stats = jax.device_get(device_stats)
-        # Rows where any step hit a kernel table bound rerun the host oracle.
-        # Phase-boundary note: a doc overflowing in a later phase carries the
-        # earlier phases' metadata stamps; the full-pipeline host rerun
-        # re-stamps the identical values (device/host stamp parity), so the
-        # outcome is still bit-identical to a pure host run.
         n_rows = len(batch.docs)
-        step_ids = self.phases[phase]
-        evals = [
-            (self.device_steps[i], self._eval_step(self.device_steps[i], i, stats))
-            for i in step_ids
-        ]
-        overflow_any = np.zeros(n_rows, dtype=bool)
-        for _, ev in evals:
-            if ev.overflow is not None:
-                overflow_any |= ev.overflow[:n_rows]
         last = phase == len(self.phases) - 1
-        outcomes: List[ProcessingOutcome] = []
+        outcomes: List[Optional[ProcessingOutcome]] = []
         survivors: List[TextDocument] = []
-        # Vectorized pass-row fast path: one batch-level AND of every step's
-        # verdict finds the rows that pass the whole phase; their only side
-        # effects are metadata pass-stamps (constant, or per-row via a
-        # batch-precomputed stamp function), so they skip the per-row
-        # decide() walk.  Rows that fail, overflow, need a non-identity C4
-        # rewrite, or hit a step without a batch verdict (badwords: the
-        # doc's language is only known per row) keep the per-row path.
-        fast_mask = None
-        if n_rows:
-            fast_mask = ~overflow_any
+        # (slot in outcomes, doc) for the deferred host calls.
+        overflow_rows: List[Tuple[int, TextDocument]] = []
+        suffix_rows: List[Tuple[int, TextDocument]] = []
+        with TRACER.span(
+            "assemble",
+            {"batch": batch.seq, "bucket": batch.max_len, "phase": phase,
+             "rows": n_rows},
+        ):
+            if TELEMETRY.enabled:
+                TELEMETRY.mark("assemble", (d.id for d in batch.docs))
+            # ONE bundled transfer: each per-key np.asarray is its own
+            # synchronous device round trip; jax.device_get moves the whole
+            # tree in one call.
+            stats = jax.device_get(device_stats)
+            # Rows where any step hit a kernel table bound rerun the host
+            # oracle.  Phase-boundary note: a doc overflowing in a later
+            # phase carries the earlier phases' metadata stamps; the
+            # full-pipeline host rerun re-stamps the identical values
+            # (device/host stamp parity), so the outcome is still
+            # bit-identical to a pure host run.
+            step_ids = self.phases[phase]
+            evals = [
+                (self.device_steps[i], self._eval_step(self.device_steps[i], i, stats))
+                for i in step_ids
+            ]
+            overflow_any = np.zeros(n_rows, dtype=bool)
             for _, ev in evals:
-                if ev.passed is None or (
-                    ev.pass_stamps is None and ev.pass_stamp_fn is None
-                ):
-                    fast_mask = None
-                    break
-                fast_mask &= ev.passed[:n_rows]
-                if ev.c4_line_keep is not None:
-                    fast_mask &= ev.c4_rewrite_identity[:n_rows]
-        for row, doc in enumerate(batch.docs):
-            if fast_mask is not None and fast_mask[row]:
-                # Passed every step: stamp in step order, exactly what
-                # _assemble_row's pass branches would have written.
+                if ev.overflow is not None:
+                    overflow_any |= ev.overflow[:n_rows]
+            # Vectorized pass-row fast path: one batch-level AND of every
+            # step's verdict finds the rows that pass the whole phase; their
+            # only side effects are metadata pass-stamps (constant, or
+            # per-row via a batch-precomputed stamp function), so they skip
+            # the per-row decide() walk.  Rows that fail, overflow, need a
+            # non-identity C4 rewrite, or hit a step without a batch verdict
+            # (badwords: the doc's language is only known per row) keep the
+            # per-row path.
+            fast_mask = None
+            if n_rows:
+                fast_mask = ~overflow_any
                 for _, ev in evals:
-                    if ev.pass_stamps is not None:
-                        for k, v in ev.pass_stamps:
-                            doc.metadata[k] = v
-                    else:
-                        ev.pass_stamp_fn(row, doc)
-                if not last:
-                    survivors.append(doc)
+                    if ev.passed is None or (
+                        ev.pass_stamps is None and ev.pass_stamp_fn is None
+                    ):
+                        fast_mask = None
+                        break
+                    fast_mask &= ev.passed[:n_rows]
+                    if ev.c4_line_keep is not None:
+                        fast_mask &= ev.c4_rewrite_identity[:n_rows]
+            for row, doc in enumerate(batch.docs):
+                if fast_mask is not None and fast_mask[row]:
+                    # Passed every step: stamp in step order, exactly what
+                    # _assemble_row's pass branches would have written.
+                    for _, ev in evals:
+                        if ev.pass_stamps is not None:
+                            for k, v in ev.pass_stamps:
+                                doc.metadata[k] = v
+                        else:
+                            ev.pass_stamp_fn(row, doc)
+                    outcome = None
+                elif overflow_any[row]:
+                    METRICS.inc("worker_host_fallback_total")
+                    overflow_rows.append((len(outcomes), doc))
+                    outcomes.append(None)
                     continue
-                if self.host_steps:
-                    outcome = execute_processing_pipeline(
-                        self.host_suffix_executor, doc
-                    )
                 else:
-                    outcome = ProcessingOutcome.success(doc)
-            elif overflow_any[row]:
-                METRICS.inc("worker_host_fallback_total")
-                outcome = execute_processing_pipeline(self.host_executor, doc)
-            else:
-                outcome = self._assemble_row(evals, row, doc)
+                    outcome = self._assemble_row(evals, row, doc)
                 if outcome is None:  # passed every step of this phase
                     if not last:
                         survivors.append(doc)
                         continue
                     if self.host_steps:
-                        outcome = execute_processing_pipeline(
-                            self.host_suffix_executor, doc
-                        )
-                    else:
-                        outcome = ProcessingOutcome.success(doc)
-            if outcome is not None:  # hard error -> no outcome (reference quirk)
+                        suffix_rows.append((len(outcomes), doc))
+                        outcomes.append(None)
+                        continue
+                    outcome = ProcessingOutcome.success(doc)
                 outcomes.append(outcome)
-        return outcomes, survivors
+        if overflow_rows:
+            self._fill_slots(
+                outcomes, overflow_rows, "host_tail",
+                {"kind": "overflow", "docs": len(overflow_rows)},
+                "stage_host_tail_seconds", self.host_executor,
+            )
+        if suffix_rows:
+            self._fill_slots(
+                outcomes, suffix_rows, "host_suffix",
+                {"batch": batch.seq, "docs": len(suffix_rows)},
+                "stage_host_suffix_seconds", self.host_suffix_executor,
+            )
+        # A hard error has no outcome (reference quirk).
+        return [o for o in outcomes if o is not None], survivors
+
+    def _fill_slots(self, outcomes, rows, span, args, counter, executor) -> None:
+        """Run the deferred ``(slot, doc)`` rows as one host block and put
+        each outcome in its slot."""
+        done = self._host_block(span, args, counter, executor, [d for _, d in rows])
+        for (slot, _), outcome in zip(rows, done):
+            outcomes[slot] = outcome
 
     def phase_previewable(self, phase: int) -> bool:
         """True when every step of ``phase`` carries a full batch verdict
@@ -1911,22 +1975,31 @@ class CompiledPipeline:
         return self.assemble_batch(batch, self.dispatch_batch(batch))
 
     def _timed_pack(
-        self, docs: List[TextDocument], batch_size: int, max_len: int
+        self,
+        docs: List[TextDocument],
+        batch_size: int,
+        max_len: int,
+        seq: Optional[int] = None,
     ) -> PackedBatch:
-        """``pack_documents`` with the pack-stage wall clock attached.
+        """``pack_documents`` with the pack-stage wall clock attached and
+        the batch's sequence number ``seq`` (the next one when not given).
 
         Runs once per batch on the pack pool's hot path — the clock comes
         from the module-scope import, not a per-call ``import time``."""
+        if seq is None:
+            seq = next(self._batch_ids)
         if TELEMETRY.enabled:
             TELEMETRY.mark("pack", (d.id for d in docs))
         t0 = _time_mod.perf_counter()
         try:
             with TRACER.span(
-                "pack", {"rows": len(docs), "bucket": max_len}
+                "pack", {"batch": seq, "rows": len(docs), "bucket": max_len}
             ):
-                return pack_documents(
+                batch = pack_documents(
                     docs, batch_size=batch_size, max_len=max_len
                 )
+                batch.seq = seq
+                return batch
         finally:
             METRICS.inc("stage_pack_seconds", _time_mod.perf_counter() - t0)
 
@@ -1966,13 +2039,20 @@ class CompiledPipeline:
         pool = self._pack_pool()
 
         def submit(docs, batch_size, max_len):
-            return pool.submit(
-                self._timed_pack, docs, batch_size=batch_size, max_len=max_len
+            # Numbered in submission (= consumption) order; the future
+            # carries the number so a wait on it names its batch.
+            seq = next(self._batch_ids)
+            future = pool.submit(
+                self._timed_pack, docs, batch_size=batch_size,
+                max_len=max_len, seq=seq,
             )
+            future.batch_seq = seq
+            return future
 
         gen = iter_packed_batches(docs_iter, pack_fn=submit, **kwargs)
         pf = prefetch_iter(
-            gen, depth=max(2, self._overlap.pack_workers + 1), block=1
+            gen, depth=max(2, self._overlap.pack_workers + 1), block=1,
+            label="pack",
         )
         return pf, pf.close
 
@@ -2016,16 +2096,17 @@ class CompiledPipeline:
         never the sequence — so serial (depth 1, or --no-overlap) and
         overlapped runs produce byte-identical outcome streams by
         construction.
-        """
-        import os
-        import time
 
-        debug = os.environ.get("TEXTBLAST_PHASE_DEBUG") == "1"
+        Each phase is one ``phase`` span (the chunk's number, the phase,
+        documents in, batches, survivors).
+        """
         no_overlap = os.environ.get("TEXTBLAST_NO_OVERLAP") == "1"
         overlapped = (
             self._overlap.enabled and not no_overlap and self.mesh is None
         )
         depth = max(1, self._overlap.pipeline_depth) if overlapped else 1
+        chunk_id = self._chunks
+        self._chunks += 1
         current: List[TextDocument] = docs
         if self._route_dict_scripts or self.wire_u16:
             from ..utils.cjk import has_astral, has_dict_script
@@ -2049,137 +2130,157 @@ class CompiledPipeline:
             _host_routed = None
             routed = {}
         for phase in range(len(self.phases)):
-            t0 = time.perf_counter()
-            timing = {"dispatch": 0.0, "drain": 0.0}
-            n_in, n_batches = len(current), 0
-            survivors: List[TextDocument] = []
-            # FIFO window entries: ("batch", (batch, stats)) dispatched and
-            # awaiting assembly, or ("host", docs) fallback groups awaiting
-            # their host-oracle pass.  ``inflight`` counts batch entries only.
-            window: deque = deque()
-            inflight = 0
-            # Host-oracle threshold for leftover groups: the first phase's
-            # program is cheap (it exists to kill docs early), so the device
-            # wins even for small groups; later phases carry the expensive
-            # kernels and the (bit-exact) host oracle wins below ~half a
-            # batch.  Mesh runs keep every doc on device (shard accounting),
-            # and TEXTBLAST_HOST_TAILS=off pins tails to the device too (the
-            # parity suites use it so device kernels decide every doc).
-            if self.mesh is None and os.environ.get("TEXTBLAST_HOST_TAILS") != "off":
-                # Per-bucket: the cutoff tracks each bucket's own row budget
-                # (with a uniform geometry this is the historical scalar).
-                div = 16 if phase == 0 else 2
-                host_tail_max = {
-                    b: self.geometry.batch_for(b) // div
-                    for b in self.geometry.buckets
-                }
-            else:
-                host_tail_max = 0
-            over_length = self.buckets[-1] - PACK_MARGIN
-            # Phase 0 only: later phases' survivors already passed it.
-            route = _host_routed if phase == 0 else None
-
-            def _process_fallback(fallback_docs):
-                outs = []
-                for doc in fallback_docs:
-                    # Over-length and routed (dict-script/astral) docs are
-                    # genuine fallbacks; leftover tail groups are deliberate
-                    # routing — count them apart so the bench's honesty
-                    # metric stays meaningful.
-                    if len(doc.content) > over_length or (
-                        route is not None and routed.get(id(doc), False)
-                    ):
-                        METRICS.inc("worker_host_fallback_total")
-                    else:
-                        METRICS.inc("worker_host_tail_total")
-                    outcome = execute_processing_pipeline(self.host_executor, doc)
-                    if outcome is not None:
-                        outs.append(outcome)
-                return outs
-
-            def _drain_front():
-                nonlocal inflight
-                kind, payload = window.popleft()
-                ta = time.perf_counter()
-                with TRACER.span("post", {"kind": kind, "phase": phase}):
-                    if kind == "batch":
-                        inflight -= 1
-                        METRICS.set("inflight_batches", inflight)
-                        TRACER.counter("inflight_batches", inflight)
-                        b, stats = payload
-                        outcomes, alive = self._execute_packed(b, phase, stats)
-                        survivors.extend(alive)
-                    else:
-                        outcomes = _process_fallback(payload)
-                dt = time.perf_counter() - ta
-                timing["drain"] += dt
-                METRICS.inc("stage_post_seconds", dt)
-                return outcomes
-
-            src, src_close = self._packed_source(
-                iter(current),
-                host_tail_max=host_tail_max,
-                route_fn=route,
-                overlapped=overlapped,
-            )
-            try:
-                for item, fallback in src:
-                    if item is not None:
-                        # Overlapped items are pack futures; resolving here
-                        # keeps FIFO order (futures complete out of order,
-                        # but we only ever wait on the oldest).
-                        if hasattr(item, "result"):
-                            if WATCHDOG.enabled:
-                                WATCHDOG.wait("pack_wait", item.done)
-                            batch = item.result()
-                        else:
-                            batch = item
-                        if overlapped:
-                            METRICS.set("queue_depth_pack", src.qsize())
-                            TRACER.counter("queue_depth_pack", src.qsize())
-                        n_batches += 1
-                        td = time.perf_counter()
-                        with TRACER.span(
-                            "dispatch",
-                            {"bucket": batch.max_len,
-                             "rows": batch.batch_size, "phase": phase},
-                        ):
-                            stats = self._dispatch_window(
-                                batch, phase, no_overlap
-                            )
-                        dt = time.perf_counter() - td
-                        timing["dispatch"] += dt
-                        METRICS.inc("stage_dispatch_seconds", dt)
-                        window.append(("batch", (batch, stats)))
-                        inflight += 1
-                        METRICS.set("inflight_batches", inflight)
-                        TRACER.counter("inflight_batches", inflight)
-                    if fallback:
-                        window.append(("host", fallback))
-                    # Host groups at the front never block on the device —
-                    # draining them early IS the read/post overlap; batch
-                    # entries drain once more than ``depth`` are in flight.
-                    while window and (
-                        window[0][0] == "host" or inflight > depth
-                    ):
-                        yield from _drain_front()
-                while window:
-                    yield from _drain_front()
-            finally:
-                src_close()
-                METRICS.set("inflight_batches", 0)
-            if debug:
-                print(
-                    f"[phase {phase}] docs={n_in} batches={n_batches} "
-                    f"survivors={len(survivors)} depth={depth} "
-                    f"{time.perf_counter()-t0:.2f}s "
-                    f"(dispatch {timing['dispatch']:.2f}s "
-                    f"drain {timing['drain']:.2f}s)",
-                    flush=True,
+            with TRACER.span(
+                "phase",
+                {"chunk": chunk_id, "phase": phase, "docs_in": len(current)},
+            ) as span:
+                survivors, n_batches = yield from self._run_phase(
+                    current,
+                    phase,
+                    depth,
+                    overlapped,
+                    no_overlap,
+                    # Phase 0 only: later phases' survivors already passed it.
+                    _host_routed if phase == 0 else None,
+                    routed,
                 )
+                if span.live:
+                    span.add_args(
+                        {"batches": n_batches, "survivors": len(survivors)}
+                    )
             current = survivors
             if not current:
                 break
+
+    def _run_phase(
+        self, current, phase, depth, overlapped, no_overlap, route, routed
+    ):
+        """One phase of :meth:`process_chunk`: yields its final outcomes in
+        window order and returns ``(survivors, batches dispatched)``."""
+        n_batches = 0
+        survivors: List[TextDocument] = []
+        # FIFO window entries: ("batch", (batch, stats)) dispatched and
+        # awaiting assembly, or ("host", docs) fallback groups awaiting
+        # their host-oracle pass.  ``inflight`` counts batch entries only.
+        window: deque = deque()
+        inflight = 0
+        # Host-oracle threshold for leftover groups: the first phase's
+        # program is cheap (it exists to kill docs early), so the device
+        # wins even for small groups; later phases carry the expensive
+        # kernels and the (bit-exact) host oracle wins below ~half a
+        # batch.  Mesh runs keep every doc on device (shard accounting),
+        # and TEXTBLAST_HOST_TAILS=off pins tails to the device too (the
+        # parity suites use it so device kernels decide every doc).
+        if self.mesh is None and os.environ.get("TEXTBLAST_HOST_TAILS") != "off":
+            # Per-bucket: the cutoff tracks each bucket's own row budget
+            # (with a uniform geometry this is the historical scalar).
+            div = 16 if phase == 0 else 2
+            host_tail_max = {
+                b: self.geometry.batch_for(b) // div
+                for b in self.geometry.buckets
+            }
+        else:
+            host_tail_max = 0
+        over_length = self.buckets[-1] - PACK_MARGIN
+
+        def _process_fallback(fallback_docs):
+            kind = "tail"
+            for doc in fallback_docs:
+                # Over-length and routed (dict-script/astral) docs are
+                # genuine fallbacks; leftover tail groups are deliberate
+                # routing — count them apart so the bench's honesty
+                # metric stays meaningful.
+                if len(doc.content) > over_length:
+                    METRICS.inc("worker_host_fallback_total")
+                    if kind == "tail":
+                        kind = "over_length"
+                elif route is not None and routed.get(id(doc), False):
+                    METRICS.inc("worker_host_fallback_total")
+                    kind = "routed"
+                else:
+                    METRICS.inc("worker_host_tail_total")
+            outs = self._host_block(
+                "host_tail", {"kind": kind, "docs": len(fallback_docs)},
+                "stage_host_tail_seconds", self.host_executor, fallback_docs,
+            )
+            return [o for o in outs if o is not None]
+
+        def _drain_front():
+            nonlocal inflight
+            kind, payload = window.popleft()
+            ta = _time_mod.perf_counter()
+            with TRACER.span("post", {"kind": kind, "phase": phase}):
+                if kind == "batch":
+                    inflight -= 1
+                    METRICS.set("inflight_batches", inflight)
+                    TRACER.counter("inflight_batches", inflight)
+                    b, stats = payload
+                    outcomes, alive = self._execute_packed(b, phase, stats)
+                    survivors.extend(alive)
+                else:
+                    outcomes = _process_fallback(payload)
+            METRICS.inc("stage_post_seconds", _time_mod.perf_counter() - ta)
+            return outcomes
+
+        src, src_close = self._packed_source(
+            iter(current),
+            host_tail_max=host_tail_max,
+            route_fn=route,
+            overlapped=overlapped,
+        )
+        try:
+            for item, fallback in src:
+                if item is not None:
+                    # Overlapped items are pack futures; resolving here
+                    # keeps FIFO order (futures complete out of order,
+                    # but we only ever wait on the oldest).
+                    if hasattr(item, "result"):
+                        if item.done():
+                            batch = item.result()
+                        else:
+                            with TRACER.span(
+                                "pack_wait", {"batch": item.batch_seq}
+                            ):
+                                if WATCHDOG.enabled:
+                                    WATCHDOG.wait("pack_wait", item.done)
+                                batch = item.result()
+                    else:
+                        batch = item
+                    if overlapped:
+                        METRICS.set("queue_depth_pack", src.qsize())
+                        TRACER.counter("queue_depth_pack", src.qsize())
+                    n_batches += 1
+                    td = _time_mod.perf_counter()
+                    with TRACER.span(
+                        "dispatch",
+                        {"batch": batch.seq, "bucket": batch.max_len,
+                         "rows": batch.batch_size, "phase": phase},
+                    ):
+                        stats = self._dispatch_window(
+                            batch, phase, no_overlap
+                        )
+                    METRICS.inc(
+                        "stage_dispatch_seconds", _time_mod.perf_counter() - td
+                    )
+                    window.append(("batch", (batch, stats)))
+                    inflight += 1
+                    METRICS.set("inflight_batches", inflight)
+                    TRACER.counter("inflight_batches", inflight)
+                if fallback:
+                    window.append(("host", fallback))
+                # Host groups at the front never block on the device —
+                # draining them early IS the read/post overlap; batch
+                # entries drain once more than ``depth`` are in flight.
+                while window and (
+                    window[0][0] == "host" or inflight > depth
+                ):
+                    yield from _drain_front()
+            while window:
+                yield from _drain_front()
+        finally:
+            src_close()
+            METRICS.set("inflight_batches", 0)
+        return survivors, n_batches
 
     _BADWORDS_PASS_STAMPS = (("c4_badwords_filter_status", "passed"),)
 
@@ -2322,7 +2423,11 @@ def process_documents_device(
     chunk_size = max(4 * pipeline.batch_size, 4096)
     stream = doc_stream()
     while True:
-        chunk = list(islice(stream, chunk_size))
+        # The number process_chunk gives this chunk's phase spans.
+        with TRACER.span("chunk_fill", {"chunk": pipeline._chunks}) as span:
+            chunk = list(islice(stream, chunk_size))
+            if span.live:
+                span.add_args({"docs": len(chunk)})
         if not chunk:
             break
         yield from pipeline.process_chunk(chunk)

@@ -26,6 +26,7 @@ from .errors import PipelineError, StepError
 from .executor import PipelineExecutor
 from .io import ParquetInputConfig, ParquetReader, ParquetWriter
 from .utils.metrics import FILTER_DROP_PREFIX, METRICS
+from .utils.trace import TRACER
 
 logger = logging.getLogger(__name__)
 
@@ -168,6 +169,17 @@ def aggregate_results_from_stream(
 
     out_writer = ParquetWriter(output_file)
     excl_writer = ParquetWriter(excluded_file)
+
+    def write(writer, batch) -> None:
+        # Blocking on a full writer queue (or, serial, the write itself)
+        # shows as the driving thread's ``write_enqueue`` span.
+        with TRACER.span("write_enqueue", {"rows": len(batch)}):
+            writer.write_batch(batch)
+
+    def close(writer) -> None:
+        with TRACER.span("write_enqueue", {"rows": 0, "close": 1}):
+            writer.close()
+
     if write_queue > 0:
         from .utils.overlap import ThreadedWriter
 
@@ -192,14 +204,14 @@ def aggregate_results_from_stream(
                 METRICS.inc("producer_results_success_total")
                 out_batch.append(outcome.document)
                 if len(out_batch) >= PARQUET_WRITE_BATCH_SIZE:
-                    out_writer.write_batch(out_batch)
+                    write(out_writer, out_batch)
                     out_batch.clear()
             elif outcome.kind == ProcessingOutcome.FILTERED:
                 result.filtered += 1
                 METRICS.inc("producer_results_filtered_total")
                 excl_batch.append(outcome.document)
                 if len(excl_batch) >= PARQUET_WRITE_BATCH_SIZE:
-                    excl_writer.write_batch(excl_batch)
+                    write(excl_writer, excl_batch)
                     excl_batch.clear()
             else:
                 # Error outcomes are counted in neither file (rs:168-170);
@@ -233,11 +245,11 @@ def aggregate_results_from_stream(
                     logger.error("Additional writer-teardown failure: %s", e)
 
         if out_batch:
-            guarded(lambda: out_writer.write_batch(out_batch))
+            guarded(lambda: write(out_writer, out_batch))
         if excl_batch:
-            guarded(lambda: excl_writer.write_batch(excl_batch))
-        guarded(out_writer.close)
-        guarded(excl_writer.close)
+            guarded(lambda: write(excl_writer, excl_batch))
+        guarded(lambda: close(out_writer))
+        guarded(lambda: close(excl_writer))
         if teardown_error is not None:
             if primary is None:
                 raise teardown_error

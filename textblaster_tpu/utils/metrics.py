@@ -508,8 +508,24 @@ _SPECS: Dict[str, Tuple[str, str]] = {
     ),
     "stage_post_seconds": (
         "counter",
-        "Wall seconds in host post-passes (assembly, TokenCounter, "
-        "C4BadWords re-decides, host-oracle reruns)",
+        "Wall seconds in host post-passes: the device wait, batch assembly "
+        "(C4BadWords re-decides included), the host steps after the last "
+        "phase (stage_host_suffix_seconds), host-oracle tails and reruns "
+        "(stage_host_tail_seconds), and the window's bookkeeping",
+    ),
+    # Parts of stage_post_seconds, so not in STAGE_COUNTERS (the stage
+    # breakdown would count them twice).
+    "stage_host_suffix_seconds": (
+        "counter",
+        "Wall seconds running the host steps that follow the last device "
+        "phase (e.g. TokenCounter), one block per batch; part of "
+        "stage_post_seconds",
+    ),
+    "stage_host_tail_seconds": (
+        "counter",
+        "Wall seconds on the host oracle: leftover tail groups, routed and "
+        "over-length documents, kernel-overflow reruns and the degradation "
+        "ladder's host rung; part of stage_post_seconds",
     ),
     "stage_write_seconds": (
         "counter",

@@ -56,8 +56,11 @@ _DONE = object()
 
 
 class _PrefetchIterator:
-    def __init__(self, source: Iterable, depth: int, block: int) -> None:
+    def __init__(
+        self, source: Iterable, depth: int, block: int, label: str = "read"
+    ) -> None:
         self._queue: "queue.Queue[Any]" = queue.Queue(maxsize=max(1, depth))
+        self._label = label
         self._stop = threading.Event()
         self._block: List[Any] = []
         self._pos = 0
@@ -108,10 +111,16 @@ class _PrefetchIterator:
                 return item
             if self._done:
                 raise StopIteration
-            if WATCHDOG.enabled:
-                got = WATCHDOG.queue_get("read_prefetch", self._queue)
-            else:
-                got = self._queue.get()
+            try:
+                got = self._queue.get_nowait()
+            except queue.Empty:
+                # The consumer waits on its producer: a ``feed_wait`` span
+                # (per block, and only when the queue ran dry).
+                with TRACER.span("feed_wait", {"queue": self._label}):
+                    if WATCHDOG.enabled:
+                        got = WATCHDOG.queue_get("read_prefetch", self._queue)
+                    else:
+                        got = self._queue.get()
             # Per-block (never per-item): the gauge feeds the live rollup's
             # read-queue track the same way ThreadedWriter feeds write's.
             METRICS.set("queue_depth_read", self._queue.qsize())
@@ -143,15 +152,18 @@ class _PrefetchIterator:
         self._stop.set()
 
 
-def prefetch_iter(source: Iterable, depth: int = 4, block: int = 256):
+def prefetch_iter(
+    source: Iterable, depth: int = 4, block: int = 256, label: str = "read"
+):
     """Run ``source`` on a background thread, ``depth`` blocks ahead.
 
     Items are forwarded in order; source exceptions re-raise at the
     consumer's ``next()`` at the position they occurred.  ``block`` items
     are handed over per queue op to keep synchronization off the per-item
-    hot path.
+    hot path.  ``label`` names the queue in the consumer's ``feed_wait``
+    spans.
     """
-    return _PrefetchIterator(source, depth=depth, block=block)
+    return _PrefetchIterator(source, depth=depth, block=block, label=label)
 
 
 #: Process-wide pack pools, keyed by worker count (executors cannot grow,

@@ -1,30 +1,41 @@
-"""Dependency-free span tracer emitting Chrome trace-event JSON.
+"""The program's span tracer: Chrome trace-event JSON, and program spans
+in the profiler's own trace.
 
 The overlapped pipeline (utils/overlap.py + ops/pipeline.py process_chunk)
 runs read/pack/dispatch/device-wait/post/write across four thread lanes,
 and the multihost path adds negotiated lockstep rounds on top — the flat
 Prometheus counters in utils/metrics.py say *how much* time each stage
-took, but not *where the bubbles are*.  This module records per-batch
-spans and resilience instant events into the Chrome trace-event format
-(the JSON array flavor), which loads directly in Perfetto
-(https://ui.perfetto.dev) or chrome://tracing:
+took, but not *where the bubbles are*.  ``TRACER.span`` marks per-batch,
+per-group and per-chunk stages (never per document) and has two sinks:
 
-* ``"X"`` complete events — one per span, with microsecond ``ts``/``dur``;
-* ``"i"`` instant events — resilience transitions (retry, breaker
-  trip/probe/recovery, negotiated verdicts, joint degradation);
-* ``"C"`` counter events — queue depths, so Perfetto draws them as tracks;
-* ``"M"`` metadata events — process/thread names, so each overlap thread
-  (textblast-prefetch / textblast-pack-N / textblast-writer / MainThread)
-  gets its own labeled lane.
+* **Chrome trace-event JSON** (``--trace out.json``), which loads directly
+  in Perfetto (https://ui.perfetto.dev) or chrome://tracing:
+
+  - ``"X"`` complete events — one per span, with microsecond ``ts``/``dur``;
+  - ``"i"`` instant events — resilience transitions (retry, breaker
+    trip/probe/recovery, negotiated verdicts, joint degradation);
+  - ``"C"`` counter events — queue depths, so Perfetto draws them as tracks;
+  - ``"M"`` metadata events — process/thread names, so each overlap thread
+    (textblast-prefetch / textblast-pack-N / textblast-writer / MainThread)
+    gets its own labeled lane.
+
+* **The profiler's trace.**  While a ``jax.profiler`` session is active
+  (``--trace-device``, or any ``jax.profiler.start_trace``), every span
+  also opens a ``jax.profiler.TraceAnnotation`` named ``tb.<span>`` with
+  the span's args as its metadata.  The spans then land in the same
+  ``.xplane.pb`` as the device operations, on the profiler's clock, each on
+  the line of the thread that emitted it — so an idle stretch of the device
+  can be read against the program stage that was running on the host.  The
+  ``tb.`` prefix keeps them apart from JAX's and the runtime's own events.
 
 Design constraints, in order:
 
-1. **Near-zero cost when off.**  Tracing is opt-in (``--trace out.json``).
-   Disabled, ``TRACER.span()`` is one attribute check returning a shared
-   no-op context manager — no allocation, no lock.  All span sites are
-   per-batch or per-round (never per-document), so even enabled the event
-   rate is tiny next to the work being traced.
-2. **Bounded memory.**  Events accumulate in a ring buffer; with a file
+1. **Near-zero cost when off.**  With neither sink on, ``TRACER.span()`` is
+   one attribute check and one ``TraceAnnotation.is_enabled()`` call
+   returning a shared no-op context manager — no allocation, no lock.  All
+   span sites are per batch, group, chunk or round (never per document),
+   so even enabled the event rate is tiny next to the work being traced.
+2. **Bounded memory.**  JSON events accumulate in a ring buffer; with a file
    configured the buffer spills to disk whenever it fills, so a
    multi-hour run holds at most ``ring`` events in memory.  Without a
    file (in-memory mode, used by tests) the ring simply drops the oldest
@@ -36,9 +47,10 @@ Design constraints, in order:
    array, so a killed run still yields a loadable trace.  ``close()``
    writes the terminator for well-formed JSON.
 
-An opt-in bridge to ``jax.profiler.trace`` (``device_profile``) captures
-the XLA device-side profile alongside the host-side spans — the host
-trace shows *that* the device wait dominated; the profiler shows *why*.
+``device_profile`` (``--trace-device``) runs a ``jax.profiler`` session
+around a block: the XLA device profile and the program's ``tb.*`` spans in
+one file — the spans show *which stage* the device waited on; the device
+lines show *what ran*.
 """
 
 from __future__ import annotations
@@ -56,12 +68,43 @@ logger = logging.getLogger(__name__)
 
 __all__ = ["Tracer", "TRACER", "device_profile"]
 
+#: Name prefix of the program's spans in the profiler's trace.
+SPAN_PREFIX = "tb."
+
+#: ``jax.profiler.TraceAnnotation`` once JAX is imported (see
+#: ``_session_active``).
+_Annotation = None
+
+
+def _session_active() -> bool:
+    """Whether a profiler session is recording.  No session can be active
+    before JAX is imported, and this module does not import JAX itself;
+    once it is, the name is rebound to ``TraceAnnotation.is_enabled``, so
+    every later call is that one C++ call."""
+    global _Annotation, _session_active
+    if "jax" not in sys.modules:
+        return False
+    from jax.profiler import TraceAnnotation
+
+    _Annotation = TraceAnnotation
+    _session_active = TraceAnnotation.is_enabled
+    return TraceAnnotation.is_enabled()
+
+
+def _annotation(name: str, args: Optional[Dict[str, Any]]):
+    """The ``TraceAnnotation`` of span ``name``, its args as metadata."""
+    return _Annotation(SPAN_PREFIX + name, **(args or {}))
+
 
 class _NullSpan:
-    """Shared no-op context manager returned by every call while tracing
-    is disabled — the entire off-cost of a span site."""
+    """Shared no-op context manager returned by every call while neither
+    sink records — the entire off-cost of a span site."""
 
     __slots__ = ()
+
+    #: Whether the span records anywhere; a site computes costly args
+    #: only for a live span.
+    live = False
 
     def __enter__(self):
         return self
@@ -76,8 +119,32 @@ class _NullSpan:
 _NULL_SPAN = _NullSpan()
 
 
+class _ProfilerSpan:
+    """A span for the profiler's trace alone (JSON tracing off)."""
+
+    __slots__ = ("_ann",)
+    live = True
+
+    def __init__(self, name: str, args: Optional[Dict[str, Any]]):
+        self._ann = _annotation(name, args)
+
+    def __enter__(self):
+        self._ann.__enter__()
+        return self
+
+    def add_args(self, args: Dict[str, Any]) -> None:
+        """Args known only at the end of the span become metadata of the
+        same profiler event."""
+        self._ann.set_metadata(**args)
+
+    def __exit__(self, *exc):
+        self._ann.__exit__(None, None, None)
+        return False
+
+
 class _Span:
-    __slots__ = ("_tracer", "_name", "_args", "_t0")
+    __slots__ = ("_tracer", "_name", "_args", "_t0", "_ann")
+    live = True
 
     def __init__(self, tracer: "Tracer", name: str, args: Optional[Dict[str, Any]]):
         self._tracer = tracer
@@ -85,6 +152,10 @@ class _Span:
         self._args = args
 
     def __enter__(self):
+        self._ann = None
+        if _session_active():
+            self._ann = _annotation(self._name, self._args)
+            self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
 
@@ -96,9 +167,14 @@ class _Span:
             self._args = dict(args)
         else:
             self._args = {**self._args, **args}
+        if self._ann is not None:
+            self._ann.set_metadata(**args)
 
     def __exit__(self, *exc):
-        self._tracer._complete(self._name, self._t0, time.perf_counter(), self._args)
+        t1 = time.perf_counter()
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+        self._tracer._complete(self._name, self._t0, t1, self._args)
         return False
 
 
@@ -233,11 +309,14 @@ class Tracer:
     # --- recording ----------------------------------------------------------
 
     def span(self, name: str, args: Optional[Dict[str, Any]] = None):
-        """Context manager recording one ``"X"`` complete event on the
-        current thread's lane."""
-        if not self.enabled:
-            return _NULL_SPAN
-        return _Span(self, name, args)
+        """Context manager recording one span on the current thread's lane:
+        an ``"X"`` complete event while JSON tracing is on, and a
+        ``tb.<name>`` annotation while a profiler session is active."""
+        if self.enabled:
+            return _Span(self, name, args)
+        if _session_active():
+            return _ProfilerSpan(name, args)
+        return _NULL_SPAN
 
     def instant(self, name: str, args: Optional[Dict[str, Any]] = None) -> None:
         """Record a zero-duration ``"i"`` event (resilience transitions)."""
@@ -397,8 +476,10 @@ TRACER = Tracer()
 def device_profile(log_dir: Optional[str]):
     """Opt-in bridge to ``jax.profiler.trace``: captures the XLA device
     profile (TensorBoard/Perfetto-loadable) into ``log_dir`` for the
-    duration of the block.  ``log_dir=None`` is a no-op, and a backend
-    without profiler support degrades to a warning, not a failure."""
+    duration of the block, with the program's ``tb.*`` spans on the host
+    threads' lines of the same file.  ``log_dir=None`` is a no-op, and a
+    backend without profiler support degrades to a warning, not a
+    failure."""
     if not log_dir:
         yield
         return
