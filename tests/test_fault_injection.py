@@ -217,6 +217,39 @@ def test_device_split_rung_recovers(config):
     assert FAULTS.fired("device.execute") == 5
 
 
+def test_split_rung_of_half_row_batch_uses_warm_program(config):
+    # A faulted half-row tail batch splits into halves packed at the
+    # bucket's half-row count again — the program warmup installed for the
+    # split rung — and compiles nothing new mid-incident.
+    from textblaster_tpu.ops.packing import pack_documents
+    from textblaster_tpu.ops.pipeline import CompiledPipeline
+
+    pipeline = CompiledPipeline(config, buckets=(512,), batch_size=32)
+    half = pipeline._warm_half_rows(512)
+    assert half == 16
+    assert [rows for *_, rows in pipeline._warmup_jobs()] == [32, 16]
+
+    def run():
+        return pipeline._execute_packed(
+            pack_documents(_docs(10), half, 512), phase=0
+        )
+
+    clean = run()
+    keys = set(pipeline._jitted)
+    assert (512, 0, half) in keys
+    # times=4: the full-batch policy budget (1 + 3 retries) fails; both
+    # halves then dispatch clean.
+    FAULTS.inject("device.execute", OSError("persistent-ish"), times=4)
+    faulted, deltas = _metric_deltas(
+        run, "resilience_ladder_split_total", "resilience_ladder_host_total"
+    )
+    assert deltas["resilience_ladder_split_total"] == 1
+    assert deltas["resilience_ladder_host_total"] == 0
+    assert FAULTS.fired("device.execute") == 4
+    assert set(pipeline._jitted) == keys
+    assert _outcome_key(faulted[0]) == _outcome_key(clean[0])
+
+
 def test_device_outage_host_rung_and_breaker(config):
     docs = _docs(40)
     clean = list(process_documents_device(config, iter(docs), device_batch=8))

@@ -18,7 +18,7 @@ path rather than truncated.
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import Iterator, List, Optional, Sequence, Tuple
+from typing import Dict, Iterator, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -31,6 +31,9 @@ __all__ = [
     "pack_documents",
     "pack_documents_loop",
     "iter_packed_batches",
+    "HOST_TAIL_FILL",
+    "HOST_TAIL_FILL_FIRST",
+    "fill_tail_rows",
 ]
 
 # Bucket char capacities.  Most CC documents are < 8k chars; the tail gets the
@@ -41,6 +44,19 @@ DEFAULT_BUCKETS: Tuple[int, ...] = (512, 2048, 8192, 32768, 65536)
 #: stream wraps the text in boundary markers), so a bucket admits documents
 #: only up to this many chars below its capacity.
 PACK_MARGIN = 4
+
+#: On an accelerator a leftover group goes to the host oracle only when its
+#: codepoints fill less than this share of the padded lanes of the program
+#: that would take it.  Measured on a TPU v5e: the host oracle costs about
+#: 2.8 us per character of a long document, a phase-1/2 program about
+#: 0.3-0.35 us per padded lane, so the device is the cheaper side above a
+#: fill of about 1/8.
+HOST_TAIL_FILL = 1 / 8
+
+#: The same share in the first phase.  Its program (language ID) costs 1/5
+#: to 1/13 of a later phase's device seconds per padded lane on the same
+#: chip, while the host oracle runs the whole pipeline whatever the phase.
+HOST_TAIL_FILL_FIRST = 1 / 64
 
 
 @dataclass
@@ -137,6 +153,27 @@ def pack_documents(
     return PackedBatch(cps=cps, lengths=lengths, valid=valid, docs=list(docs))
 
 
+def fill_tail_rows(
+    lengths: Sequence[int],
+    bucket: int,
+    full_rows: int,
+    half_rows: int,
+    min_fill: float = HOST_TAIL_FILL,
+) -> Optional[int]:
+    """Rows to pack a leftover group of documents of ``lengths`` chars at in
+    ``bucket``, or None for the host oracle — the accelerator rule.
+
+    A group of at most ``half_rows`` documents takes the half-row program
+    (``full_rows`` where no such program is warm, i.e. ``half_rows ==
+    full_rows``), a larger one the full-row program; the group goes to the
+    host only if its codepoints fill less than ``min_fill`` of that
+    program's padded lanes."""
+    rows = half_rows if len(lengths) <= half_rows else full_rows
+    if sum(lengths) < min_fill * rows * bucket:
+        return None
+    return rows
+
+
 def iter_packed_batches(
     docs: Iterator[TextDocument],
     batch_size: int = 256,
@@ -146,6 +183,8 @@ def iter_packed_batches(
     pack_fn=pack_documents,
     geometry=None,
     overflow_flush: int = 64,
+    half_rows: Optional[Dict[int, int]] = None,
+    min_fill: float = HOST_TAIL_FILL,
 ) -> Iterator[Tuple[Optional[PackedBatch], List[TextDocument]]]:
     """Group a document stream into per-bucket batches.
 
@@ -171,12 +210,20 @@ def iter_packed_batches(
     recent) document needs — with a uniform geometry this degenerates to
     exactly the historical ``batch_size``-sized slices.  Each group is
     packed at the smallest bucket that fits its longest document — one
-    near-full batch instead of several near-empty ones.  Groups of at most
-    ``host_tail_max`` documents are handed back as fallback docs: below
-    that size the (bit-exact) host oracle is cheaper than any padded device
-    batch.  ``host_tail_max`` may be a per-bucket mapping — with unequal
-    row budgets the "below ~a fraction of a batch" cutoff must follow the
-    group's own bucket, not one global row count.
+    near-full batch instead of several near-empty ones.  Where each group
+    goes follows one of two rules:
+
+    * count (``half_rows`` None; XLA:CPU, where it was set): groups of at
+      most ``host_tail_max`` documents are handed back as fallback docs,
+      the rest pack at the bucket's full rows.  ``host_tail_max`` may be a
+      per-bucket mapping — with unequal row budgets the "below ~a fraction
+      of a batch" cutoff must follow the group's own bucket, not one global
+      row count.
+    * fill (``half_rows`` maps each bucket to its warm half-row count;
+      accelerators): a group of at most that many documents packs at the
+      half-row count, a larger one at full rows, and only a group that
+      fills less than ``min_fill`` of those padded lanes goes to the host
+      (:func:`fill_tail_rows`).
     """
     if geometry is not None:
         buckets = tuple(geometry.buckets)
@@ -211,6 +258,18 @@ def iter_packed_batches(
                     ), []
                 break
 
+    def flush(group: List[TextDocument], bucket: int):
+        if half_rows is None:
+            rows = None if len(group) <= tail_for[bucket] else rows_for[bucket]
+        else:
+            rows = fill_tail_rows(
+                [len(d.content) for d in group], bucket,
+                rows_for[bucket], half_rows[bucket], min_fill,
+            )
+        if rows is None:
+            return None, group
+        return pack_fn(group, batch_size=rows, max_len=bucket), []
+
     leftovers = [d for b in buckets for d in pending[b]]
     leftovers.sort(key=lambda d: len(d.content))
     group: List[TextDocument] = []
@@ -221,27 +280,14 @@ def iter_packed_batches(
         # geometry) its row budget only shrinks; flush when the group
         # already fills the incoming document's budget.
         if group and len(group) >= rows_for[need]:
-            if len(group) <= tail_for[group_bucket]:
-                yield None, group
-            else:
-                yield pack_fn(
-                    group, batch_size=rows_for[group_bucket], max_len=group_bucket
-                ), []
+            yield flush(group, group_bucket)
             group = []
         group.append(doc)
         group_bucket = need
         if len(group) >= rows_for[need]:
-            if len(group) <= tail_for[need]:
-                yield None, group
-            else:
-                yield pack_fn(group, batch_size=rows_for[need], max_len=need), []
+            yield flush(group, need)
             group = []
     if group:
-        if len(group) <= tail_for[group_bucket]:
-            yield None, group
-        else:
-            yield pack_fn(
-                group, batch_size=rows_for[group_bucket], max_len=group_bucket
-            ), []
+        yield flush(group, group_bucket)
     if overflow:
         yield None, overflow

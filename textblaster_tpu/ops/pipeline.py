@@ -65,6 +65,8 @@ from .langid_tpu import langid_scores
 from .geometry import DeviceGeometry
 from .packing import (
     DEFAULT_BUCKETS,
+    HOST_TAIL_FILL,
+    HOST_TAIL_FILL_FIRST,
     PACK_MARGIN,
     PackedBatch,
     iter_packed_batches,
@@ -357,6 +359,13 @@ def _toggle_xla_compilation_cache(on: bool) -> bool:
     return True
 
 
+def _tails_by_fill() -> bool:
+    """Whether leftover groups are routed by fill (accelerators) rather
+    than by count (XLA:CPU, where the count rule was set); see
+    ``CompiledPipeline._run_phase``."""
+    return jax.default_backend() != "cpu"
+
+
 def should_warmup(warmup: Optional[bool] = None) -> bool:
     """Resolve the warmup tri-state: explicit flag > ``TEXTBLAST_WARMUP``
     env > backend default (accelerators warm — cold TPU compiles
@@ -537,6 +546,7 @@ class CompiledPipeline:
         # host tail reads 0 rather than nothing.
         METRICS.inc("stage_host_suffix_seconds", 0.0)
         METRICS.inc("stage_host_tail_seconds", 0.0)
+        METRICS.inc("worker_device_tail_total", 0)
 
     def _badwords_host_step(self, idx: int):
         """The real host C4BadWordsFilter for device step ``idx`` — runs only
@@ -794,6 +804,14 @@ class CompiledPipeline:
         half = (full + 1) // 2
         return min(full, ((half + ROWS - 1) // ROWS) * ROWS)
 
+    def _warm_half_rows(self, length: int) -> int:
+        """The half-row count warmup installs for bucket ``length`` (the
+        split rung's), or the bucket's full rows where it installs none."""
+        full = self.geometry.batch_for(length)
+        if self._split_retry and self.mesh is None:
+            return self._split_rows(full)
+        return full
+
     def _warmup_jobs(self, include_split_rows: bool = True):
         """``(program key, length, phase, rows)`` tuples warmup must cover:
         every (bucket, phase) at geometry rows — plus the degradation
@@ -810,13 +828,8 @@ class CompiledPipeline:
         for length in self.buckets:
             full = self.geometry.batch_for(length)
             variants = [full]
-            sub = self._split_rows(full)
-            if (
-                include_split_rows
-                and self._split_retry
-                and self.mesh is None
-                and sub != full
-            ):
+            sub = self._warm_half_rows(length)
+            if include_split_rows and sub != full:
                 variants.append(sub)
             for phase in range(len(self.phases)):
                 for rows in variants:
@@ -1747,9 +1760,10 @@ class CompiledPipeline:
         outcomes: List[ProcessingOutcome] = []
         survivors: List[TextDocument] = []
         if self._split_retry and self.mesh is None and len(batch.docs) > 1:
-            # Split rung.  Both halves pack to the same padded row count so
-            # they share one traced program shape (a fresh jit entry — the
-            # warmup's AOT executables are fixed to the full batch size).
+            # Split rung.  Both halves pack to the bucket's half-row count,
+            # the program warmup installed for this rung — also when the
+            # faulted batch is itself a half-row tail group, so no program
+            # compiles mid-incident.
             METRICS.inc("resilience_ladder_split_total")
             TRACER.instant(
                 "ladder_split", {"bucket": batch.max_len, "phase": phase}
@@ -1757,7 +1771,7 @@ class CompiledPipeline:
             if EVENTS.enabled:
                 EVENTS.emit("ladder_split", batch=batch.max_len,
                             depth=len(batch.docs), phase=phase)
-            sub_rows = self._split_rows(batch.batch_size)
+            sub_rows = self._split_rows(self.geometry.batch_for(batch.max_len))
             mid = (len(batch.docs) + 1) // 2
             for part in (batch.docs[:mid], batch.docs[mid:]):
                 if not part:
@@ -2015,7 +2029,10 @@ class CompiledPipeline:
             )
         return self._pack_pool_obj
 
-    def _packed_source(self, docs_iter, host_tail_max, route_fn, overlapped):
+    def _packed_source(
+        self, docs_iter, host_tail_max, half_rows, min_fill, route_fn,
+        overlapped,
+    ):
         """The packer stage for one phase.
 
         Serial: the grouping generator inline, packing on the caller's
@@ -2030,6 +2047,8 @@ class CompiledPipeline:
         kwargs = dict(
             geometry=self.geometry,
             host_tail_max=host_tail_max,
+            half_rows=half_rows,
+            min_fill=min_fill,
             route_fn=route_fn,
             overflow_flush=max(1, self._overlap.overflow_flush),
         )
@@ -2156,7 +2175,17 @@ class CompiledPipeline:
         self, current, phase, depth, overlapped, no_overlap, route, routed
     ):
         """One phase of :meth:`process_chunk`: yields its final outcomes in
-        window order and returns ``(survivors, batches dispatched)``."""
+        window order and returns ``(survivors, batches dispatched)``.
+
+        The phase's leftover groups (``iter_packed_batches``) are routed by
+        count on XLA:CPU: at most 1/16 of the bucket's rows in phase 0, half
+        of them after, go to the host oracle.  On an accelerator they are
+        routed by fill: a group of at most the bucket's warm half-row count
+        packs at it, and only a group that fills less than
+        ``HOST_TAIL_FILL`` of its padded lanes (``HOST_TAIL_FILL_FIRST`` in
+        phase 0, whose program is the cheapest) goes to the host.  Documents
+        in groups the count rule would have given the host and the fill rule
+        sends to the device count in ``worker_device_tail_total``."""
         n_batches = 0
         survivors: List[TextDocument] = []
         # FIFO window entries: ("batch", (batch, stats)) dispatched and
@@ -2164,13 +2193,19 @@ class CompiledPipeline:
         # their host-oracle pass.  ``inflight`` counts batch entries only.
         window: deque = deque()
         inflight = 0
-        # Host-oracle threshold for leftover groups: the first phase's
-        # program is cheap (it exists to kill docs early), so the device
-        # wins even for small groups; later phases carry the expensive
-        # kernels and the (bit-exact) host oracle wins below ~half a
-        # batch.  Mesh runs keep every doc on device (shard accounting),
-        # and TEXTBLAST_HOST_TAILS=off pins tails to the device too (the
-        # parity suites use it so device kernels decide every doc).
+        # Leftover-group routing.  The count rule, set on XLA:CPU: the first
+        # phase's program is cheap (it exists to kill docs early), so the
+        # device wins even for small groups; later phases carry the
+        # expensive kernels and the (bit-exact) host oracle wins below
+        # ~half a batch.  Accelerators take the fill rule instead and keep
+        # the count thresholds only to count what the fill rule moved to
+        # the device.  Mesh runs keep every doc on device (shard
+        # accounting), and TEXTBLAST_HOST_TAILS=off pins tails to the
+        # device too (the parity suites use it so device kernels decide
+        # every doc).
+        host_tail_max = 0
+        half_rows = None
+        min_fill = HOST_TAIL_FILL_FIRST if phase == 0 else HOST_TAIL_FILL
         if self.mesh is None and os.environ.get("TEXTBLAST_HOST_TAILS") != "off":
             # Per-bucket: the cutoff tracks each bucket's own row budget
             # (with a uniform geometry this is the historical scalar).
@@ -2179,8 +2214,10 @@ class CompiledPipeline:
                 b: self.geometry.batch_for(b) // div
                 for b in self.geometry.buckets
             }
-        else:
-            host_tail_max = 0
+            if _tails_by_fill():
+                half_rows = {
+                    b: self._warm_half_rows(b) for b in self.geometry.buckets
+                }
         over_length = self.buckets[-1] - PACK_MARGIN
 
         def _process_fallback(fallback_docs):
@@ -2225,6 +2262,8 @@ class CompiledPipeline:
         src, src_close = self._packed_source(
             iter(current),
             host_tail_max=host_tail_max,
+            half_rows=half_rows,
+            min_fill=min_fill,
             route_fn=route,
             overlapped=overlapped,
         )
@@ -2250,6 +2289,12 @@ class CompiledPipeline:
                         METRICS.set("queue_depth_pack", src.qsize())
                         TRACER.counter("queue_depth_pack", src.qsize())
                     n_batches += 1
+                    if (
+                        half_rows is not None
+                        and len(batch.docs) <= host_tail_max[batch.max_len]
+                    ):
+                        # Only a leftover group can be this small.
+                        METRICS.inc("worker_device_tail_total", len(batch.docs))
                     td = _time_mod.perf_counter()
                     with TRACER.span(
                         "dispatch",

@@ -209,7 +209,14 @@ _SPECS: Dict[str, Tuple[str, str]] = {
     "worker_host_tail_total": (
         "counter",
         "Documents deliberately routed to the host oracle as end-of-stream "
-        "tail groups too small to justify a padded device batch",
+        "tail groups too small (XLA:CPU) or too sparse (accelerators) to "
+        "justify a padded device batch",
+    ),
+    "worker_device_tail_total": (
+        "counter",
+        "Documents in end-of-stream tail groups the accelerator's fill rule "
+        "sent to the device that the XLA:CPU count rule would have sent to "
+        "the host oracle",
     ),
     "worker_fold_hazard_rows_total": (
         "counter",
