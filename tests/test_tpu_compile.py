@@ -14,7 +14,9 @@ tests (an entry compiled for a described chip cannot be read back here).
 """
 
 import os
+import re
 
+import numpy as np
 import pytest
 
 jax = pytest.importorskip("jax")
@@ -29,7 +31,7 @@ ROWS = 64  # the TPU default geometry's rows at the widest bucket
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
 
@@ -46,10 +48,15 @@ def one_chip():
     jax.config.update("jax_enable_x64", False)
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", cache_was)
     jax.config.update("jax_enable_x64", x64_was)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
 
 
 @pytest.fixture
@@ -140,19 +147,25 @@ def test_sort_kernel_compiles(one_chip, compiled_kernels, n_keys):
     _compile(fn, [((ROWS, pso._MAX_SORT_LANES), jnp.int32)] * n_keys, one_chip)
 
 
-def test_shipped_pipeline_programs_compile(one_chip, compiled_kernels, monkeypatch):
-    """Every phase program of the shipped config at the 2048 bucket, with
-    the TPU's own defaults (this process's backend is the CPU, so they are
-    steered here): the chain preps in ops/stats.py lower inside Mosaic."""
-    from textblaster_tpu.config.pipeline import load_pipeline_config
-    from textblaster_tpu.ops.pipeline import CompiledPipeline
-
+@pytest.fixture
+def tpu_defaults(compiled_kernels, monkeypatch):
+    """The TPU's own scan, table and wire choices and kernel probes (this
+    process's backend is the CPU, so they are steered here)."""
     monkeypatch.setenv("TEXTBLAST_SCAN_IMPL", "shift")
     monkeypatch.setenv("TEXTBLAST_TABLE_IMPL", "sort")
     monkeypatch.setenv("TEXTBLAST_WIRE", "u16")
     for mod, name in ((pso, "_probe_backend"), (psc, "_probe_backend"),
                       (psc, "_probe_fused"), (psc, "_probe_depfuse")):
         monkeypatch.setattr(mod, name, lambda: True)
+
+
+def test_shipped_pipeline_programs_compile(one_chip, tpu_defaults):
+    """Every phase program of the shipped config at the 2048 bucket, with
+    the TPU's own defaults: the chain preps in ops/stats.py lower inside
+    Mosaic."""
+    from textblaster_tpu.config.pipeline import load_pipeline_config
+    from textblaster_tpu.ops.pipeline import CompiledPipeline
+
     pipeline = CompiledPipeline(
         load_pipeline_config("configs/pipeline_config_offline.yaml"),
         batch_size=ROWS,
@@ -164,3 +177,48 @@ def test_shipped_pipeline_programs_compile(one_chip, compiled_kernels, monkeypat
             [((ROWS, 2048), jnp.uint16), ((ROWS,), jnp.int32)],
             one_chip,
         )
+
+
+#: A collective operation in compiled HLO text, by its kind.
+COLLECTIVE = re.compile(
+    r" (all-reduce|all-gather|reduce-scatter|collective-permute|all-to-all)"
+    r"(-start|-done)?\("
+)
+
+#: Collectives per phase program of the Danish job over a data mesh: one
+#: all-reduce in phase 1, the OR of the duplicate walk's batch-wide gate
+#: (ops/stats.py gopher_rep_stats), and two in phase 2, the ORs of C4's
+#: citation and pattern gates (c4_stage, _pattern_union_starts; two of the
+#: three share one all-reduce).  Each gate skips real work on every chip.
+MESH_COLLECTIVES = {0: [], 1: ["all-reduce"], 2: ["all-reduce"] * 2}
+
+
+@pytest.mark.parametrize("bucket", [2048, 32768])
+def test_mesh_programs_keep_only_the_gate_all_reduces(topo, tpu_defaults, bucket):
+    """Every phase program of the benchmark's Danish job over the 4-chip
+    data mesh, at 64 rows per chip, on both sides of the fused kernels'
+    16,384-lane gate.  The row sorts stack their jobs so that each chip's
+    rows stay on it (``ops/stats.py`` ``_stack_rows``), so the compiler
+    moves no row between chips; what is left are the scalar ORs of the
+    batch-wide ``lax.cond`` gates."""
+    from jax.sharding import Mesh
+
+    from textblaster_tpu.config.pipeline import load_pipeline_config
+    from textblaster_tpu.ops.pipeline import CompiledPipeline
+
+    chips = len(topo.devices)
+    pipeline = CompiledPipeline(
+        load_pipeline_config("benchmark/configs/danish_cc.yaml"),
+        batch_size=chips * ROWS,
+        mesh=Mesh(np.array(topo.devices), ("data",)),
+    )
+    assert pipeline.wire_u16
+    for phase in range(len(pipeline.phases)):
+        compiled = pipeline._fn_for(bucket, phase).lower(
+            jax.ShapeDtypeStruct((chips * ROWS, bucket), jnp.uint16),
+            jax.ShapeDtypeStruct((chips * ROWS,), jnp.int32),
+        ).compile()
+        text = compiled.as_text()
+        assert "tpu_custom_call" in text
+        found = [kind for kind, _ in COLLECTIVE.findall(text)]
+        assert found == MESH_COLLECTIVES[phase], (bucket, phase)

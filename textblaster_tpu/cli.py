@@ -86,7 +86,9 @@ def build_parser() -> argparse.ArgumentParser:
                           "docs past the largest bucket take the bit-exact host "
                           "fallback.  Default: the built-in long-doc set.")
     run.add_argument("--device-batch", type=int, default=None,
-                     help="Documents per device batch (tpu backend)")
+                     help="Documents per device batch (tpu backend), over "
+                          "all chips of a data mesh; by default each chip "
+                          "gets the rows one chip holds alone")
     run.add_argument("--auto-geometry", action="store_true",
                      help="Calibrate device geometry from the data: sample "
                           "document lengths from the head of the stream, "

@@ -103,6 +103,12 @@ _CJK_BADWORDS_LANGS = ("ja", "th", "zh")  # c4_filters.rs:70
 _BREAKER_OPEN = object()
 
 
+#: Documents per chunk per chip in ``process_documents_device``: a mesh's
+#: chunk grows with its chips, so each bucket fills as many batches per
+#: chunk as on one chip.
+CHUNK_DOCS = 4096
+
+
 def device_step_types() -> frozenset:
     return frozenset(_DEVICE_STEPS)
 
@@ -323,9 +329,10 @@ class WarmupStats:
     """Timing breakdown of one ``warmup_parallel`` call.
 
     ``total_s`` is wall time; ``trace_s``/``compile_s``/``cache_load_s``
-    attribute where it went (compile_s is summed across pool threads, so it
-    can exceed total_s on multi-core).  ``float(stats)`` is ``total_s`` for
-    drop-in use where the old float return was consumed."""
+    attribute where it went (compile_s and cache_load_s are summed across
+    pool threads, so they can exceed total_s on multi-core).
+    ``float(stats)`` is ``total_s`` for drop-in use where the old float
+    return was consumed."""
 
     total_s: float = 0.0
     trace_s: float = 0.0
@@ -418,24 +425,40 @@ class CompiledPipeline:
         mesh=None,
         phase_split: bool = True,
         geometry: Optional[DeviceGeometry] = None,
+        multihost: bool = False,
     ) -> None:
+        """``multihost``: built by a multi-host runtime
+        (``parallel/multihost.py``), whose processes negotiate one program
+        sequence and wire; its mesh keeps the int32 wire, the batch as
+        given and none of the one-controller path below."""
         self.config = config
         self.mesh = mesh
+        #: Chips one dispatch spans.
+        self.chips = mesh.devices.size if mesh is not None else 1
+        #: One controller dispatches every batch for every chip: no mesh, or
+        #: a mesh this process drives alone.  Such a pipeline takes the
+        #: one-chip path on each chip: the u16 wire, warm dispatch, the
+        #: ladder's split rung, leftover groups routed onto warm half-row
+        #: programs, the in-flight window and donated inputs.
+        self.one_controller = mesh is None or not multihost
         if geometry is not None:
             # Calibrated (or checkpoint-recorded) geometry supersedes the
             # buckets/batch_size knobs; mesh runs need every per-bucket batch
             # divisible by the device count.
             if mesh is not None:
-                geometry = geometry.with_batch_multiple(mesh.devices.size)
+                geometry = geometry.with_batch_multiple(self.chips)
             self.geometry = geometry
         else:
             bs = tuple(sorted(buckets))
-            if not batch_size:  # None or 0 — CLI passes ints through unguarded
-                batch_size = default_batch_size(bs)
+            default = default_batch_size(bs)
+            explicit = bool(batch_size)  # None or 0 — CLI passes ints through
+            if not explicit:
+                # Each chip of a one-controller mesh holds what one chip
+                # holds alone; an explicit batch is the global batch.
+                batch_size = default * (self.chips if self.one_controller else 1)
             if mesh is not None:
-                n_dev = mesh.devices.size
-                batch_size = max(n_dev, (batch_size // n_dev) * n_dev)
-            src = "default" if batch_size == default_batch_size(bs) else "explicit"
+                batch_size = max(self.chips, (batch_size // self.chips) * self.chips)
+            src = "explicit" if explicit and batch_size != default else "default"
             self.geometry = DeviceGeometry.uniform(bs, batch_size, source=src)
         self.buckets = self.geometry.buckets
         # The representative (largest) per-dispatch row count: chunk sizing,
@@ -484,9 +507,9 @@ class CompiledPipeline:
         # ~65 MB/s), and BMP codepoints fit uint16 exactly.  Rows containing
         # supplementary-plane chars (emoji etc.) are routed to the host
         # oracle instead — decisions stay bit-identical, attribution is the
-        # fallback counter.  Meshes keep int32 (multi-host sharding layers
-        # are not wire-bound the same way; one format keeps lockstep simple).
-        self.wire_u16 = self.mesh is None and _wire_u16()
+        # fallback counter.  Multi-host meshes keep int32 (one format keeps
+        # lockstep simple).
+        self.wire_u16 = self.one_controller and _wire_u16()
 
         # Multi-phase short-circuiting: always on single-controller runs
         # (including single-process meshes — one controller dispatches for
@@ -533,8 +556,8 @@ class CompiledPipeline:
         self._split_retry = rc.split_retry
 
         # Overlapped host pipeline (see process_chunk): depth of the device
-        # in-flight window and the pack-stage thread pool.  Mesh runs stay
-        # serial (lockstep dispatch must not reorder across hosts).
+        # in-flight window and the pack-stage thread pool.  Multi-host
+        # meshes stay serial here (their runtime keeps its own window).
         self._overlap = getattr(config, "overlap", None) or OverlapConfig()
         self._pack_pool_obj = None
         # Sequence numbers shared by every span of one batch and of one
@@ -547,6 +570,8 @@ class CompiledPipeline:
         METRICS.inc("stage_host_suffix_seconds", 0.0)
         METRICS.inc("stage_host_tail_seconds", 0.0)
         METRICS.inc("worker_device_tail_total", 0)
+        if mesh is not None:
+            METRICS.inc("stage_mesh_upload_seconds", 0.0)
 
     def _badwords_host_step(self, idx: int):
         """The real host C4BadWordsFilter for device step ``idx`` — runs only
@@ -724,6 +749,7 @@ class CompiledPipeline:
             # Raw traceable fn (scan_dispatch_counts traces it under
             # jax.eval_shape to count dispatches without compiling).
             return fn
+        kwargs = {}
         if self.mesh is not None:
             from jax.sharding import NamedSharding, PartitionSpec
 
@@ -734,24 +760,22 @@ class CompiledPipeline:
             # replicated layout, and the multi-host path reads each process's
             # addressable rows as *its* documents' stats — replication would
             # silently hand every host process-0's rows.
-            out_sharding = NamedSharding(self.mesh, PartitionSpec(DATA_AXIS))
-            return jax.jit(
-                fn,
+            kwargs.update(
                 in_shardings=(
                     batch_sharding(self.mesh, 2),
                     batch_sharding(self.mesh, 1),
                 ),
-                out_shardings=out_sharding,
+                out_shardings=NamedSharding(self.mesh, PartitionSpec(DATA_AXIS)),
             )
-        if jax.default_backend() == "tpu":
+        if self.one_controller and jax.default_backend() == "tpu":
             # Each dispatch uploads fresh numpy arrays, so the input buffers
             # are never reused host-side: donating them lets XLA alias the
             # [B, L] codepoint upload into scratch instead of holding both
             # live — with a K-deep in-flight window the biggest buffer would
             # otherwise exist K+1 times.  CPU stays undonated (XLA:CPU often
             # can't use the donation and warns per call).
-            return jax.jit(fn, donate_argnums=(0, 1))
-        return jax.jit(fn)
+            kwargs["donate_argnums"] = (0, 1)
+        return jax.jit(fn, **kwargs)
 
     def _fn_for(
         self, length: int, phase: int = 0, rows: Optional[int] = None
@@ -793,23 +817,25 @@ class CompiledPipeline:
         return dict(counts)
 
     @staticmethod
-    def _split_rows(full: int) -> int:
+    def _split_rows(full: int, chips: int = 1) -> int:
         """Row count the degradation ladder's split rung packs each half to:
-        half the batch, rounded UP to the 8-row sublane tile so the split
-        program keeps the (fused) Pallas kernels — ``pallas_scan_ok`` /
-        ``fused_scan_ok`` require rows % 8 == 0, and pack_documents already
-        pads rows beyond the doc count."""
+        half the batch, rounded UP to the 8-row sublane tile on every chip
+        (a multiple of ``chips`` × 8) so the split program keeps the (fused)
+        Pallas kernels — ``pallas_scan_ok`` / ``fused_scan_ok`` require
+        rows % 8 == 0 on a chip, and pack_documents already pads rows beyond
+        the doc count."""
         from .pallas_sort import ROWS
 
+        tile = ROWS * chips
         half = (full + 1) // 2
-        return min(full, ((half + ROWS - 1) // ROWS) * ROWS)
+        return min(full, ((half + tile - 1) // tile) * tile)
 
     def _warm_half_rows(self, length: int) -> int:
         """The half-row count warmup installs for bucket ``length`` (the
         split rung's), or the bucket's full rows where it installs none."""
         full = self.geometry.batch_for(length)
-        if self._split_retry and self.mesh is None:
-            return self._split_rows(full)
+        if self._split_retry and self.one_controller:
+            return self._split_rows(full, self.chips)
         return full
 
     def _warmup_jobs(self, include_split_rows: bool = True):
@@ -857,9 +883,9 @@ class CompiledPipeline:
         bypasses both directions; pass ``aot_cache`` to use a specific
         store (bench A/B, tests).
 
-        **Compile pool.**  Tracing is Python (GIL-bound) and happens
-        serially up front; XLA compilation releases the GIL, so N
-        in-flight compiles cost ~the slowest one instead of the sum.
+        **Pool.**  Store loads and XLA compilation release the GIL, so
+        both run on a thread pool; tracing is Python (GIL-bound) and runs
+        serially between them.
 
         On accelerator backends each pool thread also fires ONE throwaway
         execution of its program (zero-filled batch): the first dispatch
@@ -885,11 +911,10 @@ class CompiledPipeline:
 
         stats = WarmupStats()
         t0 = _time.perf_counter()
-        warm_dispatch = self.mesh is None and jax.default_backend() != "cpu"
+        warm_dispatch = self.one_controller and jax.default_backend() != "cpu"
         wire = jnp.uint16 if self.wire_u16 else jnp.int32
         wire_name = "uint16" if self.wire_u16 else "int32"
         backend = jax.default_backend()
-        n_devices = self.mesh.devices.size if self.mesh is not None else 1
 
         cache = aot_cache if aot_cache is not None else AOTExecutableCache()
         try:
@@ -908,23 +933,38 @@ class CompiledPipeline:
                 phase=phase,
                 rows=rows,
                 wire=wire_name,
-                n_devices=n_devices,
+                n_devices=self.chips,
                 mesh=self.mesh is not None,
             )
 
-        # Serial front half: AOT-cache loads, then traces for the misses.
+        jobs = [
+            job for job in self._warmup_jobs(include_split_rows)
+            if not (job[0] in self._jitted
+                    and not hasattr(self._jitted[job[0]], "lower"))
+        ]  # less the already installed executables
+        aot_keys = [
+            cache_key(length, phase, rows) if cache is not None else None
+            for _, length, phase, rows in jobs
+        ]
+
+        def timed_load(aot_key):
+            t = _time.perf_counter()
+            return cache.load(aot_key), _time.perf_counter() - t
+
+        # AOT-cache loads on the pool (deserializing releases the GIL), then
+        # serial traces for the misses.
+        loads = [(None, 0.0)] * len(jobs)
+        if cache is not None and jobs:
+            with ThreadPoolExecutor(max_workers=max_workers) as pool:
+                loads = list(pool.map(timed_load, aot_keys))
         to_compile = []  # (key, length, rows, lowered, aot_key)
         loaded = []  # (key, length, rows, compiled) — warm-dispatch only
-        for key, length, phase, rows in self._warmup_jobs(include_split_rows):
-            if key in self._jitted and not hasattr(self._jitted[key], "lower"):
-                continue  # already an installed executable
+        for (key, length, phase, rows), aot_key, (compiled, load_s) in zip(
+            jobs, aot_keys, loads
+        ):
             stats.programs += 1
-            aot_key = None
             if cache is not None:
-                aot_key = cache_key(length, phase, rows)
-                t = _time.perf_counter()
-                compiled = cache.load(aot_key)
-                stats.cache_load_s += _time.perf_counter() - t
+                stats.cache_load_s += load_s
                 if compiled is not None:
                     stats.cache_hits += 1
                     self._jitted[key] = compiled
@@ -960,8 +1000,14 @@ class CompiledPipeline:
 
         def dispatch_zero(compiled, length, rows):
             wire_np = _np.uint16 if self.wire_u16 else _np.int32
-            z = jnp.asarray(_np.zeros((rows, length), dtype=wire_np))
-            zl = jnp.asarray(_np.zeros((rows,), dtype=_np.int32))
+            z = _np.zeros((rows, length), dtype=wire_np)
+            zl = _np.zeros((rows,), dtype=_np.int32)
+            if self.mesh is not None:
+                from ..parallel.mesh import shard_batch
+
+                z, zl = shard_batch(self.mesh, z, zl)
+            else:
+                z, zl = jnp.asarray(z), jnp.asarray(zl)
             jax.block_until_ready(compiled(z, zl))
 
         def compile_one(item):
@@ -1565,27 +1611,30 @@ class CompiledPipeline:
         with TRACER.span(
             "device_dispatch",
             {"batch": batch.seq, "bucket": batch.max_len,
-             "rows": batch.batch_size, "phase": phase},
-        ):
+             "rows": batch.batch_size, "phase": phase, "chips": self.chips},
+        ) as sp:
             fn = self._fn_for(batch.max_len, phase, rows=batch.batch_size)
+            cps, lengths = batch.cps, batch.lengths
+            if self.wire_u16:
+                # Astral rows were routed to the host oracle upstream
+                # (process_chunk); a slip here would truncate silently,
+                # so guard with one cheap vectorized check.
+                if int(cps.max(initial=0)) >= 0x10000:
+                    raise RuntimeError(
+                        "astral codepoint reached the uint16 wire — "
+                        "routing invariant broken"
+                    )
+                cps = cps.astype(np.uint16)
+            if sp.live:
+                sp.add_args({"bytes": int(cps.nbytes + lengths.nbytes)})
             if self.mesh is not None:
                 from ..parallel.mesh import shard_batch
 
-                cps, lengths = shard_batch(
-                    self.mesh, batch.cps, batch.lengths
+                t0 = _time_mod.perf_counter()
+                cps, lengths = shard_batch(self.mesh, cps, lengths)
+                METRICS.inc(
+                    "stage_mesh_upload_seconds", _time_mod.perf_counter() - t0
                 )
-            else:
-                cps, lengths = batch.cps, batch.lengths
-                if self.wire_u16:
-                    # Astral rows were routed to the host oracle upstream
-                    # (process_chunk); a slip here would truncate silently,
-                    # so guard with one cheap vectorized check.
-                    if int(cps.max(initial=0)) >= 0x10000:
-                        raise RuntimeError(
-                            "astral codepoint reached the uint16 wire — "
-                            "routing invariant broken"
-                        )
-                    cps = cps.astype(np.uint16)
             return fn(cps, lengths)
 
     def dispatch_lockstep(
@@ -1759,7 +1808,7 @@ class CompiledPipeline:
         fell_to_host = False
         outcomes: List[ProcessingOutcome] = []
         survivors: List[TextDocument] = []
-        if self._split_retry and self.mesh is None and len(batch.docs) > 1:
+        if self._split_retry and self.one_controller and len(batch.docs) > 1:
             # Split rung.  Both halves pack to the bucket's half-row count,
             # the program warmup installed for this rung — also when the
             # faulted batch is itself a half-row tail group, so no program
@@ -1771,7 +1820,7 @@ class CompiledPipeline:
             if EVENTS.enabled:
                 EVENTS.emit("ladder_split", batch=batch.max_len,
                             depth=len(batch.docs), phase=phase)
-            sub_rows = self._split_rows(self.geometry.batch_for(batch.max_len))
+            sub_rows = self._warm_half_rows(batch.max_len)
             mid = (len(batch.docs) + 1) // 2
             for part in (batch.docs[:mid], batch.docs[mid:]):
                 if not part:
@@ -2121,7 +2170,7 @@ class CompiledPipeline:
         """
         no_overlap = os.environ.get("TEXTBLAST_NO_OVERLAP") == "1"
         overlapped = (
-            self._overlap.enabled and not no_overlap and self.mesh is None
+            self._overlap.enabled and not no_overlap and self.one_controller
         )
         depth = max(1, self._overlap.pipeline_depth) if overlapped else 1
         chunk_id = self._chunks
@@ -2199,14 +2248,14 @@ class CompiledPipeline:
         # expensive kernels and the (bit-exact) host oracle wins below
         # ~half a batch.  Accelerators take the fill rule instead and keep
         # the count thresholds only to count what the fill rule moved to
-        # the device.  Mesh runs keep every doc on device (shard
+        # the device.  Multi-host meshes keep every doc on device (shard
         # accounting), and TEXTBLAST_HOST_TAILS=off pins tails to the
         # device too (the parity suites use it so device kernels decide
         # every doc).
         host_tail_max = 0
         half_rows = None
         min_fill = HOST_TAIL_FILL_FIRST if phase == 0 else HOST_TAIL_FILL
-        if self.mesh is None and os.environ.get("TEXTBLAST_HOST_TAILS") != "off":
+        if self.one_controller and os.environ.get("TEXTBLAST_HOST_TAILS") != "off":
             # Per-bucket: the cutoff tracks each bucket's own row budget
             # (with a uniform geometry this is the historical scalar).
             div = 16 if phase == 0 else 2
@@ -2465,7 +2514,7 @@ def process_documents_device(
     # the partial batches each phase flushes at its end.
     from itertools import islice
 
-    chunk_size = max(4 * pipeline.batch_size, 4096)
+    chunk_size = max(4 * pipeline.batch_size, CHUNK_DOCS * pipeline.chips)
     stream = doc_stream()
     while True:
         # The number process_chunk gives this chunk's phase spans.

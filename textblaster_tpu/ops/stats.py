@@ -245,6 +245,28 @@ def _scatter(values, idx, active, m, fill=0, op="set"):
 # — every gated call site satisfies it by construction and says how.
 
 
+def _stack_rows(xs, mesh=None):
+    """Same-shaped ``[B, ...]`` arrays as the rows of one ``[k*B, ...]``
+    array; rows are independent in every consumer.  One device takes them
+    one array after another.  Under a ``mesh`` they interleave, row ``r``
+    of ``xs[i]`` at ``r*k + i``, so each chip's shard of the stack holds
+    exactly its own rows: a concatenation along the sharded batch axis
+    moves rows between chips (all-to-alls going in, collective-permutes
+    coming out of every stacked sort)."""
+    if mesh is None:
+        return xs[0] if len(xs) == 1 else jnp.concatenate(xs, axis=0)
+    return jnp.stack(xs, axis=1).reshape((-1,) + xs[0].shape[1:])
+
+
+def _unstack_rows(x, k, mesh=None):
+    """The ``k`` arrays :func:`_stack_rows` stacked into ``x``."""
+    if mesh is None:
+        b = x.shape[0] // k
+        return [x[i * b : (i + 1) * b] for i in range(k)]
+    x = x.reshape((-1, k) + x.shape[1:])
+    return [x[:, i] for i in range(k)]
+
+
 def _rank_positions_many(actives, m, mesh=None):
     """For each ``[B, L]`` bool mask in ``actives``: positions of its 1st,
     2nd, ... active element per row, as ``(pos [B, m] int32, real [B, m]
@@ -253,7 +275,7 @@ def _rank_positions_many(actives, m, mesh=None):
     b, length = actives[0].shape
     pos = jnp.broadcast_to(jnp.arange(length, dtype=jnp.int32)[None, :], (b, length))
     keys = [jnp.where(a, pos, _I32_MAX) for a in actives]
-    key = keys[0] if len(keys) == 1 else jnp.concatenate(keys, axis=0)
+    key = _stack_rows(keys, mesh)
     # Pad the row length to a power of two for the Pallas network; padding
     # carries the invalid key and a safe gather index.
     padded = 1 << (length - 1).bit_length()
@@ -264,12 +286,13 @@ def _rank_positions_many(actives, m, mesh=None):
         extra = m - s_key.shape[1]
         s_key = jnp.pad(s_key, ((0, 0), (0, extra)), constant_values=_I32_MAX)
         s_pos = jnp.pad(s_pos, ((0, 0), (0, extra)))
-    outs = []
-    for i in range(len(actives)):
-        blk_key = s_key[i * b : (i + 1) * b, :m]
-        blk_pos = s_pos[i * b : (i + 1) * b, :m]
-        outs.append((blk_pos, blk_key != _I32_MAX))
-    return outs
+    return [
+        (blk_pos[:, :m], blk_key[:, :m] != _I32_MAX)
+        for blk_key, blk_pos in zip(
+            _unstack_rows(s_key, len(actives), mesh),
+            _unstack_rows(s_pos, len(actives), mesh),
+        )
+    ]
 
 
 def _gather_table(values, pos, real, fill=0):
@@ -707,25 +730,18 @@ def _sort_runs_many(jobs, mesh=None):
     for h, _, v in jobs:
         keys.append(jnp.where(v, jnp.minimum(h, _I32_MAX - 1), _I32_MAX))
         n_valid.append(jnp.sum(v, axis=1).astype(jnp.int32))
-    if len(jobs) == 1:
-        s_key, s_payload = sort2(keys[0], jobs[0][1], mesh=mesh)
-    else:
-        s_key, s_payload = sort2(
-            jnp.concatenate(keys, axis=0),
-            jnp.concatenate([j[1] for j in jobs], axis=0),
-            mesh=mesh,
-        )
+    s_key, s_payload = sort2(
+        _stack_rows(keys, mesh), _stack_rows([j[1] for j in jobs], mesh), mesh=mesh
+    )
     iota = jnp.arange(m, dtype=jnp.int32)[None, :]
-    outs = []
-    for i, nv in enumerate(n_valid):
-        outs.append(
-            (
-                iota < nv[:, None],
-                s_key[i * b : (i + 1) * b],
-                s_payload[i * b : (i + 1) * b],
-            )
+    return [
+        (iota < nv[:, None], k, v)
+        for nv, k, v in zip(
+            n_valid,
+            _unstack_rows(s_key, len(jobs), mesh),
+            _unstack_rows(s_payload, len(jobs), mesh),
         )
-    return outs
+    ]
 
 
 def _dup_counts_sorted(sorted_triple) -> Tuple[jax.Array, jax.Array]:
@@ -1438,7 +1454,7 @@ def gopher_rep_stats(
                         first_in_run=rpre[i] if rpre else None,
                     )
                     walk.append((n, rid_n, grams[n][2], grams[n][1]))
-            res = _find_all_dup_bytes_batched(walk)
+            res = _find_all_dup_bytes_batched(walk, mesh)
             return tuple(res[f"dup_{n}"] for n in dup_sizes)
 
         def _dup_zero(operand):
@@ -1490,7 +1506,7 @@ def _dup_run_info_sorted(
     return win_valid & (first_occ < idx), first_occ
 
 
-def _find_all_dup_bytes_batched(jobs) -> Dict[str, jax.Array]:
+def _find_all_dup_bytes_batched(jobs, mesh=None) -> Dict[str, jax.Array]:
     """find_all_duplicate, EXACT: the oracle's greedy scan with its
     visited-set dynamics (text.rs:241-259) — ``seen`` holds only windows the
     scan actually visited, a hit counts the window's bytes and jumps ``n``
@@ -1511,12 +1527,12 @@ def _find_all_dup_bytes_batched(jobs) -> Dict[str, jax.Array]:
     if not jobs:
         return out
     b, m = jobs[0][1].shape
-    n_vec = jnp.concatenate(
-        [jnp.full((b,), n, jnp.int32) for n, _, _, _ in jobs]
+    n_vec = _stack_rows(
+        [jnp.full((b,), n, jnp.int32) for n, _, _, _ in jobs], mesh
     )  # [kB]
-    rid = jnp.concatenate([j[1] for j in jobs], axis=0)  # [kB, m]
-    val = jnp.concatenate([j[2] for j in jobs], axis=0)
-    gbs = jnp.concatenate([j[3] for j in jobs], axis=0)
+    rid = _stack_rows([j[1] for j in jobs], mesh)  # [kB, m]
+    val = _stack_rows([j[2] for j in jobs], mesh)
+    gbs = _stack_rows([j[3] for j in jobs], mesh)
     rows = jnp.arange(rid.shape[0], dtype=jnp.int32)
     onehot_visited = use_sort_tables()
     lane = jnp.arange(m, dtype=jnp.int32)[None, :]
@@ -1548,8 +1564,8 @@ def _find_all_dup_bytes_batched(jobs) -> Dict[str, jax.Array]:
         jnp.zeros(rid.shape[0], jnp.int32),
     )
     (_, _, acc), _ = jax.lax.scan(step, init, (rid.T, gbs.T, val.T))
-    for i, (n, _, _, _) in enumerate(jobs):
-        out[f"dup_{n}"] = acc[i * b : (i + 1) * b]
+    for (n, _, _, _), a in zip(jobs, _unstack_rows(acc, len(jobs), mesh)):
+        out[f"dup_{n}"] = a
     return out
 
 

@@ -261,8 +261,8 @@ def global_data_mesh() -> "jax.sharding.Mesh":
     Exception: on a multi-process **CPU** job the mesh covers only this
     process's local devices.  XLA:CPU refuses to execute a computation that
     spans processes (INVALID_ARGUMENT "Multiprocess computations aren't
-    implemented on the CPU backend"), and the compiled pipeline programs are
-    collective-free, so per-host execution under the negotiated lockstep
+    implemented on the CPU backend"), and the compiled pipeline programs move
+    no row between devices, so per-host execution under the negotiated lockstep
     schedule — whose exchanges ride :func:`host_allgather` — is semantically
     identical: each host's "global" batch is simply its own stripe.  On
     accelerator backends the mesh spans the whole job as before and XLA
@@ -1328,7 +1328,9 @@ def run_local_shard(
     # (global_data_mesh) where each host runs its own full-width program.
     n_proc = len({d.process_index for d in mesh.devices.flat})
     if pipeline is None:
-        pipeline = CompiledPipeline(config, buckets=buckets, mesh=mesh)
+        pipeline = CompiledPipeline(
+            config, buckets=buckets, mesh=mesh, multihost=True
+        )
         # Warm before the first lockstep round: every host compiles (or AOT-
         # cache-loads) the identical program set up front, so no host hits a
         # first-dispatch compile stall mid-round while its peers wait at the
@@ -1885,7 +1887,7 @@ def run_local_shard(
                     piggybacked round counts, which include still-pending
                     tail survivors.  Per-host launch counts may differ
                     (chunk confirmation progress is local); that is sound
-                    for the collective-free programs this build compiles,
+                    for programs that move no row between devices,
                     the same residual-risk stance resilience/negotiated.py
                     documents for fetches.  Voided entries (``out=None``)
                     re-launch here on the barrier's next pass, after the
@@ -2449,7 +2451,7 @@ def run_multihost(
     after a peer death and would undercut survival from below.  Each
     process then runs its full-width local-device mesh (exactly the
     multi-process CPU fallback :func:`global_data_mesh` already takes; the
-    compiled programs are collective-free either way — on accelerator pods
+    compiled programs move no row between devices either way — on accelerator pods
     this trades the cross-host XLA mesh for survivability).  Under
     ``survive_peer_loss`` a peer death mid-exchange triggers gang
     reformation instead of gang death: survivors fence the dead rank's
@@ -2569,7 +2571,7 @@ def run_multihost(
         # The gang is coupled only through the membership dir on the shared
         # filesystem; jax.process_count() stays 1, so global_data_mesh()
         # hands every process its full-width local mesh — exactly the
-        # multi-process CPU fallback, with collective-free programs.
+        # multi-process CPU fallback, each host's collectives its own.
         import shutil
 
         if force and os.path.isdir(membership_root):
@@ -2764,7 +2766,7 @@ def run_multihost(
 
         pipeline = CompiledPipeline(
             config, buckets=tuple(sorted(buckets)), batch_size=device_batch,
-            mesh=mesh, geometry=geometry,
+            mesh=mesh, geometry=geometry, multihost=True,
         )
         from ..ops.pipeline import maybe_warmup
 
@@ -3408,7 +3410,7 @@ def _run_elastic(
     mesh = data_mesh() if len(jax.devices()) > 1 else None
     pipeline = CompiledPipeline(
         config, buckets=tuple(sorted(buckets)), batch_size=device_batch,
-        mesh=mesh,
+        mesh=mesh, multihost=True,
     )
     from ..ops.pipeline import maybe_warmup
 

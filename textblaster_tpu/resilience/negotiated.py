@@ -39,11 +39,12 @@ Residual risk, documented rather than hidden: if a compiled program carries
 cross-host collectives (XLA's choice) and one host's *launch* fails while a
 peer's succeeds, the peer's fetch can block on a collective that never
 completes — the verdict negotiation only runs after the fetch returns or
-raises.  The data-parallel filter programs this build compiles are
-collective-free (see parallel/mesh.py), so the fetch completes locally and
-the negotiation always convenes; on topologies where XLA inserts
-collectives the heartbeat teardown remains the backstop, exactly as for
-hard process death.
+raises.  The data-parallel filter programs this build compiles move no
+row between devices; their only collectives are the scalar ORs of three
+batch-wide gates (see parallel/mesh.py).  On a per-host mesh those stay on
+the host, so the fetch completes locally and the negotiation always
+convenes; on a mesh that spans processes the heartbeat teardown remains the
+backstop, exactly as for hard process death.
 """
 
 from __future__ import annotations

@@ -534,6 +534,12 @@ _SPECS: Dict[str, Tuple[str, str]] = {
         "over-length documents, kernel-overflow reruns and the degradation "
         "ladder's host rung; part of stage_post_seconds",
     ),
+    # Part of stage_dispatch_seconds, so not in STAGE_COUNTERS either.
+    "stage_mesh_upload_seconds": (
+        "counter",
+        "Wall seconds placing each batch on the chips of a data mesh, "
+        "sharded by rows (shard_batch); part of stage_dispatch_seconds",
+    ),
     "stage_write_seconds": (
         "counter",
         "Wall seconds writing outcome batches to Parquet",
