@@ -81,3 +81,50 @@ def test_filtered_doc_metadata_roundtrip(tmp_path):
 
     md = json.loads(pq.read_table(excl).column("metadata")[0].as_py())
     assert md["gopher_quality_filter_status"] == "filtered"
+
+
+def test_execute_processing_batch_matches_per_document_calls():
+    from textblaster_tpu.errors import DocumentFiltered, UnexpectedError
+    from textblaster_tpu.executor import PipelineExecutor, ProcessingStep
+    from textblaster_tpu.orchestration import (
+        execute_processing_batch,
+        execute_processing_pipeline,
+    )
+    from textblaster_tpu.utils.metrics import FILTER_DROP_PREFIX, METRICS
+
+    class Judge(ProcessingStep):
+        name = "Judge"
+
+        def process(self, d):
+            if d.id == "doc-2":
+                raise DocumentFiltered(d, "too short")
+            if d.id == "doc-3":
+                raise UnexpectedError("broken")
+            d.metadata["judged"] = "yes"
+            return d
+
+    names = [
+        "worker_tasks_processed_total", "worker_tasks_filtered_total",
+        "worker_tasks_failed_total", "worker_active_tasks",
+        FILTER_DROP_PREFIX + "Judge",
+        "worker_task_processing_duration_seconds::count",
+    ]
+
+    def run(fn):
+        before = METRICS.all_values()
+        outcomes = fn(PipelineExecutor([Judge()]), [doc(i) for i in range(1, 6)])
+        after = METRICS.all_values()
+        deltas = {n: after.get(n, 0.0) - before.get(n, 0.0) for n in names}
+        return [o.to_dict() for o in outcomes], deltas
+
+    single, single_deltas = run(
+        lambda ex, docs: [execute_processing_pipeline(ex, d) for d in docs]
+    )
+    batch, batch_deltas = run(execute_processing_batch)
+    assert batch == single
+    assert batch_deltas == single_deltas
+    assert single_deltas["worker_tasks_processed_total"] == 3
+    assert single_deltas["worker_tasks_filtered_total"] == 1
+    assert single_deltas["worker_tasks_failed_total"] == 1
+    assert single_deltas["worker_active_tasks"] == 0
+    assert single_deltas["worker_task_processing_duration_seconds::count"] == 5

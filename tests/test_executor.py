@@ -101,3 +101,79 @@ def test_batch_mixed_results_input_order():
     assert isinstance(results[0], TextDocument) and results[0].id == "good1"
     assert isinstance(results[1], StepError)
     assert isinstance(results[2], TextDocument) and results[2].id == "good2"
+
+
+class FilterIdStep(ProcessingStep):
+    """Filters only a specific doc id."""
+
+    name = "FilterIdStep"
+
+    def __init__(self, bad_id):
+        self.bad_id = bad_id
+
+    def process(self, document):
+        if document.id == self.bad_id:
+            raise DocumentFiltered(document, "filtered mid-batch")
+        document.metadata[self.name] = "passed"
+        return document
+
+
+class CountingBatchStep(MockStep):
+    """Records the documents of each ``process_batch`` call."""
+
+    def __init__(self, name, **kw):
+        super().__init__(name, **kw)
+        self.batches = []
+
+    def process_batch(self, documents):
+        self.batches.append([d.id for d in documents])
+        return super().process_batch(documents)
+
+
+def _pipeline():
+    def stamp(d):
+        d.metadata["stamped"] = d.id
+        return d
+
+    return [
+        CountingBatchStep("first", fn=stamp),
+        FilterIdStep(bad_id="filtered"),
+        SmartErrorStep(bad_id="broken"),
+        CountingBatchStep("last"),
+    ]
+
+
+def _describe(result):
+    if isinstance(result, StepError):
+        inner = result.filtered()
+        return ("error", result.step_name, str(result),
+                inner.reason if inner else None, type(result.__cause__))
+    return ("doc", result.id, dict(result.metadata))
+
+
+def test_step_major_batch_equals_run_single_per_document():
+    ids = ["a", "filtered", "b", "broken", "c"]
+    single = []
+    for i in ids:
+        try:
+            single.append(PipelineExecutor(_pipeline()).run_single(doc(i)))
+        except StepError as e:
+            single.append(e)
+    steps = _pipeline()
+    batch = PipelineExecutor(steps).run_batch([doc(i) for i in ids])
+    assert [_describe(r) for r in batch] == [_describe(r) for r in single]
+    assert [_describe(r)[1] for r in batch] == [
+        "a", "FilterIdStep", "b", "SmartErrorStep", "c",
+    ]
+    # One call per step, over the documents still alive.
+    assert steps[0].batches == [ids]
+    assert steps[-1].batches == [["a", "b", "c"]]
+
+
+def test_batch_short_circuits_a_failing_document():
+    never = CountingBatchStep("never")
+    ex = PipelineExecutor([MockStep("boom", fail=True), never])
+    results = ex.run_batch([doc("x"), doc("y")])
+    assert all(isinstance(r, StepError) and r.step_name == "boom" for r in results)
+    assert isinstance(results[0].source, UnexpectedError)
+    assert never.batches == [] and never.calls == 0

@@ -428,6 +428,10 @@ pipeline:
         a = assemble[ev[3]["batch"]]
         assert a[2] <= ev[1]
     assert sum(ev[3]["docs"] for ev in suffix) == len(kept)
+    # TokenCounter's batched encode took every block of two or more.
+    for ev in suffix:
+        assert ev[3]["batched"] == (ev[3]["docs"] if ev[3]["docs"] >= 2 else 0)
+    assert any(ev[3]["batched"] for ev in suffix)
     assert not [ev for ln in lines if ln is not line for ev in ln
                 if ev[0] == "tb.host_suffix"]
 

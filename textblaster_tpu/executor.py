@@ -15,7 +15,10 @@ documents the device path cannot handle.
 
 ``run_batch`` returns results in *input order* — deliberately not inheriting
 the reference's completion-order quirk (executor.rs:60-70; SURVEY.md §7
-behavioral quirk #12).
+behavioral quirk #12).  It runs step-major: each step's ``process_batch``
+takes every document still alive, so a step with a batched implementation
+(TokenCounter's batched encode) makes one call per batch.  Steps are
+per-document, so the results equal ``run_single`` on each document.
 """
 
 from __future__ import annotations
@@ -38,9 +41,35 @@ class ProcessingStep:
     """
 
     name: str = "ProcessingStep"
+    #: Documents this step has handled through its own batched
+    #: ``process_batch`` rather than the default loop over ``process``.
+    batched_docs: int = 0
 
     def process(self, document: TextDocument) -> TextDocument:
         raise NotImplementedError
+
+    def process_batch(
+        self, documents: Sequence[TextDocument]
+    ) -> List[Union[TextDocument, Exception]]:
+        """Each document's :meth:`process` result, or the exception it
+        raised, in input order.  A step overrides this only with a batch
+        call whose per-document results equal :meth:`process`'s."""
+        out: List[Union[TextDocument, Exception]] = []
+        for doc in documents:
+            try:
+                out.append(self.process(doc))
+            except Exception as e:
+                out.append(e)
+        return out
+
+
+def _step_error(step_name: str, error: Exception) -> StepError:
+    """``error`` raised by step ``step_name``, wrapped as ``run_single``
+    raises it: a pipeline error as it is, any other as Unexpected."""
+    source = error if isinstance(error, PipelineError) else UnexpectedError(str(error))
+    wrapped = StepError(step_name, source)
+    wrapped.__cause__ = error
+    return wrapped
 
 
 class PipelineExecutor:
@@ -59,20 +88,32 @@ class PipelineExecutor:
         for step in self.steps:
             try:
                 current = step.process(current)
-            except PipelineError as e:
-                raise StepError(step.name, e) from e
             except Exception as e:  # non-pipeline bugs surface as Unexpected
-                raise StepError(step.name, UnexpectedError(str(e))) from e
+                raise _step_error(step.name, e) from e
         return current
 
     def run_batch(
         self, documents: Iterable[TextDocument]
     ) -> List[Union[TextDocument, StepError]]:
-        """Run many documents; per-document results in input order."""
-        out: List[Union[TextDocument, StepError]] = []
-        for doc in documents:
-            try:
-                out.append(self.run_single(doc))
-            except StepError as e:
-                out.append(e)
+        """Run many documents step by step; per-document results in input
+        order.  A document leaves the batch at its first failing step, as
+        ``run_single`` short-circuits."""
+        out: List[Union[TextDocument, StepError]] = list(documents)
+        alive = list(range(len(out)))
+        for step in self.steps:
+            if not alive:
+                break
+            results = step.process_batch([out[i] for i in alive])
+            still = []
+            for i, result in zip(alive, results, strict=True):
+                if isinstance(result, Exception):
+                    out[i] = _step_error(step.name, result)
+                else:
+                    out[i] = result
+                    still.append(i)
+            alive = still
         return out
+
+    def batched_docs(self) -> int:
+        """Documents the steps have handled through their own batch calls."""
+        return sum(step.batched_docs for step in self.steps)

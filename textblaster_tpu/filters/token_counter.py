@@ -3,7 +3,8 @@
 Re-implementation of ``TokenCounter``
 (``/root/reference/src/pipeline/token/token_counter.rs:8-43``): loads a
 HuggingFace tokenizer at build time, encodes content *with* special tokens,
-and stamps ``metadata["token_count"]``.
+and stamps ``metadata["token_count"]``.  A batch of documents is encoded
+in one ``encode_batch`` call (:meth:`TokenCounter.process_batch`).
 
 Loading resolution order (the reference only supports hub fetch,
 token_counter.rs:14; this build adds offline paths first since TPU pods are
@@ -28,6 +29,7 @@ construction, matching the reference's build-time failure surface
 from __future__ import annotations
 
 import os
+from typing import List, Sequence, Union
 
 from ..data_model import TextDocument
 from ..errors import UnexpectedError
@@ -120,12 +122,35 @@ class TokenCounter(ProcessingStep):
             if self._bpe is not None:
                 count = self._bpe.count(document.content)
             else:
-                encoding = self._tokenizer.encode(
-                    document.content, add_special_tokens=True
+                count = len(
+                    self._tokenizer.encode(document.content, add_special_tokens=True)
                 )
-                count = len(encoding.tokens)
         except Exception as e:
             raise UnexpectedError(str(e)) from e
+        return self._stamp(document, count)
+
+    def process_batch(
+        self, documents: Sequence[TextDocument]
+    ) -> List[Union[TextDocument, Exception]]:
+        """One ``encode_batch`` over the documents, run in the tokenizer's
+        native core across the host's cores.  Padding would count every
+        encoding at the longest one's length, so a padded tokenizer, the
+        native BPE counter and a single document take the loop over
+        :meth:`process`; so does a batch whose call raises, which gives each
+        failing document its own error."""
+        tok = self._tokenizer
+        if tok is None or tok.padding is not None or len(documents) < 2:
+            return super().process_batch(documents)
+        try:
+            encodings = tok.encode_batch(
+                [d.content for d in documents], add_special_tokens=True
+            )
+        except Exception:
+            return super().process_batch(documents)
+        self.batched_docs += len(documents)
+        return [self._stamp(d, len(e)) for d, e in zip(documents, encodings, strict=True)]
+
+    def _stamp(self, document: TextDocument, count: int) -> TextDocument:
         document.metadata["token_count"] = str(count)
         if self._standin:
             # Not a reference metadata key: deliberately extra so downstream

@@ -48,7 +48,7 @@ from ..filters.common import fmt2, fmt4, rust_bool, rust_float, rust_lines
 from ..filters.gopher_quality import DEFAULT_STOP_WORDS
 from ..filters.fineweb_quality import DEFAULT_STOP_CHARS
 from ..models.langid import ISO_TO_NAME, LANGUAGES, NAME_TO_ISO, LangIdModel
-from ..orchestration import execute_processing_pipeline
+from ..orchestration import execute_processing_batch, execute_processing_pipeline
 from ..pipeline_builder import build_pipeline_from_config
 from ..resilience.breaker import CircuitBreaker
 from ..resilience.faults import FAULTS
@@ -568,6 +568,7 @@ class CompiledPipeline:
         # The post stage's parts exist from the start, so a run with no
         # host tail reads 0 rather than nothing.
         METRICS.inc("stage_host_suffix_seconds", 0.0)
+        METRICS.inc("worker_host_suffix_batched_total", 0)
         METRICS.inc("stage_host_tail_seconds", 0.0)
         METRICS.inc("worker_device_tail_total", 0)
         if mesh is not None:
@@ -1775,6 +1776,26 @@ class CompiledPipeline:
         finally:
             METRICS.inc(counter, _time_mod.perf_counter() - t0)
 
+    def _host_suffix_block(
+        self, seq: int, docs: List[TextDocument]
+    ) -> List[ProcessingOutcome]:
+        """The host steps after the last phase over one batch's survivors,
+        step by step (``execute_processing_batch``), as the ``host_suffix``
+        span; ``batched`` counts the documents a step took in one batch
+        call."""
+        executor = self.host_suffix_executor
+        t0 = _time_mod.perf_counter()
+        try:
+            with TRACER.span("host_suffix", {"batch": seq, "docs": len(docs)}) as sp:
+                before = executor.batched_docs()
+                outcomes = execute_processing_batch(executor, docs)
+                batched = executor.batched_docs() - before
+                METRICS.inc("worker_host_suffix_batched_total", batched)
+                sp.add_args({"batched": batched})
+                return outcomes
+        finally:
+            METRICS.inc("stage_host_suffix_seconds", _time_mod.perf_counter() - t0)
+
     def _execute_packed(
         self, batch: PackedBatch, phase: int, inflight=None
     ) -> Tuple[List[ProcessingOutcome], List[TextDocument]]:
@@ -1952,25 +1973,23 @@ class CompiledPipeline:
                     outcome = ProcessingOutcome.success(doc)
                 outcomes.append(outcome)
         if overflow_rows:
-            self._fill_slots(
-                outcomes, overflow_rows, "host_tail",
-                {"kind": "overflow", "docs": len(overflow_rows)},
+            self._fill_slots(outcomes, overflow_rows, self._host_block(
+                "host_tail", {"kind": "overflow", "docs": len(overflow_rows)},
                 "stage_host_tail_seconds", self.host_executor,
-            )
+                [d for _, d in overflow_rows],
+            ))
         if suffix_rows:
-            self._fill_slots(
-                outcomes, suffix_rows, "host_suffix",
-                {"batch": batch.seq, "docs": len(suffix_rows)},
-                "stage_host_suffix_seconds", self.host_suffix_executor,
-            )
+            self._fill_slots(outcomes, suffix_rows, self._host_suffix_block(
+                batch.seq, [d for _, d in suffix_rows]
+            ))
         # A hard error has no outcome (reference quirk).
         return [o for o in outcomes if o is not None], survivors
 
-    def _fill_slots(self, outcomes, rows, span, args, counter, executor) -> None:
-        """Run the deferred ``(slot, doc)`` rows as one host block and put
-        each outcome in its slot."""
-        done = self._host_block(span, args, counter, executor, [d for _, d in rows])
-        for (slot, _), outcome in zip(rows, done):
+    @staticmethod
+    def _fill_slots(outcomes, rows, done) -> None:
+        """Put each outcome of the deferred ``(slot, doc)`` rows in its
+        slot."""
+        for (slot, _), outcome in zip(rows, done, strict=True):
             outcomes[slot] = outcome
 
     def phase_previewable(self, phase: int) -> bool:

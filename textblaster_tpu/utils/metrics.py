@@ -218,6 +218,12 @@ _SPECS: Dict[str, Tuple[str, str]] = {
         "sent to the device that the XLA:CPU count rule would have sent to "
         "the host oracle",
     ),
+    "worker_host_suffix_batched_total": (
+        "counter",
+        "Documents whose host steps after the last device phase ran through "
+        "a step's own batch call (TokenCounter's one encode_batch per "
+        "batch) rather than one call per document",
+    ),
     "worker_fold_hazard_rows_total": (
         "counter",
         "Bad-words rows containing an IGNORECASE fold-hazard codepoint, "
