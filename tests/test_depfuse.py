@@ -7,9 +7,10 @@ against the staged lax schedules for every group kind (affine, add, dfa,
 segmax, copy), forward and reverse passes, shift taps, and multi-block
 carries, over full-range int32 inputs at 128–1280 lanes.  Consumer layer:
 ``structure``/``gopher_rep_stats``/``gopher_quality_stats``/``c4_stage``/
-``sentence_counts`` with ``TEXTBLAST_DEPFUSE`` on vs off vs the host
-oracle must agree on kind/reason/content over the edge documents, and the
-per-(bucket, phase) dispatch counts are pinned as a regression gate.
+``sentence_counts`` with the chain kernels vs the staged lax path
+(``TEXTBLAST_PALLAS=off``) vs the host oracle must agree on
+kind/reason/content over the edge documents, and the per-(bucket, phase)
+dispatch counts are pinned as a regression gate.
 
 Exchange layer: ``NegotiatedGuard.negotiate_batch`` posts ONE allgather
 vector for a window's worth of verdicts — depth-1 wire traffic must stay
@@ -45,11 +46,8 @@ pytestmark = [pytest.mark.depfuse]
 
 @pytest.fixture
 def interp(monkeypatch):
-    """Force the interpret-mode kernel path; clear any disabling hatch."""
+    """Force the interpret-mode kernel path; clear the disabling hatch."""
     monkeypatch.delenv("TEXTBLAST_PALLAS", raising=False)
-    monkeypatch.delenv("TEXTBLAST_NO_PALLAS", raising=False)
-    monkeypatch.delenv("TEXTBLAST_FUSED", raising=False)
-    monkeypatch.delenv("TEXTBLAST_DEPFUSE", raising=False)
     monkeypatch.setenv("TEXTBLAST_PALLAS_INTERPRET", "1")
 
 
@@ -232,14 +230,12 @@ def test_chain_dfa_pass_feeds_counter(interp, length):
 
 
 def test_chain_gate_respects_hatch(interp, monkeypatch):
-    assert psc.depfuse_enabled()
     assert psc.chain_scan_ok(16, 512)
-    monkeypatch.setenv("TEXTBLAST_DEPFUSE", "off")
-    assert not psc.depfuse_enabled()
+    monkeypatch.setenv("TEXTBLAST_PALLAS", "off")
     assert not psc.chain_scan_ok(16, 512)
 
 
-# --- consumer parity: depfuse vs staged over edge docs -----------------------
+# --- consumer parity: chain kernels vs staged over edge docs -----------------
 
 
 def _arrays(d):
@@ -255,7 +251,7 @@ def test_gopher_rep_depfuse_vs_staged(interp, monkeypatch):
         on = gopher_rep_stats(st, (2, 3), (5, 6), 128, 256)
     assert set(counts) == {"fused", "pallas_sort"}, dict(counts)
     with monkeypatch.context() as m:
-        m.setenv("TEXTBLAST_DEPFUSE", "off")
+        m.setenv("TEXTBLAST_PALLAS", "off")
         st2 = structure(cps, lens, with_hashes=True)
         off = gopher_rep_stats(st2, (2, 3), (5, 6), 128, 256)
     assert set(on) == set(off)
@@ -266,13 +262,24 @@ def test_gopher_rep_depfuse_vs_staged(interp, monkeypatch):
 
 
 @pytest.mark.pallas
-def test_gopher_quality_depfuse_vs_staged(interp, monkeypatch):
+@pytest.mark.parametrize("staged", ["lax", "per_scan_kernels"])
+def test_gopher_quality_depfuse_vs_staged(interp, monkeypatch, staged):
+    """Chain kernel against the staged path: all lax (``TEXTBLAST_PALLAS=off``)
+    or with the per-scan kernels, as the chip runs it above the fused
+    kernels' lane ceiling."""
     cps, lens = _rows_from_texts(EDGE_TEXTS, 256)
     hashes = tuple(range(-5, 5))
-    on = gopher_quality_stats(structure(cps, lens), hashes)
+    with psc.count_scan_dispatches() as counts:
+        on = gopher_quality_stats(structure(cps, lens), hashes)
+    assert set(counts) == {"fused"}, dict(counts)
     with monkeypatch.context() as m:
-        m.setenv("TEXTBLAST_DEPFUSE", "off")
-        off = gopher_quality_stats(structure(cps, lens), hashes)
+        if staged == "lax":
+            m.setenv("TEXTBLAST_PALLAS", "off")
+        else:
+            m.setattr(psc, "_FUSED_MAX_LANES", 128)
+        with psc.count_scan_dispatches() as counts:
+            off = gopher_quality_stats(structure(cps, lens), hashes)
+    assert "fused" not in counts, dict(counts)
     assert set(on) == set(off)
     for k in on:
         np.testing.assert_array_equal(
@@ -310,7 +317,7 @@ def test_c4_and_sentences_depfuse_vs_staged(interp, monkeypatch,
 
     on = run()
     with monkeypatch.context() as m:
-        m.setenv("TEXTBLAST_DEPFUSE", "off")
+        m.setenv("TEXTBLAST_PALLAS", "off")
         off = run()
     assert set(on) == set(off)
     for k in on:
@@ -320,8 +327,8 @@ def test_c4_and_sentences_depfuse_vs_staged(interp, monkeypatch,
 @pytest.mark.pallas
 @pytest.mark.slow
 def test_full_pipeline_three_way_parity(interp, monkeypatch):
-    """Whole-pipeline decisions: depfuse chains vs staged
-    (TEXTBLAST_DEPFUSE=off) vs the pure-Python host oracle must agree on
+    """Whole-pipeline decisions: chain kernels vs staged lax
+    (TEXTBLAST_PALLAS=off) vs the pure-Python host oracle must agree on
     kind/reason/content over the edge docs."""
     from textblaster_tpu.config.pipeline import parse_pipeline_config
     from textblaster_tpu.data_model import TextDocument
@@ -374,7 +381,7 @@ pipeline:
         for o in process_documents_device(config, iter(docs()), device_batch=8)
     }
     with monkeypatch.context() as m:
-        m.setenv("TEXTBLAST_DEPFUSE", "off")
+        m.setenv("TEXTBLAST_PALLAS", "off")
         off = {
             o.document.id: o
             for o in process_documents_device(
@@ -419,12 +426,15 @@ pipeline:
 """
 
 # Pinned per-(bucket, phase) dispatch counts for _GATE_YAML with the
-# chains on.  A regression that splits a chain back into staged dispatches
+# kernels on.  A regression that splits a chain back into staged dispatches
 # (or silently drops a path out of chain_scan_ok) moves these numbers —
-# update them only with a parity-verified kernel change.
+# update them only with a parity-verified kernel change.  The sorts and the
+# phase-1 lax scans include the sort-built tables: the rank sorts of the
+# line, paragraph and word tables, the window un-sort, C4's two compactions
+# and its per-line segmented/latch scans.
 _GATE_EXPECT_ON = {
-    0: {"fused": 5, "pallas_sort": 3},
-    1: {"fused": 4, "lax_scan": 2, "pallas_scan": 1},
+    0: {"fused": 5, "pallas_sort": 7},
+    1: {"fused": 4, "lax_scan": 8, "pallas_scan": 1, "pallas_sort": 3},
 }
 
 
@@ -445,7 +455,7 @@ def test_dispatch_count_regression_gate(interp, monkeypatch):
             )
             tot_on += sum(on_c.values())
             with monkeypatch.context() as m:
-                m.setenv("TEXTBLAST_DEPFUSE", "off")
+                m.setenv("TEXTBLAST_PALLAS", "off")
                 off_c = pipeline.scan_dispatch_counts(length, phase)
             tot_off += sum(off_c.values())
         assert tot_on < tot_off, (length, tot_on, tot_off)

@@ -4,8 +4,10 @@ fused_scan``) and the shard_map'd mesh dispatch.
 Same contract as ``test_pallas_scan.py``: the exact kernel program runs
 under Pallas interpret mode on CPU, every op is int32 ALU with exact
 wraparound, so every comparison is bit-exact — fused kernel vs the staged
-lax path (``TEXTBLAST_FUSED=off``) vs the pure-Python host oracle, across
-every in-kernel block width, multi-block carries, and the edge documents.
+path the chip takes above the fused kernels' lane ceiling (per-scan kernels,
+reached here by lowering ``pallas_scan._FUSED_MAX_LANES`` below the test's
+width) vs the pure-Python host oracle, across every in-kernel block width,
+multi-block carries, and the edge documents.
 The mesh tests assert the shard_map'd kernels match single-device output
 bit-for-bit on the 8 virtual CPU devices conftest forces.
 """
@@ -21,11 +23,7 @@ import jax.numpy as jnp  # noqa: E402
 try:
     from textblaster_tpu.ops import pallas_scan as psc
     from textblaster_tpu.ops import pallas_sort as pso
-    from textblaster_tpu.ops.stats import (
-        fineweb_stats,
-        gopher_quality_stats,
-        structure,
-    )
+    from textblaster_tpu.ops.stats import fineweb_stats, structure
     from textblaster_tpu.parallel.mesh import batch_sharding, data_mesh
 except Exception as e:  # pragma: no cover - partial jax builds
     pytest.skip(f"pallas scan stack unavailable: {e}", allow_module_level=True)
@@ -35,11 +33,15 @@ pytestmark = [pytest.mark.pallas, pytest.mark.fused]
 
 @pytest.fixture
 def interp(monkeypatch):
-    """Force the interpret-mode kernel path; clear any disabling hatch."""
+    """Force the interpret-mode kernel path; clear the disabling hatch."""
     monkeypatch.delenv("TEXTBLAST_PALLAS", raising=False)
-    monkeypatch.delenv("TEXTBLAST_NO_PALLAS", raising=False)
-    monkeypatch.delenv("TEXTBLAST_FUSED", raising=False)
     monkeypatch.setenv("TEXTBLAST_PALLAS_INTERPRET", "1")
+
+
+def _staged(m):
+    """Inside ``monkeypatch.context() as m``: the staged path with the
+    per-scan kernels, as the chip runs it above the fused lane ceiling."""
+    m.setattr(psc, "_FUSED_MAX_LANES", 128)
 
 
 def _full_range_int32(rng, shape):
@@ -153,17 +155,12 @@ def test_fused_is_one_dispatch(interp):
 # --- gates and hatches ------------------------------------------------------
 
 
-def test_fused_gate(interp, monkeypatch):
+def test_fused_gate(interp):
     assert psc.fused_scan_ok(8, 256)
     assert not psc.fused_scan_ok(12, 256)  # rows not a multiple of 8
     assert not psc.fused_scan_ok(8, 100)  # length not a multiple of 128
     assert not psc.fused_scan_ok(8, 2 * psc._FUSED_MAX_LANES)  # VMEM ceiling
     assert psc.pallas_scan_ok(8, 2 * psc._FUSED_MAX_LANES)  # per-scan still ok
-    monkeypatch.setenv("TEXTBLAST_FUSED", "off")
-    assert not psc.fused_scan_ok(8, 256)  # hatch hits only the fused kernel
-    assert psc.pallas_scan_ok(8, 256)
-    monkeypatch.setenv("TEXTBLAST_FUSED", "on")
-    assert psc.fused_scan_ok(8, 256)
 
 
 def test_probe_cache_keys_on_env_hatches(monkeypatch):
@@ -172,7 +169,6 @@ def test_probe_cache_keys_on_env_hatches(monkeypatch):
     for mod in (psc, pso):
         mod._probe_cached.cache_clear()
         monkeypatch.delenv("TEXTBLAST_PALLAS", raising=False)
-        monkeypatch.delenv("TEXTBLAST_NO_PALLAS", raising=False)
         monkeypatch.setenv("TEXTBLAST_PALLAS_INTERPRET", "1")
         e1 = mod._env_hatches()
         mod._probe_backend()
@@ -212,8 +208,7 @@ def test_pipeline_probes_before_tracing(probe, monkeypatch):
     from textblaster_tpu.config.pipeline import load_pipeline_config
     from textblaster_tpu.ops.pipeline import CompiledPipeline
 
-    for var in ("TEXTBLAST_PALLAS", "TEXTBLAST_NO_PALLAS", "TEXTBLAST_FUSED",
-                "TEXTBLAST_DEPFUSE", "TEXTBLAST_PALLAS_INTERPRET"):
+    for var in ("TEXTBLAST_PALLAS", "TEXTBLAST_PALLAS_INTERPRET"):
         monkeypatch.delenv(var, raising=False)
     asked = []
     for name, mod, attr in (("sort", pso, "_probe_backend"),
@@ -264,31 +259,17 @@ def test_structure_fused_vs_staged(interp, monkeypatch, with_hashes):
         fused = structure(cps, lens, with_hashes=with_hashes)
     assert counts.get("fused") == 1
     with monkeypatch.context() as m:
-        m.setenv("TEXTBLAST_FUSED", "off")
+        _staged(m)
         staged = structure(cps, lens, with_hashes=with_hashes)
     for k, v in _structure_fields(fused).items():
         np.testing.assert_array_equal(v, _structure_fields(staged)[k], err_msg=k)
-
-
-def test_gopher_quality_fused_vs_staged(interp, monkeypatch):
-    cps, lens = _edge_batch()
-    hashes = tuple(range(-5, 5))
-    fused = gopher_quality_stats(structure(cps, lens), hashes)
-    with monkeypatch.context() as m:
-        m.setenv("TEXTBLAST_FUSED", "off")
-        staged = gopher_quality_stats(structure(cps, lens), hashes)
-    assert set(fused) == set(staged)
-    for k in fused:
-        np.testing.assert_array_equal(
-            np.asarray(fused[k]), np.asarray(staged[k]), err_msg=k
-        )
 
 
 def test_fineweb_fused_vs_staged(interp, monkeypatch):
     cps, lens = _edge_batch()
     fused = fineweb_stats(structure(cps, lens), (".", "!", "?"), 64, 30)
     with monkeypatch.context() as m:
-        m.setenv("TEXTBLAST_FUSED", "off")
+        _staged(m)
         staged = fineweb_stats(structure(cps, lens), (".", "!", "?"), 64, 30)
     assert set(fused) == set(staged)
     for k in fused:
@@ -298,7 +279,7 @@ def test_fineweb_fused_vs_staged(interp, monkeypatch):
 
 
 def test_full_pipeline_three_way_parity(interp, monkeypatch):
-    """Whole-pipeline decisions: fused kernels vs staged (TEXTBLAST_FUSED=off)
+    """Whole-pipeline decisions: fused kernels vs staged (per-scan kernels)
     vs the pure-Python host oracle must agree on kind/reason/content."""
     from textblaster_tpu.config.pipeline import parse_pipeline_config
     from textblaster_tpu.data_model import TextDocument
@@ -360,7 +341,7 @@ pipeline:
         for o in process_documents_device(config, iter(docs()), device_batch=8)
     }
     with monkeypatch.context() as m:
-        m.setenv("TEXTBLAST_FUSED", "off")
+        _staged(m)
         staged = {
             o.document.id: o
             for o in process_documents_device(config, iter(docs()), device_batch=8)
@@ -475,9 +456,10 @@ def test_scan_dispatch_counts_fused_vs_staged(interp, monkeypatch):
     fused = p.scan_dispatch_counts(256)
     assert fused.get("fused", 0) >= 1
     with monkeypatch.context() as m:
-        m.setenv("TEXTBLAST_FUSED", "off")
+        _staged(m)
         staged = _pipeline().scan_dispatch_counts(256)
     assert staged.get("fused", 0) == 0
+    assert staged.get("pallas_scan", 0) >= 1
     total_fused = sum(fused.values())
     total_staged = sum(staged.values())
     assert total_fused < total_staged  # the megakernel removed dispatches

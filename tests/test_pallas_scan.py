@@ -29,9 +29,8 @@ pytestmark = pytest.mark.pallas
 
 @pytest.fixture
 def interp(monkeypatch):
-    """Force the interpret-mode kernel path; clear any disabling hatch."""
+    """Force the interpret-mode kernel path; clear the disabling hatch."""
     monkeypatch.delenv("TEXTBLAST_PALLAS", raising=False)
-    monkeypatch.delenv("TEXTBLAST_NO_PALLAS", raising=False)
     monkeypatch.setenv("TEXTBLAST_PALLAS_INTERPRET", "1")
 
 
@@ -171,9 +170,6 @@ def test_escape_hatches_win_over_interpret(monkeypatch):
     monkeypatch.setenv("TEXTBLAST_PALLAS", "off")
     assert not psc.pallas_scan_supported()
     monkeypatch.delenv("TEXTBLAST_PALLAS")
-    monkeypatch.setenv("TEXTBLAST_NO_PALLAS", "1")
-    assert not psc.pallas_scan_supported()
-    monkeypatch.delenv("TEXTBLAST_NO_PALLAS")
     assert psc.pallas_scan_supported()
 
 
@@ -197,7 +193,6 @@ def test_compiled_kernel_parity_on_accelerator(monkeypatch):
     """The Mosaic-compiled kernel (not interpret mode) vs lax on a real
     accelerator — skipped on CPU, where the probe declines by design."""
     monkeypatch.delenv("TEXTBLAST_PALLAS", raising=False)
-    monkeypatch.delenv("TEXTBLAST_NO_PALLAS", raising=False)
     monkeypatch.delenv("TEXTBLAST_PALLAS_INTERPRET", raising=False)
     if jax.default_backend() == "cpu":
         pytest.skip("needs an accelerator backend")

@@ -47,13 +47,7 @@ pipeline:
 def interp(monkeypatch):
     """Pin the trace-shaping knobs to their defaults + interpret mode, so
     compiled programs (and their cost models) are machine-independent."""
-    for k in (
-        "TEXTBLAST_PALLAS",
-        "TEXTBLAST_NO_PALLAS",
-        "TEXTBLAST_FUSED",
-        "TEXTBLAST_DEPFUSE",
-        "TEXTBLAST_NO_COMPILE_CACHE",
-    ):
+    for k in ("TEXTBLAST_PALLAS", "TEXTBLAST_NO_COMPILE_CACHE"):
         monkeypatch.delenv(k, raising=False)
     monkeypatch.setenv("TEXTBLAST_PALLAS_INTERPRET", "1")
 
@@ -308,9 +302,9 @@ def test_sentinel_counts_check_passes_with_join_modules_imported(tmp_path):
 
 
 def test_sentinel_check_fails_on_depfuse_off(tmp_path):
-    """A flipped fusion hatch must fail the check, naming the drifted
-    (bucket, phase) entries — fast: the counts stage fails before any
-    compile."""
+    """A flipped kernel hatch (``TEXTBLAST_PALLAS=off``) must fail the
+    check, naming the drifted (bucket, phase) entries and the knob — fast:
+    the counts stage fails before any compile."""
     proc = subprocess.run(
         [
             sys.executable,
@@ -320,7 +314,7 @@ def test_sentinel_check_fails_on_depfuse_off(tmp_path):
             BASELINE,
         ],
         env=_clean_env(
-            TEXTBLAST_DEPFUSE="off",
+            TEXTBLAST_PALLAS="off",
             TEXTBLAST_AOT_CACHE_DIR=str(tmp_path / "aot"),
         ),
         cwd=REPO,
@@ -331,7 +325,7 @@ def test_sentinel_check_fails_on_depfuse_off(tmp_path):
     assert proc.returncode == 1, proc.stdout + proc.stderr
     assert "dispatch counts drifted" in proc.stdout
     assert "b256/p0/r16" in proc.stdout
-    assert "TEXTBLAST_DEPFUSE" in proc.stdout  # env drift note
+    assert "TEXTBLAST_PALLAS" in proc.stdout  # env drift note
 
 
 @pytest.mark.slow

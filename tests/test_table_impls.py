@@ -1,30 +1,29 @@
-"""Scatter vs sort table-construction parity.
+"""The device program's scan and table primitives against plain references.
 
-The device kernels build their per-line/per-segment/per-word tables two ways
-(:func:`textblaster_tpu.ops.device.use_sort_tables`): XLA scatters (the CPU
-default) and a sorted compaction + gathers (the TPU default — XLA:TPU
-serializes scatters into per-element loops).  The TPU
-path cannot run on TPU in CI, but its *semantics* are backend-independent:
-this suite pins both implementations to identical outputs on the nasty-case
-corpus (blank lines, trailing newlines, all-whitespace lines, citations,
-empty docs, dense repetition), so a silicon window only has to validate
-performance, not correctness.
+Every backend runs one program: segmented scans on the contiguous-shift
+schedule (:func:`textblaster_tpu.ops.device.shift_scan_tuple`) and
+per-segment tables built by sorted compaction, never by XLA scatter.  This
+suite pins each primitive to an independent reference written here — a
+sequential numpy loop per scan, a numpy stable partition for ``compact``, a
+Python span walk for the citation fill — at widths that cross the doubling
+steps, and runs the nasty-case corpus (blank lines, trailing newlines,
+all-whitespace lines, citations, empty docs, dense repetition) through
+device-vs-host-oracle parity for the filters whose tables these build.
 """
 
-import os
+import zlib
 
 import numpy as np
 import pytest
 
-import jax
+import jax.numpy as jnp
 
-from textblaster_tpu.data_model import TextDocument
-from textblaster_tpu.ops import compact as C
-from textblaster_tpu.ops import langid_tpu as LT
-from textblaster_tpu.ops import stats as S
-from textblaster_tpu.ops.packing import pack_documents
+from textblaster_tpu.ops import device as D
+from textblaster_tpu.ops.compact import compact
+from textblaster_tpu.ops.dfa import citation_spans
+from textblaster_tpu.ops.stats import _poly_hash_many
 
-from test_device_parity import CORPUS
+from test_device_parity import CORPUS, assert_outcomes_equal, run_both
 
 EXTRA = [
     "a\n\n\nb\nc\n",
@@ -41,161 +40,248 @@ EXTRA = [
     "æøå πολύ 北京 😀 mixed\nscripts here.",
 ]
 
-ML, MW = 128, 256
+_I32_MIN = int(np.iinfo(np.int32).min)
 
-C4P = S.C4Params(
-    split_paragraph=True,
-    remove_citations=True,
-    filter_no_terminal_punct=True,
-    min_num_sentences=3,
-    min_words_per_line=2,
-    max_word_length=20,
-    filter_lorem_ipsum=True,
-    filter_javascript=True,
-    filter_curly_bracket=True,
-    filter_policy=True,
+# Widths below, at and across the doubling steps (1 lane, one past 128, 512).
+WIDTHS = (1, 129, 512)
+
+
+@pytest.fixture(autouse=True)
+def lax_path(monkeypatch):
+    """The lax schedule, not the interpret-mode kernels."""
+    monkeypatch.delenv("TEXTBLAST_PALLAS_INTERPRET", raising=False)
+
+
+def _seq(values, flags, step, first):
+    """Row-wise sequential scan: ``out[i] = step(out[i-1], v[i], f[i])``,
+    ``out[0] = first(v[0], f[0])``, in int64 then wrapped to int32."""
+    out = np.zeros(values.shape, np.int64)
+    for r in range(values.shape[0]):
+        acc = None
+        for i in range(values.shape[1]):
+            v, f = int(values[r, i]), bool(flags[r, i])
+            acc = first(v, f) if acc is None else step(acc, v, f)
+            out[r, i] = acc
+    return out.astype(np.int32)
+
+
+def _ref_poly_hash(values, in_seg, seg_start):
+    # h = h*31 + v inside a segment, restarting at each segment start;
+    # positions outside segments pass the running hash through.
+    out = np.zeros(values.shape, np.int64)
+    for r in range(values.shape[0]):
+        h = 0
+        for i in range(values.shape[1]):
+            v = int(values[r, i])
+            if seg_start[r, i]:
+                h = v
+            elif in_seg[r, i]:
+                h = h * 31 + v
+            out[r, i] = h
+            h = int(np.int64(h).astype(np.int32))
+    return out.astype(np.int32)
+
+
+def _scan_case(name, vals, flags):
+    v, f = jnp.asarray(vals), jnp.asarray(flags)
+    if name == "seg_scan_add":
+        got = D.seg_scan_add(v, f)
+        want = _seq(vals, flags, lambda a, x, r: x if r else a + x, lambda x, r: x)
+    elif name == "seg_scan_or":
+        got = D.seg_scan_or(v, f)
+        want = _seq(vals, flags, lambda a, x, r: x if r else a | x, lambda x, r: x)
+    elif name == "seg_scan_max":
+        got = D.seg_scan_max(v, f)
+        want = _seq(vals, flags, lambda a, x, r: x if r else max(a, x), lambda x, r: x)
+    elif name == "latch_scan":
+        # Every caller holds its values at 0 off the set positions.
+        vals = np.where(flags, vals, 0).astype(np.int32)
+        got = D.latch_scan(jnp.asarray(vals), f)
+        want = _seq(
+            vals, flags, lambda a, x, s: x if s else a, lambda x, s: x if s else 0
+        )
+    elif name == "assoc_scan1_max":
+        got = D.assoc_scan1(jnp.maximum, np.int32(_I32_MIN), v)
+        want = _seq(vals, flags, lambda a, x, _: max(a, x), lambda x, _: x)
+    else:  # _poly_hash_many: two streams sharing one segmentation
+        rng = np.random.default_rng(vals.shape[1])
+        in_seg = rng.random(vals.shape) < 0.8
+        seg_start = flags & in_seg
+        vals2 = rng.integers(0, 0x110000, vals.shape, dtype=np.int32)
+        got_a, got_b = _poly_hash_many(
+            (v, jnp.asarray(vals2)), jnp.asarray(in_seg), jnp.asarray(seg_start)
+        )
+        np.testing.assert_array_equal(
+            np.asarray(got_b), _ref_poly_hash(vals2, in_seg, seg_start)
+        )
+        got, want = got_a, _ref_poly_hash(vals, in_seg, seg_start)
+    np.testing.assert_array_equal(np.asarray(got), want)
+
+
+@pytest.mark.parametrize("width", WIDTHS)
+@pytest.mark.parametrize(
+    "name",
+    [
+        "seg_scan_add",
+        "seg_scan_or",
+        "seg_scan_max",
+        "latch_scan",
+        "assoc_scan1_max",
+        "poly_hash_many",
+    ],
 )
+def test_shift_scan_matches_sequential(name, width):
+    rng = np.random.default_rng(zlib.crc32(f"{name}/{width}".encode()))
+    shape = (3, width)
+    if name in ("seg_scan_add", "poly_hash_many"):
+        # Full-range int32: the int32 wraparound must match exactly.
+        vals = rng.integers(_I32_MIN, 2**31, shape, dtype=np.int64).astype(np.int32)
+    elif name == "seg_scan_or":
+        vals = rng.integers(0, 2**20, shape, dtype=np.int32)
+    else:
+        vals = rng.integers(-1000, 1000, shape, dtype=np.int32)
+    # Dense, sparse and near-empty reset rows: long segments reach the
+    # last doubling levels.
+    flags = rng.random(shape) < np.array([[0.1], [0.01], [0.002]])
+    _scan_case(name, vals, flags)
 
 
-def _batch():
-    docs = [
-        TextDocument(id=str(i), content=c, source="s")
-        for i, c in enumerate(CORPUS + EXTRA)
-        if len(c) <= 500
+@pytest.mark.parametrize("width", [200, 256])
+def test_compact_matches_stable_partition(width):
+    rng = np.random.default_rng(width)
+    cps = rng.integers(0, 0x110000, (8, width), dtype=np.int32)
+    keep = rng.random((8, width)) < 0.6
+    keep[0] = False  # nothing kept
+    keep[1] = True  # everything kept
+    got_cps, got_len = compact(jnp.asarray(cps), jnp.asarray(keep))
+    want = np.zeros_like(cps)
+    for r in range(cps.shape[0]):
+        kept = cps[r][keep[r]]
+        want[r, : len(kept)] = kept
+    np.testing.assert_array_equal(np.asarray(got_cps), want)
+    np.testing.assert_array_equal(np.asarray(got_len), keep.sum(axis=1))
+
+
+def _ref_citations(text):
+    """Python span walk for ``\\[\\d+(?:,\\s*\\d+)*\\]``, leftmost first,
+    non-overlapping, with the same digit and whitespace predicates as the
+    masks handed to the kernel."""
+    inside = [False] * len(text)
+    i = 0
+    while i < len(text):
+        if text[i] != "[":
+            i += 1
+            continue
+        j = i + 1
+        ok = False
+        while True:
+            start = j
+            while j < len(text) and text[j].isdigit():
+                j += 1
+            if j == start:
+                break
+            if j < len(text) and text[j] == "]":
+                ok = True
+                break
+            if j < len(text) and text[j] == ",":
+                j += 1
+                while j < len(text) and text[j].isspace():
+                    j += 1
+                continue
+            break
+        if ok:
+            for k in range(i, j + 1):
+                inside[k] = True
+            i = j + 1
+        else:
+            i += 1
+    return inside
+
+
+def test_citation_spans_match_span_walk():
+    texts = [
+        "x [1] y [2, 3] z [4]",
+        "[broken [5] citation] more",
+        "[[1]] [1,2,  3] [1 ,2] [,1] [] [12a] [7]",
+        "no citations here",
+        "[1][2][3]",
+        "tail [9",
+        "[3,\t4]\n[5]",
+        "",
     ]
-    docs += [
-        TextDocument(id=f"p{i}", content="pad doc.", source="s")
-        for i in range((-len(docs)) % 8)
-    ]
-    return pack_documents(docs, len(docs), 512)
+    width = 64
+    cps = np.zeros((len(texts), width), np.int32)
+    for r, t in enumerate(texts):
+        cps[r, : len(t)] = [ord(c) for c in t]
+    chars = [[chr(c) if c else "\0" for c in row] for row in cps]
+    digit = np.array([[c.isdigit() for c in row] for row in chars])
+    ws = np.array([[c.isspace() for c in row] for row in chars])
+    got = np.asarray(citation_spans(jnp.asarray(cps), jnp.asarray(digit), jnp.asarray(ws)))
+    for r, t in enumerate(texts):
+        want = _ref_citations(t) + [False] * (width - len(t))
+        assert got[r].tolist() == want, t
 
 
-def _k_rep(cps, lengths):
-    st = S.structure(cps, lengths)
-    return dict(S.gopher_rep_stats(st, (2, 3, 4), (5, 6, 10), ML, MW))
+_STEP_YAML = {
+    "gopher_repetition": """
+  - type: GopherRepetitionFilter
+    dup_line_frac: 0.2
+    dup_para_frac: 0.2
+    dup_line_char_frac: 0.15
+    dup_para_char_frac: 0.15
+    top_n_grams: [[2, 0.1], [3, 0.1]]
+    dup_n_grams: [[4, 0.1], [5, 0.1]]
+""",
+    "fineweb_gopher_quality": """
+  - type: FineWebQualityFilter
+    line_punct_thr: 0.12
+    line_punct_exclude_zero: false
+    short_line_thr: 0.67
+    short_line_length: 30
+    char_duplicates_ratio: 0.1
+    new_line_ratio: 0.3
+  - type: GopherQualityFilter
+    min_doc_words: 2
+    max_doc_words: 1000
+    min_avg_word_length: 1.0
+    max_avg_word_length: 12.0
+    max_symbol_word_ratio: 0.5
+    max_bullet_lines_ratio: 0.9
+    max_ellipsis_lines_ratio: 0.5
+    max_non_alpha_words_ratio: 0.9
+    min_stop_words: 0
+    stop_words: [ "og", "er", "det", "the" ]
+""",
+    "c4_paragraph": """
+  - type: C4QualityFilter
+    split_paragraph: true
+    remove_citations: true
+    filter_no_terminal_punct: true
+    min_num_sentences: 1
+    min_words_per_line: 2
+    max_word_length: 20
+    filter_lorem_ipsum: true
+    filter_javascript: true
+    filter_curly_bracket: true
+    filter_policy: true
+""",
+    "c4_sentence": """
+  - type: C4QualityFilter
+    split_paragraph: false
+    remove_citations: true
+    filter_no_terminal_punct: true
+    min_num_sentences: 1
+    min_words_per_line: 2
+    max_word_length: 20
+    filter_lorem_ipsum: true
+    filter_javascript: true
+    filter_curly_bracket: true
+    filter_policy: true
+""",
+}
 
 
-def _k_fw(cps, lengths):
-    st = S.structure(cps, lengths)
-    out = dict(S.fineweb_stats(st, ('"', "'", ".", "!", "?", "”"), ML, 30))
-    out.update(
-        S.gopher_quality_stats(
-            st, tuple(S.hash_string(w) for w in ("og", "er", "det", "the"))
-        )
-    )
-    return out
-
-
-def _k_c4(cps, lengths):
-    c4s, c4c, c4l = S.c4_stage(cps, lengths, C4P, ML)
-    out = dict(c4s)
-    out["cps"], out["len"] = c4c, c4l
-    sp, sc, sl = S.c4_stage(
-        cps, lengths, C4P._replace(split_paragraph=False), ML
-    )
-    out.update({f"sent:{k}": v for k, v in sp.items()})
-    out["sent:cps"], out["sent:len"] = sc, sl
-    return out
-
-
-def _k_misc(cps, lengths):
-    import jax.numpy as jnp
-
-    keep = (cps % 3 != 0) & (jnp.arange(cps.shape[1])[None, :] < lengths[:, None])
-    cc, clen = C.compact(cps, keep)
-    sc, ng = LT.langid_scores(cps, lengths)
-    return {"c_cps": cc, "c_len": clen, "scores": sc, "n": ng}
-
-
-def _run(kernel, impl, cps, lengths, monkeypatch):
-    monkeypatch.setenv("TEXTBLAST_TABLE_IMPL", impl)
-
-    # A FRESH function object per run: jax.jit caches compiled executables
-    # keyed on the underlying function, so re-wrapping the same module-level
-    # kernel after an env flip would silently return the previous impl's
-    # cached result and make the comparison vacuous (caught by review).
-    def fresh(c, l):
-        return kernel(c, l)
-
-    return jax.device_get(jax.jit(fresh)(cps, lengths))
-
-
-@pytest.mark.parametrize("kernel", [_k_rep, _k_fw, _k_c4, _k_misc])
-def test_sort_tables_match_scatter(kernel, monkeypatch):
-    batch = _batch()
-    ref = _run(kernel, "scatter", batch.cps, batch.lengths, monkeypatch)
-    got = _run(kernel, "sort", batch.cps, batch.lengths, monkeypatch)
-    assert set(ref) == set(got)
-    for k in ref:
-        np.testing.assert_array_equal(
-            np.asarray(ref[k]), np.asarray(got[k]), err_msg=k
-        )
-
-
-@pytest.mark.parametrize("kernel", [_k_rep, _k_fw, _k_c4, _k_misc])
-def test_chunk_scan_matches_default(kernel, monkeypatch):
-    """The blocked `chunk` scan schedule (TEXTBLAST_SCAN_IMPL=chunk) must be
-    bit-identical to the default schedule across every kernel — any scan
-    schedule computes the same values for associative monoids, and this pins
-    the implementation to that promise (incl. padding of non-multiple
-    lengths and segmented resets)."""
-    batch = _batch()
-
-    def fresh_ref(c, l):  # fresh fn objects per impl — see _run
-        return kernel(c, l)
-
-    def fresh_chunk(c, l):
-        return kernel(c, l)
-
-    monkeypatch.delenv("TEXTBLAST_SCAN_IMPL", raising=False)
-    ref = jax.device_get(jax.jit(fresh_ref)(batch.cps, batch.lengths))
-    monkeypatch.setenv("TEXTBLAST_SCAN_IMPL", "chunk")
-    # Odd chunk size forces in-chunk padding; 48 < 512/2 engages the path.
-    monkeypatch.setenv("TEXTBLAST_SCAN_CHUNK", "48")
-    got = jax.device_get(jax.jit(fresh_chunk)(batch.cps, batch.lengths))
-    assert set(ref) == set(got)
-    for k in ref:
-        np.testing.assert_array_equal(
-            np.asarray(ref[k]), np.asarray(got[k]), err_msg=k
-        )
-
-
-def test_chunk_scan_tuple_direct():
-    """Direct unit pin of chunk_scan_tuple against the shift schedule:
-    random segmented add/max/latch streams (scalar identities) and a
-    function-composition scan with an iota array identity + trailing dims —
-    odd lengths force the padding path."""
-    import jax.numpy as jnp
-
-    from textblaster_tpu.ops.device import (
-        _latch_op,
-        _seg_add_op,
-        _seg_max_op,
-        chunk_scan_tuple,
-        shift_scan_tuple,
-    )
-
-    rng = np.random.default_rng(3)
-    for length in (7, 48, 96, 131, 513):
-        vals = jnp.asarray(rng.integers(0, 100, (4, length), dtype=np.int32))
-        reset = jnp.asarray(rng.random((4, length)) < 0.15)
-        for op, ident in ((_seg_add_op, 0), (_seg_max_op, -(2**31)), (_latch_op, 0)):
-            want = shift_scan_tuple(op, (ident, False), (vals, reset))
-            got = chunk_scan_tuple(op, (ident, False), (vals, reset), chunk_size=16)
-            for w, g in zip(want, got):
-                np.testing.assert_array_equal(np.asarray(w), np.asarray(g))
-
-    # Function composition with trailing state dim: f_i : [N] -> [N] maps,
-    # composed left-to-right (the dfa_states >8-states shape).
-    n_states = 5
-    fns = jnp.asarray(rng.integers(0, n_states, (3, 67, n_states), dtype=np.int32))
-    iota = jnp.arange(n_states, dtype=jnp.int32)
-
-    def compose(a, b):
-        # take_along_axis needs equal ranks; chunk broadcasts operands first.
-        a0, b0 = jnp.broadcast_arrays(a[0], b[0])
-        return (jnp.take_along_axis(b0, a0, axis=-1),)
-
-    want = shift_scan_tuple(compose, (iota,), (fns,))[0]
-    got = chunk_scan_tuple(compose, (iota,), (fns,), chunk_size=8)[0]
-    np.testing.assert_array_equal(np.asarray(want), np.asarray(got))
+@pytest.mark.parametrize("step", sorted(_STEP_YAML))
+def test_nasty_corpus_device_matches_oracle(step):
+    host_by_id, dev_by_id = run_both("pipeline:" + _STEP_YAML[step], CORPUS + EXTRA)
+    assert_outcomes_equal(host_by_id, dev_by_id)

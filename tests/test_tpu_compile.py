@@ -61,9 +61,8 @@ def one_chip(topo):
 
 @pytest.fixture
 def compiled_kernels(monkeypatch):
-    """Real (not interpret-mode) kernels, every hatch open."""
-    for var in ("TEXTBLAST_PALLAS", "TEXTBLAST_NO_PALLAS", "TEXTBLAST_FUSED",
-                "TEXTBLAST_DEPFUSE", "TEXTBLAST_PALLAS_INTERPRET"):
+    """Real (not interpret-mode) kernels, the hatch open."""
+    for var in ("TEXTBLAST_PALLAS", "TEXTBLAST_PALLAS_INTERPRET"):
         monkeypatch.delenv(var, raising=False)
 
 
@@ -149,10 +148,9 @@ def test_sort_kernel_compiles(one_chip, compiled_kernels, n_keys):
 
 @pytest.fixture
 def tpu_defaults(compiled_kernels, monkeypatch):
-    """The TPU's own scan, table and wire choices and kernel probes (this
-    process's backend is the CPU, so they are steered here)."""
-    monkeypatch.setenv("TEXTBLAST_SCAN_IMPL", "shift")
-    monkeypatch.setenv("TEXTBLAST_TABLE_IMPL", "sort")
+    """The TPU's own wire choice and kernel probes (this process's backend
+    is the CPU, so they are steered here; scans and tables are the same
+    program on every backend)."""
     monkeypatch.setenv("TEXTBLAST_WIRE", "u16")
     for mod, name in ((pso, "_probe_backend"), (psc, "_probe_backend"),
                       (psc, "_probe_fused"), (psc, "_probe_depfuse")):

@@ -8,21 +8,21 @@ lengths.  Downstream filter kernels then run on the compacted batch exactly as
 they would on any packed batch — sequential pipeline semantics preserved
 without leaving the device (SURVEY.md §7 "content rewriting" hard part).
 
-Two implementations behind :func:`textblaster_tpu.ops.device.use_sort_tables`:
-an XLA scatter (fast on CPU, serialized on TPU) and a sorted partition on the
-VMEM bitonic network (TPU).  Also used by the language-ID kernel to build its
-normalized letters-and-boundaries stream.
+The kept chars are moved by a stable partition: one row sort keyed by
+original position (kept) or ``INT32_MAX`` (dropped), on the VMEM bitonic
+network on TPU and ``lax.sort`` elsewhere — no XLA scatter, which XLA:TPU
+serializes into per-element loops.  Also used by the language-ID kernel to
+build its normalized letters-and-boundaries stream.
 """
 
 from __future__ import annotations
 
-from typing import Optional, Tuple
+from typing import Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .device import use_sort_tables
 from .pallas_sort import sort2
 
 __all__ = ["compact"]
@@ -38,39 +38,25 @@ def compact(
     Args:
       cps:  ``[B, L]`` int32 codepoints.
       keep: ``[B, L]`` bool; True chars survive, order preserved.
-      mesh: data-axis mesh for the TPU sort path (pallas under shard_map).
+      mesh: data-axis mesh for the sort (the Pallas kernel under shard_map).
 
     Returns:
       ``(new_cps [B, L] int32 zero-padded, new_lengths [B] int32)``.
     """
     b, length = cps.shape
 
-    if use_sort_tables():
-        new_lengths = jnp.sum(keep, axis=1).astype(jnp.int32)
-        # Stable partition by sort: key = original position for kept chars,
-        # INT32_MAX for dropped — kept chars land at the row start in order.
-        # Codepoints are non-negative, satisfying sort2's payload contract.
-        pos = jnp.broadcast_to(
-            jnp.arange(length, dtype=jnp.int32)[None, :], (b, length)
-        )
-        key = jnp.where(keep, pos, _I32_MAX)
-        val = jnp.where(keep, cps, 0)
-        padded = 1 << (length - 1).bit_length()
-        if padded != length:
-            pad = ((0, 0), (0, padded - length))
-            key = jnp.pad(key, pad, constant_values=_I32_MAX)
-            val = jnp.pad(val, pad)
-        s_key, s_val = sort2(key, val, mesh=mesh)
-        new_cps = jnp.where(s_key[:, :length] != _I32_MAX, s_val[:, :length], 0)
-        return new_cps, new_lengths
-
-    # Flat scatter; dropped chars route to a trash slot past the real data.
-    # (Byte-identical to the pre-gating trace so the CPU compile cache and
-    # tuned CPU-backend record are preserved.)
-    new_pos = jnp.cumsum(keep.astype(jnp.int32), axis=1) - 1
-    new_lengths = jnp.max(jnp.where(keep, new_pos + 1, 0), axis=1)
-    rows = jnp.arange(b, dtype=jnp.int32)[:, None]
-    flat_idx = jnp.where(keep, rows * length + new_pos, b * length)
-    out = jnp.zeros(b * length + 1, dtype=cps.dtype)
-    out = out.at[flat_idx.reshape(-1)].set(cps.reshape(-1), mode="drop")
-    return out[:-1].reshape(b, length), new_lengths.astype(jnp.int32)
+    new_lengths = jnp.sum(keep, axis=1).astype(jnp.int32)
+    # Stable partition by sort: key = original position for kept chars,
+    # INT32_MAX for dropped — kept chars land at the row start in order.
+    # Codepoints are non-negative, satisfying sort2's payload contract.
+    pos = jnp.broadcast_to(jnp.arange(length, dtype=jnp.int32)[None, :], (b, length))
+    key = jnp.where(keep, pos, _I32_MAX)
+    val = jnp.where(keep, cps, 0)
+    padded = 1 << (length - 1).bit_length()
+    if padded != length:
+        pad = ((0, 0), (0, padded - length))
+        key = jnp.pad(key, pad, constant_values=_I32_MAX)
+        val = jnp.pad(val, pad)
+    s_key, s_val = sort2(key, val, mesh=mesh)
+    new_cps = jnp.where(s_key[:, :length] != _I32_MAX, s_val[:, :length], 0)
+    return new_cps, new_lengths

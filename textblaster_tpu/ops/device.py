@@ -7,8 +7,10 @@ associative scans for per-word / per-line / per-paragraph aggregates, and
 rolling hashes for duplicate detection.
 
 All kernels operate on ``[B, L]`` codepoint tensors with a validity mask;
-reductions are along axis 1.  Scans use ``jax.lax.associative_scan``, which
-XLA lowers to log-depth work-efficient trees on the VPU.
+reductions are along axis 1.  Scans run on one lax schedule, the
+contiguous-shift doubling below, on every backend; per-segment tables are
+built by a sorted compaction (:mod:`.stats`, :mod:`.compact`), never by an
+XLA scatter.
 """
 
 from __future__ import annotations
@@ -33,7 +35,6 @@ __all__ = [
     "seg_scan_or",
     "seg_scan_max",
     "latch_scan",
-    "use_sort_tables",
     "rev",
     "ALNUM",
     "ALPHA",
@@ -150,28 +151,18 @@ MID_ALL_CPS = np.sort(np.array([ord(c) for c in _MID_ALL], dtype=np.int32))
 
 # --- Segmented scans ---------------------------------------------------------
 # State (v, r): r = "resets here".  Composition is the standard segmented-scan
-# monoid; associative, so any scan schedule computes the same values.
+# monoid, so any scan schedule computes the same values; the lax path has one.
 #
-# Three schedules are provided:
-#
-# * ``assoc`` — ``jax.lax.associative_scan`` (work-efficient odd/even
-#   recursion).  Its stride-2 slices relayout on TPU's tiled [sublane, lane]
-#   layouts, which makes each of the log L levels far more expensive than its
-#   FLOPs suggest.
-# * ``shift`` — Hillis-Steele doubling: level ``d`` combines position ``i``
-#   with ``i - d`` via a pad+slice shift (contiguous, layout-preserving).
-#   O(L log L) work instead of O(L), but every step is a cheap contiguous
-#   move — the TPU-friendly schedule.
-# * ``chunk`` — blocked three-phase scan: reshape ``[B, L]`` to chunks
-#   ``[C, B, n]``, one ``lax.scan`` over the C in-chunk positions (carry
-#   ``[B, n]`` — every row and chunk advances in lockstep), a tiny
-#   cross-chunk prefix over ``n``, and one broadcast combine.  ~O(2L) work
-#   and ~4 full-array memory passes versus shift's log L — the candidate
-#   replacement wherever scan passes dominate; kept opt-in until measured
-#   on silicon (microbench3).
-#
-# ``TEXTBLAST_SCAN_IMPL`` (assoc|shift|chunk) pins one; default picks by
-# backend at trace time (shift on tpu-like backends, assoc elsewhere).
+# ``shift`` — Hillis-Steele doubling: level ``d`` combines position ``i``
+# with ``i - d`` via a pad+slice shift.  O(L log L) work instead of the
+# work-efficient O(L), but every step is a contiguous, layout-preserving
+# move.  ``lax.associative_scan``'s odd/even recursion takes stride-2 slices
+# instead, which relayout on TPU's tiled [sublane, lane] layouts and make
+# each of its log L levels far dearer than its FLOPs suggest; shift is the
+# schedule the chip was measured on.  The same program runs on every
+# backend, so the CPU test suite checks what the chip runs.  Where
+# ``pallas_scan.pallas_scan_ok`` admits a shape, the Pallas kernels take the
+# scan instead (same ops, bit-identical by integer associativity).
 
 
 def _seg_add_op(a, b):
@@ -199,26 +190,6 @@ def _latch_op(a, b):
     return jnp.where(br, bv, av), ar | br
 
 
-def _scan_impl() -> str:
-    import os
-
-    impl = os.environ.get("TEXTBLAST_SCAN_IMPL", "")
-    if impl in ("shift", "assoc", "chunk"):
-        return impl
-    if jax.default_backend() == "tpu":
-        # Silicon-measured default is the shift schedule; the round-5 window
-        # banked >1x records with it and chunk is unmeasured on TPU.
-        return "shift"
-    # XLA:CPU: the blocked chunk schedule wins decisively at the (new)
-    # cache-resident batch sizes — full config best-of-3 2.68 s vs 3.60 s
-    # (assoc) at batch 64, longdoc 0.79 -> 0.93 vs oracle at batch 16.
-    return "chunk"
-
-
-def _use_shift_scan() -> bool:
-    return _scan_impl() == "shift"
-
-
 def shift_scan_tuple(op, identities, xs, axis: int = 1):
     """Inclusive scan of a TUPLE state under associative ``op`` via the
     contiguous-shift (Hillis-Steele) schedule.
@@ -226,9 +197,9 @@ def shift_scan_tuple(op, identities, xs, axis: int = 1):
     ``op`` maps ``(left_state, right_state)`` tuples to a state tuple, where
     the left operand is the earlier prefix.  ``identities`` gives ``op``'s
     identity per component: a scalar, or an array broadcastable to a
-    ``[B, d, ...]`` pad block.  The one scan-schedule implementation shared
-    by the segmented scans, :func:`assoc_scan1`, and the fused polynomial
-    hashes (stats._poly_hash_many).
+    ``[B, d, ...]`` pad block.  The one lax scan schedule, shared by the
+    segmented scans, :func:`assoc_scan1`, and the fused polynomial hashes
+    (stats._poly_hash_many).
     """
     if axis != 1:
         xs = tuple(jnp.moveaxis(x, axis, 1) for x in xs)
@@ -254,110 +225,23 @@ def shift_scan_tuple(op, identities, xs, axis: int = 1):
     return xs
 
 
-def _ident_block(ident, like: jax.Array, shape) -> jax.Array:
-    if isinstance(ident, (int, bool, np.integer, np.bool_)):
-        return jnp.full(shape, ident, dtype=like.dtype)
-    return jnp.broadcast_to(ident, shape).astype(like.dtype)
-
-
-def chunk_scan_tuple(op, identities, xs, axis: int = 1, chunk_size: int = 0):
-    """Inclusive tuple-state scan via the blocked three-phase schedule (see
-    scan notes above): one ``lax.scan`` over in-chunk positions with a
-    ``[B, n_chunks]`` carry, a small cross-chunk prefix, one combine."""
-    import os
-
-    if chunk_size <= 0:
-        # Backend-conditional default.  XLA:CPU (measured at cache-resident
-        # batch sizes): chunk 64 beats 128 on both the short-doc regime
-        # (2.59 s vs 2.70 s full-pipeline pass) and scan-bound longdoc
-        # (1.25x vs 1.11x the oracle); 32 ties 64, 256 is clearly worse.
-        # Accelerators keep 128 — the schedule only runs there under the
-        # opt-in TEXTBLAST_SCAN_IMPL=chunk A/B, and 64 is unmeasured on
-        # silicon (halved per-step work vs doubled trip count lands
-        # differently off-cache).
-        env = os.environ.get("TEXTBLAST_SCAN_CHUNK")
-        if env:
-            chunk_size = int(env)
-        else:
-            chunk_size = 64 if jax.default_backend() == "cpu" else 128
-    if axis != 1:
-        xs = tuple(jnp.moveaxis(x, axis, 1) for x in xs)
-    b, length = xs[0].shape[0], xs[0].shape[1]
-    if length <= 2 * chunk_size:
-        out = shift_scan_tuple(op, identities, xs, axis=1)
-        return out if axis == 1 else tuple(jnp.moveaxis(x, 1, axis) for x in out)
-    n = -(-length // chunk_size)
-    pad = n * chunk_size - length
-
-    xs3 = []
-    for x, ident in zip(xs, identities):
-        if pad:
-            blk = _ident_block(ident, x, (b, pad) + x.shape[2:])
-            x = jnp.concatenate([x, blk], axis=1)
-        x = x.reshape((b, n, chunk_size) + x.shape[2:])
-        xs3.append(jnp.moveaxis(x, 2, 0))  # [C, b, n, *rest]
-    xs3 = tuple(xs3)
-
-    init = tuple(
-        _ident_block(ident, x, (x.shape[1], x.shape[2]) + x.shape[3:])
-        for x, ident in zip(xs3, identities)
-    )
-
-    def step(carry, xc):
-        new = op(carry, xc)
-        return new, new
-
-    _, ys = jax.lax.scan(step, init, xs3)  # each [C, b, n, *rest]
-
-    # Cross-chunk exclusive prefix of the chunk summaries (tiny: [b, n]).
-    sums = tuple(y[-1] for y in ys)
-    inc = shift_scan_tuple(op, identities, sums, axis=1)
-    exc = tuple(
-        jnp.concatenate(
-            [_ident_block(ident, i, (b, 1) + i.shape[2:]), i[:, :-1]], axis=1
-        )
-        for i, ident in zip(inc, identities)
-    )
-    exc_b = tuple(jnp.broadcast_to(e, y.shape) for e, y in zip(exc, ys))
-    final = op(exc_b, ys)
-
-    outs = []
-    for f in final:
-        f = jnp.moveaxis(f, 0, 2).reshape((b, n * chunk_size) + f.shape[3:])
-        outs.append(f[:, :length])
-    outs = tuple(outs)
-    return outs if axis == 1 else tuple(jnp.moveaxis(x, 1, axis) for x in outs)
-
-
 def _seg_scan(op, identity, values: jax.Array, reset: jax.Array, axis: int):
-    # Dispatch accounting for bench's fused-vs-staged A/B (no-op unless a
+    # Dispatch accounting for the dispatch-count gates (no-op unless a
     # count_scan_dispatches scope is active).  Imported lazily: device is
     # imported by pallas_scan's consumers, never the other way around.
     from .pallas_scan import record_scan_dispatch
 
     record_scan_dispatch("lax_scan")
-    impl = _scan_impl()
-    if impl == "shift":
-        # Virtual elements left of position 0 are (op identity, reset=True):
-        # the identity keeps in-range prefixes exact, the True seals the
-        # boundary for later levels.
-        v, _ = shift_scan_tuple(op, (identity, True), (values, reset), axis)
-        return v
-    if impl == "chunk":
-        # The chunk schedule needs the TRUE left identity (reset=False):
-        # its identities seed every chunk's carry and the cross-chunk
-        # prefix, where a sealing True would cut segments at chunk
-        # boundaries (shift's virtual elements sit only left of position 0,
-        # where sealing is harmless).
-        v, _ = chunk_scan_tuple(op, (identity, False), (values, reset), axis)
-        return v
-    out, _ = jax.lax.associative_scan(op, (values, reset), axis=axis)
-    return out
+    # Virtual elements left of position 0 are (op identity, reset=True):
+    # the identity keeps in-range prefixes exact, the True seals the
+    # boundary for later levels.
+    v, _ = shift_scan_tuple(op, (identity, True), (values, reset), axis)
+    return v
 
 
 def assoc_scan1(op, identity, x: jax.Array, axis: int = 1) -> jax.Array:
-    """Inclusive scan of a single array under an arbitrary associative ``op``,
-    using the backend-appropriate schedule (see scan notes above).
+    """Inclusive scan of a single array under an arbitrary associative ``op``
+    on the shift schedule (see scan notes above).
 
     ``identity`` is ``op``'s identity: a scalar, or an array broadcastable to
     a ``[B, d, ...]`` pad block (e.g. an iota for function-composition scans).
@@ -365,15 +249,10 @@ def assoc_scan1(op, identity, x: jax.Array, axis: int = 1) -> jax.Array:
     from .pallas_scan import record_scan_dispatch
 
     record_scan_dispatch("lax_scan")
-    impl = _scan_impl()
-    if impl == "assoc":
-        return jax.lax.associative_scan(op, x, axis=axis)
 
     def tuple_op(a, b):
         return (op(a[0], b[0]),)
 
-    if impl == "chunk":
-        return chunk_scan_tuple(tuple_op, (identity,), (x,), axis)[0]
     return shift_scan_tuple(tuple_op, (identity,), (x,), axis)[0]
 
 
@@ -395,24 +274,6 @@ def latch_scan(values: jax.Array, set_mask: jax.Array, axis: int = 1) -> jax.Arr
     position where ``set_mask`` is True (0 before any set position).  A reset
     is expressed by a set position carrying the fill value."""
     return _seg_scan(_latch_op, 0, values, set_mask, axis)
-
-
-def use_sort_tables() -> bool:
-    """Whether per-segment tables are built scatter-free (one position sort +
-    gathers) instead of by XLA scatter.  XLA:TPU serializes scatters into
-    per-element loops — the round-3 on-chip profile's prime suspect — while
-    XLA:CPU handles the unique-index scatters well (the tuned CPU-backend
-    record keeps its byte-identical traces and warm compile cache).
-    ``TEXTBLAST_TABLE_IMPL`` (sort|scatter) pins one; default picks by
-    backend at trace time, mirroring ``_use_shift_scan``."""
-    import os
-
-    impl = os.environ.get("TEXTBLAST_TABLE_IMPL", "")
-    if impl == "sort":
-        return True
-    if impl == "scatter":
-        return False
-    return jax.default_backend() == "tpu"
 
 
 def rev(x: jax.Array, axis: int = 1) -> jax.Array:

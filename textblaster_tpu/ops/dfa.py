@@ -4,8 +4,10 @@ The reference's regexes on the hot path (the citation pattern
 ``\\[\\d+(?:,\\s*\\d+)*\\]``, c4_filters.rs:33; the sentence-boundary rules)
 become tiny DFAs here.  A DFA step is a gather through a per-char transition
 row; runs of steps compose associatively (``t_ab = t_b[t_a]``), so the whole
-row is evaluated with ``lax.associative_scan`` in log depth — no sequential
-scan, XLA-friendly (SURVEY.md §7 "regexes on device").
+row is evaluated in log depth — the Pallas scan kernel where
+``pallas_scan_ok`` admits the shape, else the shift schedule of
+:func:`.device.assoc_scan1` — with no sequential scan (SURVEY.md §7 "regexes
+on device").
 """
 
 from __future__ import annotations
@@ -14,7 +16,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .device import assoc_scan1, latch_scan, use_sort_tables
+from .device import assoc_scan1, latch_scan
 from .pallas_scan import dfa_compose_scan, pallas_scan_ok
 
 __all__ = ["dfa_packed_fns", "dfa_states", "citation_spans"]
@@ -53,49 +55,35 @@ def dfa_states(
     Returns:
       ``[B, L] int32`` — state *after* consuming each char.
 
-    For <= 8 states the per-char state maps are nibble-packed into one int32
-    (state ``s``'s successor in bits ``4s..4s+3``) and composed with
+    The per-char state maps are nibble-packed into one int32 (state ``s``'s
+    successor in bits ``4s..4s+3``, so at most 8 states) and composed with
     elementwise shifts — no gathers, which cost far more than ALU on both
-    XLA:CPU and TPU.  Larger automata fall back to the gather composition.
+    XLA:CPU and TPU.
     """
     n_states = transition.shape[1]
-    if n_states <= 8:
-        fns = dfa_packed_fns(char_classes, transition)  # [B, L] packed maps
-
-        def compose(a, b):
-            # (b . a)(s) = b[a[s]]: route each of a's nibbles through b.
-            out = jnp.zeros_like(a)
-            for s in range(n_states):
-                nib = (a >> (4 * s)) & 15
-                out = out | (((b >> (nib << 2)) & 15) << (4 * s))
-            return out
-
-        # Identity function map: nibble s holds s.
-        ident = 0
-        for s in range(n_states):
-            ident |= s << (4 * s)
-        if pallas_scan_ok(*fns.shape):
-            # Blocked VMEM kernel — same int32 composition, bit-identical
-            # (pallas_scan module docstring; parity fuzzed in tests).  Under
-            # mesh_tracing(mesh) the kernel dispatch shard_maps itself over
-            # the data axis, so mesh programs keep this path too.
-            packed = dfa_compose_scan(fns, n_states)
-        else:
-            packed = assoc_scan1(compose, np.int32(ident), fns, axis=1)
-        return (packed >> (4 * start_state)) & 15
-
-    table = jnp.asarray(transition, dtype=jnp.int32)  # [S, N]
-    # Per-char transition row: f_i : state -> state, shape [B, L, N].
-    fns = table[char_classes]
+    fns = dfa_packed_fns(char_classes, transition)  # [B, L] packed maps
 
     def compose(a, b):
-        # Apply a then b: (b . a)(s) = b[a[s]].
-        return jnp.take_along_axis(b, a, axis=-1)
+        # (b . a)(s) = b[a[s]]: route each of a's nibbles through b.
+        out = jnp.zeros_like(a)
+        for s in range(n_states):
+            nib = (a >> (4 * s)) & 15
+            out = out | (((b >> (nib << 2)) & 15) << (4 * s))
+        return out
 
-    composed = assoc_scan1(
-        compose, jnp.arange(transition.shape[1], dtype=jnp.int32), fns, axis=1
-    )
-    return composed[..., start_state]
+    # Identity function map: nibble s holds s.
+    ident = 0
+    for s in range(n_states):
+        ident |= s << (4 * s)
+    if pallas_scan_ok(*fns.shape):
+        # Blocked VMEM kernel — same int32 composition, bit-identical
+        # (pallas_scan module docstring; parity fuzzed in tests).  Under
+        # mesh_tracing(mesh) the kernel dispatch shard_maps itself over the
+        # data axis, so mesh programs keep this path too.
+        packed = dfa_compose_scan(fns, n_states)
+    else:
+        packed = assoc_scan1(compose, np.int32(ident), fns, axis=1)
+    return (packed >> (4 * start_state)) & 15
 
 
 # Citation DFA symbols: 0=other, 1='[', 2=digit, 3=',', 4=space, 5=']'.
@@ -144,29 +132,11 @@ def citation_spans(cps: jax.Array, digit_mask: jax.Array, ws_mask: jax.Array) ->
     lb_pos = jnp.where(cps == ord("["), positions, -1)
     last_lb = assoc_scan1(jnp.maximum, np.int32(-1), lb_pos, axis=1)
 
-    b, length = cps.shape
-
-    if use_sort_tables():
-        # Scatter-free span fill (the TPU path): spans never overlap ('['
-        # resets the candidate), so position p is inside a span iff the
-        # NEAREST accept at/after p opened at or before p.  A reversed latch
-        # scan carries each accept's span start (biased +1 so 0 = "no accept
-        # follows") back over the positions it covers.
-        start1 = jnp.where(accept, last_lb + 1, 0)
-        na = jnp.flip(latch_scan(jnp.flip(start1, 1), jnp.flip(accept, 1)), 1)
-        return (na > 0) & (positions >= na - 1)
-
-    rows = jnp.arange(b, dtype=jnp.int32)[:, None]
-    starts = jnp.where(accept, last_lb, -1)
-
-    flat_start = jnp.where(accept, rows * (length + 1) + starts, b * (length + 1))
-    flat_end = jnp.where(accept, rows * (length + 1) + positions + 1, b * (length + 1))
-    flat = jnp.zeros(b * (length + 1) + 1, dtype=jnp.int32)
-    flat = flat.at[flat_start.reshape(-1)].add(
-        jnp.where(accept, 1, 0).reshape(-1), mode="drop"
-    )
-    flat = flat.at[flat_end.reshape(-1)].add(
-        jnp.where(accept, -1, 0).reshape(-1), mode="drop"
-    )
-    diff = flat[:-1].reshape(b, length + 1)
-    return jnp.cumsum(diff[:, :length], axis=1) > 0
+    # Scatter-free span fill: spans never overlap ('[' resets the
+    # candidate), so position p is inside a span iff the NEAREST accept
+    # at/after p opened at or before p.  A reversed latch scan carries each
+    # accept's span start (biased +1 so 0 = "no accept follows") back over
+    # the positions it covers.
+    start1 = jnp.where(accept, last_lb + 1, 0)
+    na = jnp.flip(latch_scan(jnp.flip(start1, 1), jnp.flip(accept, 1)), 1)
+    return (na > 0) & (positions >= na - 1)

@@ -3,9 +3,9 @@ and the fused per-bucket filter megakernel.
 
 The per-row hot scans — DFA matching over nibble-packed transition maps
 (:mod:`.dfa`) and the segmented polynomial-hash streams feeding the
-repetition/duplicate statistics (:mod:`.stats`) — run as log-depth
-``lax.associative_scan`` under XLA, which materializes every doubling
-level's ``[B, L]`` intermediate in HBM.  This module runs the *same
+repetition/duplicate statistics (:mod:`.stats`) — run under XLA as the
+log-depth shift schedule of :mod:`.device`, which materializes every
+doubling level's ``[B, L]`` intermediate in HBM.  This module runs the *same
 associative ops* as a blocked sequential scan instead: the grid tiles rows
 (8-row sublane tiles), each tile stays resident in VMEM while an in-kernel
 ``fori_loop`` walks fixed-width lane blocks, scanning each block with
@@ -24,26 +24,26 @@ marked ``emit="last"`` write only their final ``[B, 1]`` carry (a per-row
 total), never the full scanned stream.
 
 Every op here is int32 ALU with exact wraparound semantics, so the kernels
-are **bit-identical** to the lax schedules by integer associativity; the
+are **bit-identical** to the lax schedule by integer associativity; the
 decision parity vs the host oracle is preserved exactly (the parity fuzz
 suites in ``tests/test_pallas_scan.py`` and ``tests/test_fused_scan.py``
 stamp this, not approximate it).
 
-Escape hatches / fallback:
+Which form computes a statistic is decided here, by shape and capability
+only — the same decision on every backend:
 
-* ``TEXTBLAST_PALLAS=off`` (or the older ``TEXTBLAST_NO_PALLAS=1``)
-  disables every Pallas kernel — callers fall back to the lax scans.
-* ``TEXTBLAST_FUSED=off`` disables only the fused megakernel — the
-  per-scan kernels (and their lax fallbacks) still run.
-* ``TEXTBLAST_DEPFUSE=off`` disables only the *dependency-chained*
-  multi-pass megakernel (:func:`chain_scan`) — callers fall back to the
-  staged schedule (which may still use :func:`fused_scan` for its
-  independent groups).
-* Non-TPU backends take the lax scans.  On a TPU a kernel that fails its
-  probe raises instead: only the hatches above choose the lax schedule
-  there.  ``TEXTBLAST_PALLAS_INTERPRET=1``
-  forces the interpret-mode kernel anywhere — how the fuzz suite runs the
-  exact kernel program under tier-1 on CPU.
+* :func:`chain_scan` (the dependency-chained multi-pass kernel) wherever
+  :func:`chain_scan_ok` admits the shape; :func:`fused_scan` where a caller
+  has no chain form (``fineweb_stats``, and ``structure`` above 8,192
+  lanes); both stop at ``_FUSED_MAX_LANES``.  Past that, the staged lax
+  path, whose single scans take the per-scan kernels up to ``_MAX_LANES``
+  (:func:`pallas_scan_ok`) and the shift schedule beyond.
+* ``TEXTBLAST_PALLAS=off`` disables every Pallas kernel — callers take the
+  staged lax path.  It is the one way round a kernel: on a TPU a kernel
+  that fails its probe raises.
+* Non-TPU backends lower no Mosaic kernel and take the staged lax path.
+  ``TEXTBLAST_PALLAS_INTERPRET=1`` forces the interpret-mode kernels
+  anywhere — how the test suite runs the exact kernel programs on CPU.
 * Mosaic ``pallas_call`` custom calls carry no GSPMD partitioning rule, so
   a program jitted with multi-device shardings cannot contain a bare one.
   ``CompiledPipeline`` traces mesh programs under ``mesh_tracing(mesh)``,
@@ -92,10 +92,8 @@ __all__ = [
     "chain_scan_supported",
     "copy_group",
     "count_scan_dispatches",
-    "depfuse_enabled",
     "dfa_compose_scan",
     "dfa_group",
-    "fused_enabled",
     "fused_scan",
     "fused_scan_ok",
     "fused_scan_supported",
@@ -495,21 +493,18 @@ def _env_hatches() -> Tuple[str, ...]:
     serving the verdict cached under the old env."""
     return (
         os.environ.get("TEXTBLAST_PALLAS", ""),
-        os.environ.get("TEXTBLAST_NO_PALLAS", ""),
         os.environ.get("TEXTBLAST_PALLAS_INTERPRET", ""),
-        os.environ.get("TEXTBLAST_FUSED", ""),
-        os.environ.get("TEXTBLAST_DEPFUSE", ""),
     )
 
 
 def _probe_error(what: str, detail: str) -> RuntimeError:
     """A probe that fails on a TPU is a fault, not a reason to switch to the
-    lax schedule behind the user's back: callers raise this.  The env
-    hatches (``TEXTBLAST_PALLAS``/``_FUSED``/``_DEPFUSE=off``) are the
-    explicit way to run without a kernel."""
+    lax schedule behind the user's back: callers raise this.
+    ``TEXTBLAST_PALLAS=off`` is the explicit way to run without the
+    kernels."""
     return RuntimeError(
-        f"{what} failed its probe on TPU: {detail}.  Set the matching "
-        "TEXTBLAST_PALLAS/_FUSED/_DEPFUSE=off hatch to run without it"
+        f"{what} failed its probe on TPU: {detail}.  Set "
+        "TEXTBLAST_PALLAS=off to run without the Pallas kernels"
     )
 
 
@@ -579,12 +574,6 @@ def _probe_fused() -> bool:
     return _probe_fused_cached(_env_hatches(), jax.default_backend())
 
 
-def fused_enabled() -> bool:
-    """``TEXTBLAST_FUSED=off`` (or ``0``/``false``) disables the fused
-    megakernel only; re-read per call so tests/benches can toggle it."""
-    return os.environ.get("TEXTBLAST_FUSED", "").lower() not in ("off", "0", "false")
-
-
 def pallas_scan_supported() -> bool:
     """Whether the scan kernels can run here.  Env decisions are re-read per
     call (the backend probe is cached keyed on env hatches + backend);
@@ -617,16 +606,16 @@ def pallas_scan_ok(b: int, length: int) -> bool:
 
 
 def fused_scan_supported() -> bool:
-    """Whether the fused megakernel can run here: the scan kernels, its own
-    hatch, and its own probe."""
-    if not (fused_enabled() and pallas_scan_supported()):
+    """Whether the fused megakernel can run here: the scan kernels and its
+    own probe."""
+    if not pallas_scan_supported():
         return False
     return interpret_forced() or _probe_fused()
 
 
 def fused_scan_ok(b: int, length: int) -> bool:
     """Gate for :func:`fused_scan` — the per-scan gate plus the fused
-    kernel's own hatch, probe, and tighter VMEM lane ceiling."""
+    kernel's own probe and tighter VMEM lane ceiling."""
     if not pallas_scan_ok(b, length) or length > _FUSED_MAX_LANES:
         return False
     return fused_scan_supported()
@@ -991,13 +980,6 @@ def chain_scan(passes: Sequence[dict]) -> List[List[Tuple[jax.Array, ...]]]:
     ]
 
 
-def depfuse_enabled() -> bool:
-    """``TEXTBLAST_DEPFUSE=off`` (or ``0``/``false``) disables the
-    dependency-chained multi-pass megakernel only; re-read per call so
-    tests/benches can toggle it."""
-    return os.environ.get("TEXTBLAST_DEPFUSE", "").lower() not in ("off", "0", "false")
-
-
 def _chain_probe(interpret: bool = False) -> bool:
     """A two-block chain: reverse walks, cross-pass and shift taps, VMEM
     scratch and the segmented-max op, against the staged lax schedule."""
@@ -1093,17 +1075,16 @@ def _probe_depfuse() -> bool:
 
 
 def chain_scan_supported() -> bool:
-    """Whether the dependency-chain kernel can run here: the fused kernel,
-    the dependency-fusion hatch, and its own probe."""
-    if not (depfuse_enabled() and fused_scan_supported()):
+    """Whether the dependency-chain kernel can run here: the fused kernel
+    and its own probe."""
+    if not fused_scan_supported():
         return False
     return interpret_forced() or _probe_depfuse()
 
 
 def chain_scan_ok(b: int, length: int) -> bool:
-    """Gate for :func:`chain_scan` — the fused gate (so ``TEXTBLAST_FUSED``
-    and the mesh/shape rules compose) plus the dependency-fusion hatch and
-    its own backend probe."""
+    """Gate for :func:`chain_scan` — the fused gate (its mesh, shape and
+    lane rules) plus the chain kernel's own backend probe."""
     return fused_scan_ok(b, length) and chain_scan_supported()
 
 

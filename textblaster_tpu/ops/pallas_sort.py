@@ -79,10 +79,10 @@ _tls = threading.local()
 
 # --- dispatch accounting ----------------------------------------------------
 #
-# bench.py's BENCH_FUSED A/B and chip_smoke.py count the kernels one traced
-# (bucket, phase) program issues.  Recording is thread-local and a no-op
-# unless a count_scan_dispatches() scope is active, so the hot path pays one
-# getattr.
+# The dispatch-count gates (tests, the profiler's sentinel) and chip_smoke.py
+# count the kernels one traced (bucket, phase) program launches.  Recording
+# is thread-local and a no-op unless a count_scan_dispatches() scope is
+# active, so the hot path pays one getattr.
 
 
 def record_scan_dispatch(kind: str) -> None:
@@ -114,14 +114,9 @@ _DATA_AXIS = "data"
 
 def pallas_enabled() -> bool:
     """Global Pallas escape hatch shared by every kernel (sort + scan):
-    ``TEXTBLAST_PALLAS=off`` (or ``0``/``false``) and the older
-    ``TEXTBLAST_NO_PALLAS=1`` both force the lax fallbacks everywhere.
-    Re-read per call so tests can toggle it."""
-    if os.environ.get("TEXTBLAST_PALLAS", "").lower() in ("off", "0", "false"):
-        return False
-    if os.environ.get("TEXTBLAST_NO_PALLAS"):
-        return False
-    return True
+    ``TEXTBLAST_PALLAS=off`` (or ``0``/``false``) forces the lax fallbacks
+    everywhere.  Re-read per call so tests can toggle it."""
+    return os.environ.get("TEXTBLAST_PALLAS", "").lower() not in ("off", "0", "false")
 
 
 def interpret_forced() -> bool:
@@ -235,7 +230,6 @@ def _env_hatches() -> Tuple[str, ...]:
     of serving the verdict cached under the old env."""
     return (
         os.environ.get("TEXTBLAST_PALLAS", ""),
-        os.environ.get("TEXTBLAST_NO_PALLAS", ""),
         os.environ.get("TEXTBLAST_PALLAS_INTERPRET", ""),
     )
 

@@ -798,14 +798,13 @@ class CompiledPipeline:
     ) -> Dict[str, int]:
         """Per-kind scan dispatch counts for one traced (bucket, phase)
         program: "fused" / "pallas_scan" kernel calls and "lax_scan"
-        schedules, "pallas_sort" / "lax_sort" row sorts.  A multi-pass dependency chain (``chain_scan``, the
-        ``TEXTBLAST_DEPFUSE`` path) books as ONE "fused" dispatch however
-        many passes and groups it carries — the whole point of the chain is
-        that its intermediate streams never leave VMEM, so one kernel launch
-        is the honest count.  Traces the raw program under
-        ``jax.eval_shape`` (no compile, no device execution), so bench's
-        BENCH_FUSED / BENCH_DEPFUSE A/Bs can report how many dispatches the
-        fused megakernel and the dependency chains removed."""
+        schedules, "pallas_sort" / "lax_sort" row sorts.  A multi-pass
+        dependency chain (``chain_scan``) books as ONE "fused" dispatch
+        however many passes and groups it carries — the whole point of the
+        chain is that its intermediate streams never leave VMEM, so one
+        kernel launch is the honest count.  Traces the raw program under
+        ``jax.eval_shape`` (no compile, no device execution); the
+        dispatch-count gates and the profiler's sentinel read it."""
         from .pallas_scan import count_scan_dispatches
 
         rows = rows or self.geometry.batch_for(length)
@@ -846,9 +845,9 @@ class CompiledPipeline:
         halves to and ``_fn_for`` keys separately.  Without pre-seeding,
         those programs (fused-kernel variants included — the split rows are
         ROWS-aligned via ``_split_rows`` so they trace the same fused path,
-        multi-pass ``chain_scan`` chains and all; the depfuse/staged choice
-        itself is an env knob, fingerprinted by the AOT cache via
-        ``_TRACE_ENV_KNOBS``, so each setting pre-seeds its own executables)
+        multi-pass ``chain_scan`` chains and all; ``TEXTBLAST_PALLAS`` is
+        fingerprinted by the AOT cache via ``_TRACE_ENV_KNOBS``, so each
+        setting pre-seeds its own executables)
         always compiled cold *mid-incident*, stacking a 15-29 s compile
         stall on top of whatever fault tripped the split."""
         jobs = []

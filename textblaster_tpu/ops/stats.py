@@ -10,9 +10,9 @@ logic stays on the host).
 Structure recovery is scan-based: word/line/paragraph segmentation via
 segmented associative scans (:mod:`.device`), citation matching and sentence
 boundaries via DFA composition (:mod:`.dfa`), duplicate detection via in-row
-sorts of (hash, length) keys.  All per-segment scatters write exactly once
-per slot (at segment-end positions) — duplicate-index scatter order is
-undefined in XLA.
+sorts of (hash, length) keys.  Per-segment tables are built by sorted
+compaction of segment-end positions, never by XLA scatter (see "Per-segment
+tables" below).
 
 Known device/oracle divergences (each measured by the parity suite,
 tests/test_device_parity.py):
@@ -45,7 +45,6 @@ from .device import (
     LOWER,
     PUNCT,
     WS,
-    assoc_scan1,
     classify,
     isin_sorted,
     latch_scan,
@@ -54,7 +53,7 @@ from .device import (
     seg_scan_add,
     seg_scan_max,
     seg_scan_or,
-    use_sort_tables,
+    shift_scan_tuple,
     utf8_width,
     word_mask,
 )
@@ -127,24 +126,16 @@ def _poly_hash_many(
         my, ays = y[0], y[1:]
         return (mx * my,) + tuple(ay + my * ax for ax, ay in zip(axs, ays))
 
-    from .device import _scan_impl, chunk_scan_tuple, shift_scan_tuple
     from .pallas_scan import affine_hash_scan, pallas_scan_ok
 
     if pallas_scan_ok(*m.shape):
         # Blocked VMEM kernel — same int32 affine composition, bit-identical
-        # to every lax schedule below (parity fuzzed in tests).
+        # to the lax schedule below (parity fuzzed in tests).
         return affine_hash_scan(m, accs)
 
-    impl = _scan_impl()
-    if impl != "assoc":
-        # Affine identity is (m=1, a=0, ...) — one shared scan schedule
-        # (device.shift_scan_tuple / chunk_scan_tuple).
-        identities = (1,) + tuple(0 for _ in accs)
-        fn = chunk_scan_tuple if impl == "chunk" else shift_scan_tuple
-        return fn(compose, identities, (m,) + accs, axis=1)[1:]
-
-    out = jax.lax.associative_scan(compose, (m,) + accs, axis=1)
-    return out[1:]
+    # Affine identity is (m=1, a=0, ...).
+    identities = (1,) + tuple(0 for _ in accs)
+    return shift_scan_tuple(compose, identities, (m,) + accs, axis=1)[1:]
 
 
 def _poly_hash(
@@ -163,8 +154,8 @@ def _poly_hash(
 # * the segmented polynomial hash is the same recurrence with m = mul inside
 #   segments (identical to _poly_hash_many's operand construction above).
 #
-# Both are int32 recurrences whose every schedule (lax shift/chunk/assoc,
-# per-scan kernel, fused kernel) computes the same function exactly, so the
+# Both are int32 recurrences whose every schedule (lax shift, per-scan
+# kernel, fused kernel) computes the same function exactly, so the
 # fused path is bit-identical by integer associativity.  Callers gate on
 # pallas_scan.fused_scan_ok first.
 
@@ -213,36 +204,14 @@ def _pattern_hash_group(src: jax.Array, mask: jax.Array) -> dict:
     }
 
 
-def _scatter(values, idx, active, m, fill=0, op="set"):
-    """Scatter per-char ``values`` at ``active`` positions into ``[B, m]``
-    slots keyed by ``idx``.  With op="set", callers must guarantee one active
-    position per slot."""
-    b = values.shape[0]
-    rows = jnp.arange(b, dtype=jnp.int32)[:, None]
-    ok = active & (idx >= 0) & (idx < m)
-    flat_idx = jnp.where(ok, rows * m + idx, b * m)
-    out = jnp.full(b * m + 1, fill, dtype=values.dtype)
-    src = jnp.where(ok, values, fill).reshape(-1)
-    ref = out.at[flat_idx.reshape(-1)]
-    if op == "set":
-        out = ref.set(values.reshape(-1), mode="drop")
-    elif op == "add":
-        out = ref.add(src, mode="drop")
-    elif op == "max":
-        out = ref.max(src, mode="drop")
-    else:
-        raise ValueError(op)
-    return out[:-1].reshape(b, m)
-
-
-# --- Scatter-free table construction (the TPU path) --------------------------
-# XLA:TPU lowers the per-segment scatters above to serialized per-element
-# loops (an early on-chip profile measured ~13 s per batch).  When
-# ``use_sort_tables()`` is on, tables are built instead by ONE sorted
-# compaction of the active positions (the already-tuned VMEM bitonic network)
-# plus a small ``take_along_axis`` gather per value stream.  This requires
-# the active positions' slot keys to enumerate 0..n-1 in row order (gapless)
-# — every gated call site satisfies it by construction and says how.
+# --- Per-segment tables ------------------------------------------------------
+# XLA:TPU lowers scatters to serialized per-element loops (an early on-chip
+# profile measured ~13 s per batch), so no table here is scattered.  Each is
+# built by ONE sorted compaction of the active positions (the VMEM bitonic
+# network on TPU, ``lax.sort`` elsewhere) plus a small ``take_along_axis``
+# gather per value stream.  This requires the active positions' slot keys to
+# enumerate 0..n-1 in row order (gapless) — every call site satisfies it by
+# construction and says how.
 
 
 def _stack_rows(xs, mesh=None):
@@ -841,8 +810,6 @@ def gopher_quality_stats(
     st: TextStructure, stop_word_hashes: Sequence[int]
 ) -> Dict[str, jax.Array]:
     """Integer stats for GopherQualityFilter (gopher_quality.rs:69-295)."""
-    from .pallas_scan import fused_scan, fused_scan_ok
-
     cps, cls, mask = st.cps, st.cls, st.mask
     valid_end = st.unit_end & st.unit_valid
 
@@ -960,55 +927,6 @@ def gopher_quality_stats(
         stop_words = t[4][:, 0] if is_stop is not None else jnp.zeros_like(n_words)
         bullet_lines = res[2][0][0][:, 0]
         ellipsis_lines = res[2][0][1][:, 0]
-        dot_end = is_dot & ~_shift_l(is_dot, False)
-        ellipsis_ascii = jnp.sum(jnp.where(dot_end, dot_run // 3, 0), axis=1)
-        ellipsis_units = (ellipsis_ascii + ellipsis_uni).astype(jnp.int32)
-        return {
-            "n_words": n_words,
-            "n_non_symbol": n_words,
-            "sum_word_len": sum_len,
-            "hash_count": hash_count,
-            "ellipsis_units": ellipsis_units,
-            "n_lines": li.n_lines,
-            "bullet_lines": bullet_lines,
-            "ellipsis_lines": ellipsis_lines,
-            "alpha_words": alpha_words,
-            "stop_words": stop_words,
-        }
-
-    if fused_scan_ok(*cps.shape):
-        # One kernel for the phase's three independent scans (dot runs,
-        # first-/last-non-ws-in-line counters) plus every whole-row total
-        # that does not depend on a scan output — the totals emit as [B, 1]
-        # final carries, so no mask or count stream touches HBM.
-        totals = [
-            ((cps == ord("#")) & mask).astype(jnp.int32),
-            ((cps == 0x2026) & mask).astype(jnp.int32),
-            jnp.where(valid_end, st.unit_len, 0).astype(jnp.int32),
-            (valid_end & st.unit_alpha).astype(jnp.int32),
-        ]
-        if is_stop is not None:
-            totals.append((valid_end & is_stop).astype(jnp.int32))
-        r_reset = _first_col(mask) | _shift_r(rev(li.is_nl), False)
-        res = fused_scan(
-            [
-                _seg_add_group((is_dot.astype(jnp.int32),), dot_start),
-                _seg_add_group(
-                    (nonws.astype(jnp.int32),), _line_reset(li, mask)
-                ),
-                _seg_add_group((rev(nonws).astype(jnp.int32),), r_reset),
-                _sum_group(tuple(totals)),
-            ]
-        )
-        (dot_run,) = res[0]
-        leader = nonws & (res[1][0] == 1)
-        last_nonws = rev(rev(nonws) & (res[2][0] == 1))
-        t = res[3]
-        hash_count = t[0][:, 0]
-        ellipsis_uni = t[1][:, 0]
-        sum_len = t[2][:, 0]
-        alpha_words = t[3][:, 0]
-        stop_words = t[4][:, 0] if is_stop is not None else jnp.zeros_like(n_words)
     else:
         dot_run = seg_scan_add(is_dot.astype(jnp.int32), dot_start)
         leader = _first_nonws_in_line(nonws, li, mask)
@@ -1024,18 +942,16 @@ def gopher_quality_stats(
             if is_stop is not None
             else jnp.zeros_like(n_words)
         )
+        # Bullet lines: first non-ws char is '•' or '-' (trim_start semantics).
+        is_bullet_char = (cps == 0x2022) | (cps == ord("-"))
+        bullet_lines = jnp.sum(leader & is_bullet_char, axis=1).astype(jnp.int32)
+        # Ellipsis-ended lines: last non-ws char is '…' or closes a >=3 dot run.
+        ell_line = last_nonws & ((cps == 0x2026) | (is_dot & (dot_run >= 3)))
+        ellipsis_lines = jnp.sum(ell_line, axis=1).astype(jnp.int32)
 
     dot_end = is_dot & ~_shift_l(is_dot, False)
     ellipsis_ascii = jnp.sum(jnp.where(dot_end, dot_run // 3, 0), axis=1)
     ellipsis_units = (ellipsis_ascii + ellipsis_uni).astype(jnp.int32)
-
-    # Bullet lines: first non-ws char is '•' or '-' (trim_start semantics).
-    is_bullet_char = (cps == 0x2022) | (cps == ord("-"))
-    bullet_lines = jnp.sum(leader & is_bullet_char, axis=1).astype(jnp.int32)
-
-    # Ellipsis-ended lines: last non-ws char is '…' or closes a >=3 dot run.
-    ell_line = last_nonws & ((cps == 0x2026) | (is_dot & (dot_run >= 3)))
-    ellipsis_lines = jnp.sum(ell_line, axis=1).astype(jnp.int32)
 
     return {
         "n_words": n_words,
@@ -1103,8 +1019,7 @@ def fineweb_stats(
         total_chars_no_nl = res[3][0][:, 0]
         newline_count = res[3][1][:, 0]
     else:
-        # Per-line cumulative values, scattered once at the line's last
-        # content char (single write per slot).
+        # Per-line cumulative values, read at the line's last content char.
         char_cnt = seg_scan_add(li.content.astype(jnp.int32), reset)
         byte_cnt = seg_scan_add(jnp.where(li.content, utf8_width(cps), 0), reset)
         has_nonws = seg_scan_or(nonws.astype(jnp.int32), reset)
@@ -1113,22 +1028,14 @@ def fineweb_stats(
         total_chars_no_nl = jnp.sum(mask & ~li.is_nl, axis=1).astype(jnp.int32)
         newline_count = jnp.sum(li.is_nl, axis=1).astype(jnp.int32)
 
-    lc = li.last_content
-    if use_sort_tables():
-        # Slot j = the j-th line WITH content (blank lines hold no values on
-        # the scatter path either — their slots are pure fills there, and no
-        # consumer below reads slots positionally: validity masks, sums, and
-        # the dup sort are all permutation/gap insensitive).
-        [(tpos, treal)] = _rank_positions_many([lc], max_lines, mesh)
-        line_chars = _gather_table(char_cnt, tpos, treal)
-        line_bytes = _gather_table(byte_cnt, tpos, treal)
-        line_has_content = _gather_table(has_nonws, tpos, treal) > 0
-        line_hash_t = _gather_table(line_hash, tpos, treal)
-    else:
-        line_chars = _scatter(char_cnt, li.line_id, lc, max_lines)
-        line_bytes = _scatter(byte_cnt, li.line_id, lc, max_lines)
-        line_has_content = _scatter(has_nonws, li.line_id, lc, max_lines) > 0
-        line_hash_t = _scatter(line_hash, li.line_id, lc, max_lines)
+    # Slot j = the j-th line WITH content (a blank line holds no values,
+    # and no consumer below reads slots positionally: validity masks, sums,
+    # and the dup sort are all permutation/gap insensitive).
+    [(tpos, treal)] = _rank_positions_many([li.last_content], max_lines, mesh)
+    line_chars = _gather_table(char_cnt, tpos, treal)
+    line_bytes = _gather_table(byte_cnt, tpos, treal)
+    line_has_content = _gather_table(has_nonws, tpos, treal) > 0
+    line_hash_t = _gather_table(line_hash, tpos, treal)
     # Byte-length mixing, as in gopher_rep's tables (collision discrimination).
     line_hash_t = line_hash_t * jnp.int32(31) + line_bytes
 
@@ -1321,43 +1228,25 @@ def gopher_rep_stats(
 
     l_end, l_h, l_by, n_l = seg_values(l_content, l_start, l_pre)
     p_end, p_h, p_by, n_p = seg_values(p_content, p_start, p_pre)
-    if use_sort_tables():
-        # Segments are non-empty char runs, so seg ids are gapless 0..n-1 and
-        # slot j == the j-th segment end — identical to the scatter layout.
-        (lr, pr) = _rank_positions_many([l_end, p_end], max_segs, mesh)
-        lh, lb, lv, n_l = seg_finish(
-            _gather_table(l_h, *lr), _gather_table(l_by, *lr), n_l
-        )
-        ph, pb, pv, n_p = seg_finish(
-            _gather_table(p_h, *pr), _gather_table(p_by, *pr), n_p
-        )
-    else:
-        l_sid = jnp.cumsum(l_start.astype(jnp.int32), axis=1) - 1
-        p_sid = jnp.cumsum(p_start.astype(jnp.int32), axis=1) - 1
-        lh, lb, lv, n_l = seg_finish(
-            _scatter(l_h, l_sid, l_end, max_segs),
-            _scatter(l_by, l_sid, l_end, max_segs),
-            n_l,
-        )
-        ph, pb, pv, n_p = seg_finish(
-            _scatter(p_h, p_sid, p_end, max_segs),
-            _scatter(p_by, p_sid, p_end, max_segs),
-            n_p,
-        )
+    # Segments are non-empty char runs, so seg ids are gapless 0..n-1 and
+    # slot j is the j-th segment end.
+    (lr, pr) = _rank_positions_many([l_end, p_end], max_segs, mesh)
+    lh, lb, lv, n_l = seg_finish(
+        _gather_table(l_h, *lr), _gather_table(l_by, *lr), n_l
+    )
+    ph, pb, pv, n_p = seg_finish(
+        _gather_table(p_h, *pr), _gather_table(p_by, *pr), n_p
+    )
     l_sorted, p_sorted = _sort_runs_many([(lh, lb, lv), (ph, pb, pv)], mesh=mesh)
     l_dup_elems, l_dup_bytes = _dup_counts_sorted(l_sorted)
     p_dup_elems, p_dup_bytes = _dup_counts_sorted(p_sorted)
 
-    # Word tables for n-grams (word_idx enumerates valid ends gaplessly, so
-    # the sorted compaction lands each word at its scatter slot).
+    # Word tables for n-grams (valid ends enumerate words gaplessly, so the
+    # sorted compaction lands word j at slot j).
     valid_end = st.unit_end & st.unit_valid
-    if use_sort_tables():
-        [(wpos, wreal)] = _rank_positions_many([valid_end], max_words, mesh)
-        whash = _gather_table(st.unit_hash, wpos, wreal)
-        wbytes = _gather_table(st.unit_bytes, wpos, wreal)
-    else:
-        whash = _scatter(st.unit_hash, st.word_idx, valid_end, max_words)
-        wbytes = _scatter(st.unit_bytes, st.word_idx, valid_end, max_words)
+    [(wpos, wreal)] = _rank_positions_many([valid_end], max_words, mesh)
+    whash = _gather_table(st.unit_hash, wpos, wreal)
+    wbytes = _gather_table(st.unit_bytes, wpos, wreal)
     n_words = st.n_words
     widx = jnp.arange(max_words, dtype=jnp.int32)[None, :]
 
@@ -1483,26 +1372,23 @@ def _dup_run_info_sorted(
     if first_in_run is None:
         # Sorted by (hash, idx): the run's first slot holds the minimum index.
         first_in_run = seg_scan_max(jnp.where(run_start, sidx, -(2**30)), run_start)
-    if use_sort_tables():
-        # Un-sort by window index instead of scattering: the real entries'
-        # sidx values are exactly 0..n_valid-1 (win_valid is a prefix mask),
-        # so sorting (sidx, first_in_run) restores window order with slot j
-        # holding window j's run id — the scatter layout, fills included.
-        # Pad m to a power of two first (ADVICE r4): sort2's Pallas bitonic
-        # network requires it, and a non-pow2 width here silently fell back
-        # to lax.sort — correct but off the tuned VMEM path.  Pad keys are
-        # _I32_MAX, sorting to the end; the real entries occupy slots
-        # 0..n_valid-1 either way, so slicing back is exact.
-        k0 = jnp.where(is_real, sidx, _I32_MAX)
-        k1 = jnp.where(is_real, first_in_run, 0)
-        m_pow2 = 1 << (max(m - 1, 1)).bit_length()
-        if m_pow2 != m:
-            pad = ((0, 0), (0, m_pow2 - m))
-            k0 = jnp.pad(k0, pad, constant_values=_I32_MAX)
-            k1 = jnp.pad(k1, pad)
-        first_occ = sort2(k0, k1, mesh=mesh)[1][:, :m]
-    else:
-        first_occ = _scatter(first_in_run, sidx, is_real, m)
+    # Un-sort by window index: the real entries' sidx values are exactly
+    # 0..n_valid-1 (win_valid is a prefix mask), so sorting
+    # (sidx, first_in_run) restores window order with slot j holding window
+    # j's run id, and 0 past the real entries.  Pad m to a power of two
+    # first (ADVICE r4): sort2's Pallas bitonic network requires it, and a
+    # non-pow2 width here silently fell back to lax.sort — correct but off
+    # the tuned VMEM path.  Pad keys are _I32_MAX, sorting to the end; the
+    # real entries occupy slots 0..n_valid-1 either way, so slicing back is
+    # exact.
+    k0 = jnp.where(is_real, sidx, _I32_MAX)
+    k1 = jnp.where(is_real, first_in_run, 0)
+    m_pow2 = 1 << (max(m - 1, 1)).bit_length()
+    if m_pow2 != m:
+        pad = ((0, 0), (0, m_pow2 - m))
+        k0 = jnp.pad(k0, pad, constant_values=_I32_MAX)
+        k1 = jnp.pad(k1, pad)
+    first_occ = sort2(k0, k1, mesh=mesh)[1][:, :m]
     return win_valid & (first_occ < idx), first_occ
 
 
@@ -1533,27 +1419,20 @@ def _find_all_dup_bytes_batched(jobs, mesh=None) -> Dict[str, jax.Array]:
     rid = _stack_rows([j[1] for j in jobs], mesh)  # [kB, m]
     val = _stack_rows([j[2] for j in jobs], mesh)
     gbs = _stack_rows([j[3] for j in jobs], mesh)
-    rows = jnp.arange(rid.shape[0], dtype=jnp.int32)
-    onehot_visited = use_sort_tables()
     lane = jnp.arange(m, dtype=jnp.int32)[None, :]
 
     def step(carry, xs):
         visited, skip, acc = carry
         rid_c, gb_c, val_c = xs  # [kB] each
         can = (skip == 0) & val_c
-        if onehot_visited:
-            # One-hot compare instead of row gather/scatter: O(kB*m) VPU work
-            # per step, but no serialized dynamic addressing on TPU.
-            oh = lane == rid_c[:, None]
-            seen = jnp.sum(jnp.where(oh, visited, 0), axis=1) > 0
-            hit = can & seen
-            visited = jnp.maximum(
-                visited, (oh & (can & ~seen)[:, None]).astype(jnp.int32)
-            )
-        else:
-            seen = visited[rows, rid_c] > 0
-            hit = can & seen
-            visited = visited.at[rows, rid_c].max((can & ~seen).astype(jnp.int32))
+        # One-hot compare instead of row gather/scatter: O(kB*m) VPU work
+        # per step, but no serialized dynamic addressing on TPU.
+        oh = lane == rid_c[:, None]
+        seen = jnp.sum(jnp.where(oh, visited, 0), axis=1) > 0
+        hit = can & seen
+        visited = jnp.maximum(
+            visited, (oh & (can & ~seen)[:, None]).astype(jnp.int32)
+        )
         acc = acc + jnp.where(hit, gb_c, 0)
         skip = jnp.where(hit, n_vec - 1, jnp.maximum(skip - 1, 0))
         return (visited, skip, acc), None
@@ -1850,13 +1729,7 @@ def c4_stage(
         reset = _line_reset(li, mask)
 
         # Per-line trim: chars at/after the first non-ws, at/before the last.
-        from .pallas_scan import (
-            chain_pass,
-            chain_scan,
-            chain_scan_ok,
-            fused_scan,
-            fused_scan_ok,
-        )
+        from .pallas_scan import chain_pass, chain_scan, chain_scan_ok
 
         r_reset = _first_col(mask) | _shift_r(rev(li.is_nl), False)
         if chain_scan_ok(*cps.shape):
@@ -1889,17 +1762,6 @@ def c4_stage(
             if params.filter_lorem_ipsum:
                 lorem_h = res[0][1][0]
             before_last = res[1][0][0] >= 1
-        elif fused_scan_ok(*cps.shape):
-            # The forward and reversed line counters are independent — one
-            # fused kernel pass instead of two staged scans.
-            res = fused_scan(
-                [
-                    _seg_add_group((nonws.astype(jnp.int32),), reset),
-                    _seg_add_group((rev(nonws).astype(jnp.int32),), r_reset),
-                ]
-            )
-            after_first = res[0][0] >= 1
-            before_last = rev(res[1][0] >= 1)
         else:
             after_first = seg_scan_add(nonws.astype(jnp.int32), reset) >= 1
             before_last = rev(
@@ -2032,71 +1894,47 @@ def c4_stage(
         else None
     )
 
-    if use_sort_tables():
-        # Slot j = line id j: every line present in the compacted batch has
-        # exactly one representative char — its '\n', or the row's final
-        # char — in line order, so the sorted compaction reproduces the
-        # scatter slot layout (a final line whose chars all trimmed away has
-        # no slot on either path; its verdict comes from the fills via
-        # ``line_exists`` below).  Per-line values become segmented scans
-        # read at the representative.
-        reset1 = _line_reset(li1, m1)
-        row_last1 = m1 & ~_shift_l(m1, False)
-        rep1 = (li1.is_nl | row_last1) & m1
-        [(lpos1, lreal1)] = _rank_positions_many([rep1], max_lines, mesh)
-        content_set1 = li1.content | reset1
+    # Slot j = line id j: every line present in the compacted batch has
+    # exactly one representative char — its '\n', or the row's final char —
+    # in line order (a final line whose chars all trimmed away has no slot;
+    # its verdict comes from the fills via ``line_exists`` below).  Per-line
+    # values are segmented scans read at the representative.
+    reset1 = _line_reset(li1, m1)
+    row_last1 = m1 & ~_shift_l(m1, False)
+    rep1 = (li1.is_nl | row_last1) & m1
+    [(lpos1, lreal1)] = _rank_positions_many([rep1], max_lines, mesh)
+    content_set1 = li1.content | reset1
 
-        line_words = _gather_table(
-            seg_scan_add(valid_end1.astype(jnp.int32), reset1), lpos1, lreal1
-        )
-        line_max_word = _gather_table(
-            seg_scan_max(jnp.where(valid_end1, st1.unit_len, 0), reset1),
-            lpos1,
-            lreal1,
-        )
-        # "Value at the line's last content char" via a latch over content
-        # positions (a blank line's representative reads the latch cleared
-        # at its line start — the scatter fill).
-        line_last_char = _gather_table(
-            latch_scan(jnp.where(li1.content, c1_cps, 0), content_set1),
-            lpos1,
-            lreal1,
-        )
-        line_end_dots = _gather_table(
-            latch_scan(jnp.where(li1.content & is_dot1, dot_run1, 0), content_set1),
-            lpos1,
-            lreal1,
-        )
-        if starts is not None:
-            bad_pattern_line = (
-                _gather_table(
-                    seg_scan_or(starts.astype(jnp.int32), reset1), lpos1, lreal1
-                )
-                > 0
+    line_words = _gather_table(
+        seg_scan_add(valid_end1.astype(jnp.int32), reset1), lpos1, lreal1
+    )
+    line_max_word = _gather_table(
+        seg_scan_max(jnp.where(valid_end1, st1.unit_len, 0), reset1),
+        lpos1,
+        lreal1,
+    )
+    # "Value at the line's last content char" via a latch over content
+    # positions (a blank line's representative reads the latch cleared at
+    # its line start: 0).
+    line_last_char = _gather_table(
+        latch_scan(jnp.where(li1.content, c1_cps, 0), content_set1),
+        lpos1,
+        lreal1,
+    )
+    line_end_dots = _gather_table(
+        latch_scan(jnp.where(li1.content & is_dot1, dot_run1, 0), content_set1),
+        lpos1,
+        lreal1,
+    )
+    if starts is not None:
+        bad_pattern_line = (
+            _gather_table(
+                seg_scan_or(starts.astype(jnp.int32), reset1), lpos1, lreal1
             )
-        else:
-            bad_pattern_line = jnp.zeros_like(line_words, dtype=bool)
+            > 0
+        )
     else:
-        line_words = _scatter(
-            jnp.ones_like(c1_cps), li1.line_id, valid_end1, max_lines, op="add"
-        )
-        line_max_word = _scatter(
-            st1.unit_len, li1.line_id, valid_end1, max_lines, op="max"
-        )
-        # Terminal punctuation: last char of each (already trimmed) line.
-        line_last_char = _scatter(c1_cps, li1.line_id, li1.last_content, max_lines)
-        line_end_dots = _scatter(
-            jnp.where(is_dot1, dot_run1, 0), li1.line_id, li1.last_content, max_lines
-        )
-        if starts is not None:
-            bad_pattern_line = (
-                _scatter(
-                    starts.astype(jnp.int32), li1.line_id, starts, max_lines, op="add"
-                )
-                > 0
-            )
-        else:
-            bad_pattern_line = jnp.zeros_like(line_words, dtype=bool)
+        bad_pattern_line = jnp.zeros_like(line_words, dtype=bool)
 
     ends_terminal = isin_sorted(line_last_char, jnp.asarray(_END_PUNCT_SET)) & (
         line_last_char > 0

@@ -143,19 +143,14 @@ def config_fingerprint(config: Any) -> str:
     return hashlib.sha256(blob.encode("utf-8")).hexdigest()[:16]
 
 
-#: Env knobs that change the *traced program* (scan schedule, table impl,
-#: wire dtype, phase layout, Pallas kernel selection).  Two processes whose
-#: knobs differ must never share an executable.
+#: Env knobs that change the *traced program* (wire dtype, phase layout,
+#: Pallas kernels on or off, interpret mode).  Two processes whose knobs
+#: differ must never share an executable.
 _TRACE_ENV_KNOBS = (
-    "TEXTBLAST_SCAN_IMPL",
-    "TEXTBLAST_TABLE_IMPL",
     "TEXTBLAST_WIRE",
     "TEXTBLAST_PHASES",
     "TEXTBLAST_PALLAS",
-    "TEXTBLAST_NO_PALLAS",
     "TEXTBLAST_PALLAS_INTERPRET",
-    "TEXTBLAST_FUSED",
-    "TEXTBLAST_DEPFUSE",
 )
 
 

@@ -649,7 +649,7 @@ _SCHEDULING_ENV_KNOBS = (
 def _env_drift_note(base: Dict[str, object]) -> List[str]:
     """Informational lines when the check environment's trace-shaping
     knobs differ from the baseline's record — the usual root cause when
-    dispatch counts drift (e.g. TEXTBLAST_DEPFUSE=off).  Scheduling knobs
+    dispatch counts drift (e.g. TEXTBLAST_PALLAS=off).  Scheduling knobs
     absent from older baselines compare against "" (their recorded-empty
     default), so no baseline regeneration is needed to get them named."""
     notes = []
@@ -712,7 +712,7 @@ def main(argv: Optional[List[str]] = None) -> int:
 
     if not args.no_interpret:
         # Deterministic CPU path; setdefault so a deliberate hatch flip
-        # (e.g. TEXTBLAST_DEPFUSE=off) stays visible to the check.
+        # (e.g. TEXTBLAST_PALLAS=off) stays visible to the check.
         os.environ.setdefault("TEXTBLAST_PALLAS_INTERPRET", "1")
 
     # Honor the watchdog env knob so the guard test "sentinel stays PASS
