@@ -267,3 +267,68 @@ pipeline:
     host_by_id, dev_by_id = run_both(yaml_str, ["hello world again", "one two"])
     assert_outcomes_equal(host_by_id, dev_by_id)
     assert dev_by_id["d0"].document.metadata["token_count"] == "3"
+
+
+def _danish_stop_word_hashes():
+    from textblaster_tpu.config.pipeline import load_pipeline_config
+    from textblaster_tpu.ops.stats import hash_string
+
+    cfg = load_pipeline_config("benchmark/configs/danish_cc.yaml")
+    [words] = [
+        s.params.stop_words for s in cfg.pipeline if s.type == "GopherQualityFilter"
+    ]
+    return np.array(sorted({hash_string(w) for w in words}), dtype=np.int32)
+
+
+def _program_sets():
+    from textblaster_tpu.ops import device as d
+    from textblaster_tpu.ops import stats as s
+
+    return {
+        "MID_LETTER_CPS": d.MID_LETTER_CPS,
+        "MID_NUM_CPS": d.MID_NUM_CPS,
+        "MID_ALL_CPS": d.MID_ALL_CPS,
+        "_TERM_SET": s._TERM_SET,
+        "_STERM_SET": s._STERM_SET,
+        "_CLOSE_SET": s._CLOSE_SET,
+        "_SP_SET": s._SP_SET,
+        "_PSEP_SET": s._PSEP_SET,
+        "_END_PUNCT_SET": s._END_PUNCT_SET,
+        "danish_stop_word_hashes": _danish_stop_word_hashes(),
+        "empty": np.zeros(0, dtype=np.int32),
+    }
+
+
+@pytest.mark.parametrize("name", list(_program_sets()))
+def test_isin_sorted_matches_np_isin(name):
+    """Constant-set membership equals ``np.isin`` on the wire's uint16
+    codepoints and on int32 codepoints and hashes: every member, its
+    neighbours, the extremes, and uint16 values that alias a member's low
+    16 bits."""
+    import jax
+
+    from textblaster_tpu.ops.device import isin_sorted
+
+    values = _program_sets()[name]
+    members = values.astype(np.int64)
+    special = np.concatenate(
+        [members, members - 1, members + 1, [0, 1, 0xFFFF, -1, -(2**31), 2**31 - 1]]
+    )
+    rng = np.random.default_rng(28)
+    shape = (64, 512)
+
+    def fill(specials, lo, hi, dtype):
+        x = rng.integers(lo, hi, size=shape[0] * shape[1], dtype=np.int64)
+        x[: len(specials)] = specials
+        return rng.permutation(x).reshape(shape).astype(dtype)
+
+    i32 = special[(special >= -(2**31)) & (special < 2**31)]
+    hashes = rng.integers(-(2**31), 0, size=256)
+    x_i32 = fill(np.concatenate([i32, hashes]), -(2**31), 2**31, np.int32)
+    x_u16 = fill(np.unique(special & 0xFFFF), 0, 0x10000, np.uint16)
+    for x in (x_u16, x_i32):
+        got = np.asarray(jax.jit(lambda a: isin_sorted(a, values))(x))
+        assert got.dtype == np.bool_
+        np.testing.assert_array_equal(got, np.isin(x, values))
+    if len(values):
+        assert np.isin(x_i32, values).any()

@@ -157,10 +157,25 @@ def tpu_defaults(compiled_kernels, monkeypatch):
         monkeypatch.setattr(mod, name, lambda: True)
 
 
+#: An HLO instruction's name and the dims of its array result.
+HLO_DEF = re.compile(r"^\s*(?:ROOT )?%([\w.\-]+) = \w+\[([\d,]*)\]", re.M)
+#: A gather and the name of its table operand.
+HLO_GATHER = re.compile(r"= \S+ gather\(%([\w.\-]+),")
+
+
+def _small_gather_tables(text):
+    """Sizes of the 1-D tables of 5-196 entries that gathers read: the
+    sizes of the constant sets (ops/device.py isin_sorted), whose binary
+    search was a chain of full-batch gathers on the chip."""
+    dims = dict(HLO_DEF.findall(text))
+    sizes = [dims.get(name, "") for name in HLO_GATHER.findall(text)]
+    return [d for d in sizes if d.isdigit() and 5 <= int(d) <= 196]
+
+
 def test_shipped_pipeline_programs_compile(one_chip, tpu_defaults):
     """Every phase program of the shipped config at the 2048 bucket, with
     the TPU's own defaults: the chain preps in ops/stats.py lower inside
-    Mosaic."""
+    Mosaic, and no constant-set membership searches or gathers."""
     from textblaster_tpu.config.pipeline import load_pipeline_config
     from textblaster_tpu.ops.pipeline import CompiledPipeline
 
@@ -170,11 +185,13 @@ def test_shipped_pipeline_programs_compile(one_chip, tpu_defaults):
     )
     for phase in range(len(pipeline.phases)):
         fn = pipeline._build_fn(2048, phase, jit=False)
-        _compile(
+        text = _compile(
             fn,
             [((ROWS, 2048), jnp.uint16), ((ROWS,), jnp.int32)],
             one_chip,
-        )
+        ).as_text()
+        assert not re.search(r'op_name="[^"]*searchsorted', text), phase
+        assert _small_gather_tables(text) == [], phase
 
 
 #: A collective operation in compiled HLO text, by its kind.

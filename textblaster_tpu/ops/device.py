@@ -10,7 +10,8 @@ All kernels operate on ``[B, L]`` codepoint tensors with a validity mask;
 reductions are along axis 1.  Scans run on one lax schedule, the
 contiguous-shift doubling below, on every backend; per-segment tables are
 built by a sorted compaction (:mod:`.stats`, :mod:`.compact`), never by an
-XLA scatter.
+XLA scatter.  Membership in a constant set is a chain of compares, never a
+search: on a TPU every gather is a full-batch memory pass.
 """
 
 from __future__ import annotations
@@ -129,12 +130,16 @@ def utf8_width(cps: jax.Array) -> jax.Array:
     return w.astype(jnp.int32)
 
 
-def isin_sorted(cps: jax.Array, sorted_vals) -> jax.Array:
-    """Membership of each element in a small sorted codepoint set."""
-    sorted_vals = jnp.asarray(sorted_vals)
-    idx = jnp.searchsorted(sorted_vals, cps)
-    idx = jnp.minimum(idx, sorted_vals.shape[0] - 1)
-    return sorted_vals[idx] == cps
+def isin_sorted(x: jax.Array, values) -> jax.Array:
+    """``np.isin(x, values)`` for a host-constant set (numpy array or tuple,
+    known at trace time): an OR of ``x == v`` compares in int32, which XLA
+    fuses into one elementwise loop, because on a TPU each probe of a binary
+    search would be a full-batch gather, a memory pass of its own."""
+    x32 = x.astype(jnp.int32)
+    hit = jnp.zeros(x.shape, dtype=bool)
+    for v in np.unique(np.asarray(values, dtype=np.int32)):
+        hit = hit | (x32 == v)
+    return hit
 
 
 # Plain numpy at module scope: a jnp.asarray here would initialize a JAX

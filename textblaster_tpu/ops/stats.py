@@ -824,8 +824,7 @@ def gopher_quality_stats(
     nonws = li.content & ~ws
 
     if stop_word_hashes:
-        sw = jnp.asarray(np.sort(np.array(stop_word_hashes, dtype=np.int32)))
-        is_stop = isin_sorted(st.unit_lhash, sw)
+        is_stop = isin_sorted(st.unit_lhash, stop_word_hashes)
     else:
         is_stop = None
 
@@ -1041,8 +1040,7 @@ def fineweb_stats(
 
     n_nonblank = jnp.sum(line_has_content, axis=1).astype(jnp.int32)
 
-    sc = jnp.asarray(np.sort(np.array([ord(c) for c in stop_chars], dtype=np.int32)))
-    ends_stop_char = last_nonws & isin_sorted(cps, sc)
+    ends_stop_char = last_nonws & isin_sorted(cps, [ord(c) for c in stop_chars])
     ends_stop = jnp.sum(ends_stop_char, axis=1).astype(jnp.int32)
 
     dup_elems, dup_bytes = _dup_counts(line_hash_t, line_bytes, line_has_content, mesh)
@@ -1469,11 +1467,11 @@ def sentence_boundaries(cps: jax.Array, mask: jax.Array, cls: jax.Array) -> jax.
     """[B, L] bool — a sentence boundary falls immediately BEFORE each True
     position (the device twin of utils.text._sentence_boundaries, applied to
     the chars selected by ``mask``)."""
-    term = isin_sorted(cps, jnp.asarray(_TERM_SET)) & mask
-    sterm = isin_sorted(cps, jnp.asarray(_STERM_SET)) & mask
-    close = isin_sorted(cps, jnp.asarray(_CLOSE_SET)) & mask
-    sp = isin_sorted(cps, jnp.asarray(_SP_SET)) & mask
-    psep = isin_sorted(cps, jnp.asarray(_PSEP_SET)) & mask
+    term = isin_sorted(cps, _TERM_SET) & mask
+    sterm = isin_sorted(cps, _STERM_SET) & mask
+    close = isin_sorted(cps, _CLOSE_SET) & mask
+    sp = isin_sorted(cps, _SP_SET) & mask
+    psep = isin_sorted(cps, _PSEP_SET) & mask
 
     sym = jnp.zeros_like(cps)
     sym = jnp.where(term, 1, sym)
@@ -1509,11 +1507,11 @@ def _sentence_frame(cps: jax.Array, mask: jax.Array, cls: jax.Array) -> dict:
     the staged path and the chain kernel (all int32, kernel-ready)."""
     from .dfa import dfa_packed_fns
 
-    term = isin_sorted(cps, jnp.asarray(_TERM_SET)) & mask
-    sterm = isin_sorted(cps, jnp.asarray(_STERM_SET)) & mask
-    close = isin_sorted(cps, jnp.asarray(_CLOSE_SET)) & mask
-    sp = isin_sorted(cps, jnp.asarray(_SP_SET)) & mask
-    psep = isin_sorted(cps, jnp.asarray(_PSEP_SET)) & mask
+    term = isin_sorted(cps, _TERM_SET) & mask
+    sterm = isin_sorted(cps, _STERM_SET) & mask
+    close = isin_sorted(cps, _CLOSE_SET) & mask
+    sp = isin_sorted(cps, _SP_SET) & mask
+    psep = isin_sorted(cps, _PSEP_SET) & mask
 
     sym = jnp.zeros_like(cps)
     sym = jnp.where(term, 1, sym)
@@ -1936,7 +1934,7 @@ def c4_stage(
     else:
         bad_pattern_line = jnp.zeros_like(line_words, dtype=bool)
 
-    ends_terminal = isin_sorted(line_last_char, jnp.asarray(_END_PUNCT_SET)) & (
+    ends_terminal = isin_sorted(line_last_char, _END_PUNCT_SET) & (
         line_last_char > 0
     )
     ends_ellipsis = line_end_dots >= 3
